@@ -169,7 +169,7 @@ def run() -> dict | None:
     # tests/test_sweep_sharded.py already pins its bit-identity): timing it
     # would double the compiled-engine cost of the single-device smoke lane
     # for no signal. The CI sharded lane forces 8 host devices.
-    run_sharded = n_dev > 1 and sweep.HAVE_SHARD_MAP
+    run_sharded = n_dev > 1
     hw = enumerate_hw_space(MAXWELL, max_area=650.0)
     if smoke():
         hw = hw.downsample(SMOKE_HW_STRIDE)
@@ -272,13 +272,9 @@ def run() -> dict | None:
         rec["sharded_speedup_vs_jax_warm"] = round(speedup, 4)
         rec["scaling_efficiency"] = round(efficiency, 4)
     else:
-        why = (
-            "this jax lacks shard_map"
-            if not sweep.HAVE_SHARD_MAP
-            else f"{n_dev} device(s); needs a multi-device mesh"
-        )
         emit(
             "sweep_sharded_total", 0.0,
-            f"skipped ({why} -- see the CI sharded-smoke lane)",
+            f"skipped ({n_dev} device(s); needs a multi-device mesh "
+            "-- see the CI sharded-smoke lane)",
         )
     return rec

@@ -72,6 +72,9 @@ def main() -> None:
         os.environ["REPRO_BENCH_REFINE"] = "1"
     if args.lm:
         os.environ["REPRO_BENCH_LM"] = "1"
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from . import (
         bench_area,

@@ -5,12 +5,14 @@ The full loop the paper envisions, on this machine:
 2. take the winning *software* parameters (the tile sizes),
 3. map them onto the TPU Pallas kernel's block plan (DESIGN.md: the VMEM
    feasibility constraint is the eq. 9/11 analogue),
-4. execute the Pallas kernel (interpret mode on CPU) against the jnp
-   oracle and report correctness + achieved useful FLOP/s.
+4. execute the Pallas kernel -- compiled on a TPU, in the Pallas
+   interpreter with ``--interpret`` -- against the jnp oracle and report
+   correctness + achieved useful FLOP/s.
 
-Run: PYTHONPATH=src python examples/stencil_codesign_e2e.py
+Run: PYTHONPATH=src python examples/stencil_codesign_e2e.py [--interpret]
 """
 
+import argparse
 import time
 
 import jax
@@ -21,6 +23,11 @@ from repro.core import MAXWELL_GPU, STENCILS, ProblemSize, solve_cell
 from repro.core.solver import LATTICE_2D, decode_index
 from repro.kernels.ops import kernel_flops, stencil_run, tuned_block_rows
 from repro.kernels.ref import run_ref
+
+ap = argparse.ArgumentParser(description=__doc__)
+ap.add_argument("--interpret", action="store_true",
+                help="run the kernel in the Pallas interpreter (no TPU)")
+args = ap.parse_args()
 
 # --- 1. codesign: optimal tiles for a 2048^2 x 64 Jacobi-2D cell ----------
 spec = STENCILS["jacobi2d"]
@@ -39,7 +46,9 @@ print(f"TPU block plan: band of {block_rows} rows (VMEM-fit solve)")
 
 # --- 4. execute + validate -------------------------------------------------
 t0 = time.perf_counter()
-got = stencil_run("jacobi2d", x, steps=steps, block_rows=block_rows)
+got = stencil_run(
+    "jacobi2d", x, steps=steps, block_rows=block_rows, interpret=args.interpret
+)
 got.block_until_ready()
 dt = time.perf_counter() - t0
 want = run_ref("jacobi2d", x, steps=steps)
@@ -47,7 +56,7 @@ err = float(jnp.abs(got - want).max())
 flops = kernel_flops("jacobi2d", shape, steps)
 print(
     f"ran {steps} steps of {shape} in {dt*1e3:.0f} ms "
-    f"(interpret mode): max|err| = {err:.2e}, useful {flops/dt/1e6:.1f} MFLOP/s"
+    f"(interpret={args.interpret}): max|err| = {err:.2e}, useful {flops/dt/1e6:.1f} MFLOP/s"
 )
 assert err < 1e-5
 print("OK: Pallas kernel matches the oracle with codesigned blocks")

@@ -3,8 +3,8 @@
 
 End-to-end, through the actual CLI entry points (no test fixtures):
 
-1. ``repro.measure.cli run``: execute the tile-parameterized Pallas
-   stencils (interpret mode on CPU) over the smoke measurement grid and
+1. ``repro.measure.cli run --interpret``: execute the tile-parameterized
+   Pallas stencils in interpret mode over the smoke measurement grid and
    persist the timings as a ``kind: "measurement"`` artifact;
 2. ``repro.measure.cli fit --synthetic``: fit model-generated timings and
    assert the fit **recovers the generating machine parameters** (the
@@ -20,6 +20,12 @@ End-to-end, through the actual CLI entry points (no test fixtures):
    neither route queries nor make sweep selectors ambiguous.
 
 Exit 0 and print PASS only if every check holds.
+
+This is a CPU lane. The parent starts the CLIs as child processes and
+starts no JAX backend of its own before they run: a parent that held an
+accelerator would lock its children out of it. On a TPU host, run
+``python chip_smoke.py`` instead, which drives the same paths in one
+process.
 
 Usage: python scripts/measure_smoke.py [--store DIR] [--downsample N]
 """
@@ -87,7 +93,9 @@ def main() -> None:
     root = args.store or tempfile.mkdtemp(prefix="measure-smoke-")
 
     print(f"[1/5] measurement run (Pallas interpret grid) under {root}")
-    out = _run(MEASURE_CLI + ["run", "--store", root, "--repeats", "2"]).stdout
+    out = _run(
+        MEASURE_CLI + ["run", "--store", root, "--repeats", "2", "--interpret"]
+    ).stdout
     print(out, end="")
     meas_key = _key(out, "measurement")
 

@@ -19,6 +19,12 @@ End-to-end, through the actual CLI entry points (no test fixtures):
 
 Exit 0 and print PASS only if every check holds.
 
+This is a CPU lane. The parent starts the CLIs as child processes and
+starts no JAX backend of its own before they run: a parent that held an
+accelerator would lock its children out of it. On a TPU host, run
+``python chip_smoke.py`` instead, which drives the same paths in one
+process.
+
 Usage: python scripts/portfolio_smoke.py [--store DIR] [--downsample N]
 """
 

@@ -415,7 +415,7 @@ def _resolve_engine(engine: str, n_hw: int, devices=None) -> str:
         # promote to the mesh engine whenever there is a mesh to feed;
         # on one device "sharded" degenerates to "jax" (same program),
         # so the single-device jit path stays the simpler choice.
-        if sweep.device_count() > 1 and sweep.HAVE_SHARD_MAP:
+        if sweep.device_count() > 1:
             return "sharded"
         return "jax"
     if not sweep.HAVE_JAX:

@@ -268,7 +268,11 @@ def _jax_scorer(objective: str):
     def score(times_d, area_d, freqs_d, idx_d, valid_d, numer, budget):
         t = times_d[:, idx_d]  # (C, M, K)
         t = jnp.where(valid_d[None, :, :], t, jnp.inf)
-        wt = freqs_d @ t.min(axis=2)  # (M,)
+        # full f32 passes: the TPU's default matmul precision rounds the
+        # operands to bf16, which would move the weighted times off the oracle
+        wt = jnp.matmul(
+            freqs_d, t.min(axis=2), precision=jax.lax.Precision.HIGHEST
+        )  # (M,)
         total_area = jnp.where(valid_d, area_d[idx_d], 0.0).sum(axis=1)
         gflops = numer / wt / 1.0e9
         if objective == "density":

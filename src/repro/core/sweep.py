@@ -65,21 +65,8 @@ except ModuleNotFoundError:  # pragma: no cover
     Mesh = NamedSharding = P = None
     HAVE_JAX = False
 
-# shard_map gets its own guard: its home has moved (jax.experimental ->
-# jax.shard_map), and its absence must only disable the *sharded* engine,
-# never take HAVE_JAX -- and with it the single-device engine, the jax
-# test suite, and the bench parity asserts -- down with it.
-shard_map = getattr(jax, "shard_map", None) if HAVE_JAX else None
-if HAVE_JAX and shard_map is None:  # pragma: no cover - version-dependent
-    try:
-        from jax.experimental.shard_map import shard_map
-    except (ModuleNotFoundError, ImportError):
-        shard_map = None
-HAVE_SHARD_MAP = shard_map is not None
-
 __all__ = [
     "HAVE_JAX",
-    "HAVE_SHARD_MAP",
     "DEFAULT_CHUNK",
     "device_count",
     "sweep_cell",
@@ -154,16 +141,6 @@ def device_count() -> int:
     """Attached devices, 0 when jax is absent. The engine="auto" promotion
     test monkeypatches this, so route all auto decisions through here."""
     return jax.device_count() if HAVE_JAX else 0
-
-
-def _require_shard_map():
-    _require_jax()
-    if not HAVE_SHARD_MAP:
-        raise ModuleNotFoundError(
-            "this jax installation exposes neither jax.shard_map nor "
-            "jax.experimental.shard_map; the sharded engine is unavailable "
-            "-- use engine='jax' (single device) or engine='auto'"
-        )
 
 
 def _resolve_devices(devices):
@@ -333,7 +310,7 @@ def _sharded_cells_solver(
     regardless of how large the hardware space grows. The hw slab buffers
     are donated: at fleet scale they are dead weight after the stack.
     """
-    _require_shard_map()
+    _require_jax()
     mesh = Mesh(np.array(devices), ("hw",))
     lat, keep_idx = _lattice_arrays(lattice, gpu)
     if keep_idx.shape[0] == 0:
@@ -347,8 +324,10 @@ def _sharded_cells_solver(
         h, p = hw.shape[0], sizes.shape[0]
         if chunk <= 0 or h <= chunk:
             return best_of(hw, sizes, st)
-        out_t = jnp.full((p, h), jnp.inf, jnp.float32)
-        out_i = jnp.full((p, h), -1, jnp.int32)
+        # the carry varies over "hw" (each device writes its own shard), so
+        # its initial value must be typed varying too or fori_loop rejects it
+        out_t = lax.pcast(jnp.full((p, h), jnp.inf, jnp.float32), "hw", to="varying")
+        out_i = lax.pcast(jnp.full((p, h), -1, jnp.int32), "hw", to="varying")
 
         def one_chunk(c, carry):
             out_t, out_i = carry
@@ -360,7 +339,7 @@ def _sharded_cells_solver(
 
         return lax.fori_loop(0, h // chunk, one_chunk, (out_t, out_i))
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(P("hw"), P("hw"), P("hw"), P(), P(), P(), P()),
@@ -455,7 +434,7 @@ def sweep_cells_sharded(
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` *before* jax
     initializes to exercise the real sharded path.
     """
-    _require_shard_map()
+    _require_jax()
     lattice, sizes, chunk = _prep_cells(st, sizes, lattice, chunk)
     devs = _resolve_devices(devices)
     n_dev = len(devs)

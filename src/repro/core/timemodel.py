@@ -173,7 +173,14 @@ def _dtype_for(xp, dtype):
 
 
 def _ceil_div(xp, a, b):
-    return xp.ceil(a / b)
+    """ceil(a / b) for integer-valued a, b >= 1, exact even where the
+    backend's division is not correctly rounded (the TPU's f32 divide
+    differs from the host's in the last bit on some quotients; one ulp
+    above an exact integer quotient, ``ceil`` would count one tile too
+    many): the floor of the quotient is corrected by one integer
+    comparison."""
+    q = xp.floor(a / b)
+    return q + (q * b < a)
 
 
 def footprint_bytes(st: StencilSpec, gpu: GPUSpec, t_s1, t_s2, t_t, t_s3=1, *, xp=np, dtype=None):

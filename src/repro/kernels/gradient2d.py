@@ -24,5 +24,5 @@ def update(ext: jax.Array, h: int) -> jax.Array:
     return jnp.sqrt(gx * gx + gy * gy)
 
 
-def step(x, block_rows=None, interpret=None):
-    return stencil2d_call(x, update, HALO, block_rows, interpret)
+def step(x, block_rows=None, *, interpret):
+    return stencil2d_call(x, update, HALO, block_rows, interpret=interpret)

@@ -22,5 +22,5 @@ def update(ext: jax.Array, h: int) -> jax.Array:
     return c + ALPHA * (n + s + e + w - 4.0 * c)
 
 
-def step(x, block_rows=None, interpret=None):
-    return stencil2d_call(x, update, HALO, block_rows, interpret)
+def step(x, block_rows=None, *, interpret):
+    return stencil2d_call(x, update, HALO, block_rows, interpret=interpret)

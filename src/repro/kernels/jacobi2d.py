@@ -21,5 +21,5 @@ def update(ext: jax.Array, h: int) -> jax.Array:
     return 0.2 * (c + n + s + e + w)
 
 
-def step(x, block_rows=None, interpret=None):
-    return stencil2d_call(x, update, HALO, block_rows, interpret)
+def step(x, block_rows=None, *, interpret):
+    return stencil2d_call(x, update, HALO, block_rows, interpret=interpret)

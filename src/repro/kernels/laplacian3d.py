@@ -23,5 +23,5 @@ def update(ext: jax.Array, h: int) -> jax.Array:
     return u + d + n + s + e + w - 6.0 * c
 
 
-def step(x, block_rows=None, interpret=None):
-    return stencil3d_call(x, update, HALO, block_rows, interpret)
+def step(x, block_rows=None, *, interpret):
+    return stencil3d_call(x, update, HALO, block_rows, interpret=interpret)

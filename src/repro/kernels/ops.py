@@ -41,8 +41,9 @@ def tuned_block_rows(name: str, shape, dtype) -> int:
     return plan_block_rows(shape, dtype)
 
 
-def stencil_step(name: str, x: jax.Array, block_rows=None, interpret=None):
-    """One un-jitted stencil application (used by tests)."""
+def stencil_step(name: str, x: jax.Array, block_rows=None, *, interpret: bool):
+    """One un-jitted stencil application. ``interpret=True`` runs the
+    Pallas interpreter (any backend); ``False`` compiles for the TPU."""
     return KERNELS[name].step(x, block_rows=block_rows, interpret=interpret)
 
 
@@ -52,7 +53,8 @@ def stencil_run(
     x: jax.Array,
     steps: int = 1,
     block_rows: int | None = None,
-    interpret: bool | None = None,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """T time steps of the named stencil (Dirichlet borders)."""
     mod = KERNELS[name]

@@ -2,7 +2,8 @@
 
     # 1. run the Pallas tile kernels over a measurement grid, persist the
     #    timings as a content-addressed `kind: "measurement"` artifact
-    python -m repro.measure.cli run --store /tmp/fleet --smoke
+    #    (on a TPU; --interpret runs the Pallas interpreter on a CPU host)
+    python -m repro.measure.cli run --store /tmp/fleet
 
     # 2. refit the time model's machine parameters from a measurement run
     #    (or --synthetic: model-generated timings, the CI recovery check),
@@ -28,6 +29,7 @@ import sys
 import time
 from typing import Optional
 
+from repro.compile_cache import enable_compile_cache
 from repro.service.cli import DEFAULT_STORE, _die, _gpu, _gpu_names
 from repro.service.store import Artifact, ArtifactStore
 
@@ -71,14 +73,23 @@ def _resolve(store: ArtifactStore, key: Optional[str], kind: str) -> Artifact:
 
 
 def cmd_run(args) -> None:
+    import jax
+
     from .harness import default_grid, measure_grid
 
+    backend = jax.default_backend()
+    if backend != "tpu" and not args.interpret:
+        raise _die(
+            f"no TPU attached (jax backend is {backend!r}); measuring needs "
+            "the chip -- pass --interpret to run the Pallas interpreter"
+        )
     store = ArtifactStore(args.store)
     gpu = _gpu(args.gpu)
     grid = default_grid(smoke=not args.full, gpu=gpu)
     t0 = time.perf_counter()
     run = measure_grid(
-        grid, warmup=args.warmup, repeats=args.repeats, gpu=gpu, note=args.note
+        grid, warmup=args.warmup, repeats=args.repeats,
+        interpret=args.interpret, gpu=gpu, note=args.note,
     )
     dt = time.perf_counter() - t0
     art = store.put_json(
@@ -88,13 +99,15 @@ def cmd_run(args) -> None:
             "gpu": gpu.name,
             "stencils": sorted(run.stencil_names()),
             "backend": run.backend,
+            "device_kind": run.device_kind,
             "interpret": run.interpret,
             "records": len(run.records),
         },
     )
     print(
         f"measurement {art.key}: {len(run.records)} records "
-        f"({dt:.1f}s, backend={run.backend}, interpret={run.interpret}, "
+        f"({dt:.1f}s, backend={run.backend}, device={run.device_kind} "
+        f"x{run.device_count}, interpret={run.interpret}, "
         f"gpu frame={gpu.name})"
     )
 
@@ -217,6 +230,9 @@ def main(argv=None) -> None:
                    help="GPU family whose constants frame the fit")
     r.add_argument("--full", action="store_true",
                    help="full grid (default: smoke grid sized for CI)")
+    r.add_argument("--interpret", action="store_true",
+                   help="run the kernels in the Pallas interpreter (CPU "
+                        "lanes); without it a host with no TPU exits 2")
     r.add_argument("--warmup", type=int, default=1)
     r.add_argument("--repeats", type=int, default=3)
     r.add_argument("--note", default="")
@@ -255,6 +271,7 @@ def main(argv=None) -> None:
     b.set_defaults(fn=cmd_build)
 
     args = ap.parse_args(argv)
+    enable_compile_cache()
     args.fn(args)
     sys.stdout.flush()
 

@@ -145,6 +145,11 @@ class MeasurementRun:
     backend: str  # jax backend that executed the kernels
     interpret: bool  # True = Pallas interpret mode (CPU CI lane)
     note: str = ""
+    #: the executing device as JAX reports it (``device_kind``, e.g. "TPU
+    #: v5 lite") and the attached device count; None in runs recorded
+    #: before these fields existed.
+    device_kind: Optional[str] = None
+    device_count: Optional[int] = None
 
     def to_payload(self) -> dict:
         """Plain-JSON payload (the artifact-store manifest body)."""
@@ -152,18 +157,23 @@ class MeasurementRun:
             "records": [r.to_json() for r in self.records],
             "gpu_name": self.gpu_name,
             "backend": self.backend,
+            "device_kind": self.device_kind,
+            "device_count": self.device_count,
             "interpret": bool(self.interpret),
             "note": self.note,
         }
 
     @classmethod
     def from_payload(cls, obj: Mapping) -> "MeasurementRun":
+        count = obj.get("device_count")
         return cls(
             records=[MeasurementRecord.from_json(r) for r in obj["records"]],
             gpu_name=str(obj["gpu_name"]),
             backend=str(obj["backend"]),
             interpret=bool(obj["interpret"]),
             note=str(obj.get("note", "")),
+            device_kind=obj.get("device_kind"),
+            device_count=None if count is None else int(count),
         )
 
     def stencil_names(self) -> List[str]:
@@ -225,13 +235,13 @@ def measure_one(
     tiles: Mapping[str, int],
     warmup: int = 1,
     repeats: int = 3,
-    interpret: Optional[bool] = None,
+    *,
+    interpret: bool,
     hw: Mapping[str, float] = None,
     seed: int = 0,
 ) -> MeasurementRecord:
-    """Time one configuration (median of ``repeats`` fenced runs)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    """Time one configuration (median of ``repeats`` fenced runs).
+    ``interpret`` as in :func:`repro.kernels.pallas_stencils.run_tiled`."""
     hw = dict(STOCK_HW if hw is None else hw)
     tile_tuple = frame_tiles(name, tiles)  # 2D: t_s3 pinned to 1
     x = jax.random.normal(jax.random.PRNGKey(seed), tuple(shape), jnp.float32)
@@ -279,28 +289,33 @@ def default_grid(
     shape (footprint / bandwidth term), time-tile depth (launch-overhead
     term via the pass count), and problem size (compute term). Tile
     candidates are feasibility-filtered against ``gpu``'s family at its
-    :func:`stock_hw` point, so the grid and the fit share one frame.
+    :func:`stock_hw` point, so the grid and the fit share one frame, and
+    every candidate is a legal TPU block (``t_s1`` a multiple of 8,
+    ``t_s2`` of 128; see :func:`repro.kernels.pallas_stencils
+    .chip_tile_error`), so either grid compiles for the chip.
     """
     if smoke:
         shapes_2d = [(48, 64), (96, 128)]
         shapes_3d = [(16, 16, 32)]
         steps = 4
         tile_cands = [
-            {"t_s1": 8, "t_s2": 32, "t_t": 2, "k": 1},
-            {"t_s1": 16, "t_s2": 64, "t_t": 2, "k": 2},
-            {"t_s1": 32, "t_s2": 64, "t_t": 4, "k": 1},
-            {"t_s1": 8, "t_s2": 32, "t_t": 2, "k": 1, "t_s3": 4},
-            {"t_s1": 4, "t_s2": 32, "t_t": 4, "k": 1, "t_s3": 4},
+            {"t_s1": 8, "t_s2": 128, "t_t": 2, "k": 1, "t_s3": 2},
+            {"t_s1": 16, "t_s2": 128, "t_t": 4, "k": 2, "t_s3": 1},
+            {"t_s1": 8, "t_s2": 128, "t_t": 4, "k": 1, "t_s3": 1},
         ]
     else:
         shapes_2d = [(256, 256), (512, 512), (1024, 1024)]
         shapes_3d = [(48, 48, 64), (96, 96, 96)]
         steps = 8
+        # 2-D keeps the first five (t_s3 collapses); the model's footprint
+        # bound leaves 3-D the first, fourth and fifth
         tile_cands = [
-            {"t_s1": 8, "t_s2": 32, "t_t": 2, "k": 1},
-            {"t_s1": 16, "t_s2": 64, "t_t": 2, "k": 2},
-            {"t_s1": 32, "t_s2": 128, "t_t": 4, "k": 4},
-            {"t_s1": 64, "t_s2": 256, "t_t": 8, "k": 2},
+            {"t_s1": 8, "t_s2": 128, "t_t": 2, "k": 1, "t_s3": 2},
+            {"t_s1": 16, "t_s2": 128, "t_t": 4, "k": 2, "t_s3": 1},
+            {"t_s1": 32, "t_s2": 256, "t_t": 4, "k": 1, "t_s3": 1},
+            {"t_s1": 8, "t_s2": 128, "t_t": 4, "k": 1, "t_s3": 1},
+            {"t_s1": 8, "t_s2": 128, "t_t": 2, "k": 1, "t_s3": 4},
+            {"t_s1": 8, "t_s2": 256, "t_t": 8, "k": 1, "t_s3": 1},
         ]
     grid: Dict[str, List[dict]] = {}
     for name, st in STENCILS.items():
@@ -318,17 +333,17 @@ def measure_grid(
     grid: Optional[Dict[str, List[dict]]] = None,
     warmup: int = 1,
     repeats: int = 3,
-    interpret: Optional[bool] = None,
+    *,
+    interpret: bool,
     gpu: GPUSpec = MAXWELL_GPU,
     note: str = "",
 ) -> MeasurementRun:
     """Run every configuration of a :func:`default_grid`-shaped grid.
     Records are stamped with ``gpu``'s family stock hardware point (a
-    config may override with its own ``"hw"``)."""
+    config may override with its own ``"hw"``), the run with the device
+    that executed it."""
     if grid is None:
         grid = default_grid(gpu=gpu)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     records: List[MeasurementRecord] = []
     for name, configs in grid.items():
         for cfg in configs:
@@ -350,4 +365,6 @@ def measure_grid(
         backend=jax.default_backend(),
         interpret=bool(interpret),
         note=note,
+        device_kind=jax.devices()[0].device_kind,
+        device_count=jax.device_count(),
     )

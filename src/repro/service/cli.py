@@ -48,6 +48,7 @@ import urllib.error
 
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from .query import QueryRequest
 from .server import CodesignServer
 from .store import ArtifactStore
@@ -818,6 +819,7 @@ def main(argv=None) -> None:
     g.set_defaults(fn=cmd_gc)
 
     args = ap.parse_args(argv)
+    enable_compile_cache()
     args.fn(args)
 
 

@@ -23,7 +23,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["pipeline_apply", "bubble_fraction"]
 
@@ -59,11 +58,11 @@ def pipeline_apply(
     p_spec = jax.tree.map(lambda _: P(axis), stage_params)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(p_spec, P()),  # params split by stage; data replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def run(params_local, xs_rep):
         # params_local leaves: (1, ...) -- this device's stage

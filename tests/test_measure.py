@@ -67,6 +67,45 @@ def test_measurement_run_payload_round_trip():
         "gtx980", "cpu", True, "x",
     )
     assert back.stencil_names() == ["jacobi2d"]
+    # runs recorded before the device fields existed still load
+    assert (back.device_kind, back.device_count) == (None, None)
+    legacy = run.to_payload()
+    del legacy["device_kind"], legacy["device_count"]
+    assert MeasurementRun.from_payload(legacy).records == run.records
+
+
+def test_measurement_run_names_its_device():
+    from repro.measure.harness import measure_grid
+
+    run = measure_grid(
+        {"jacobi2d": [{"shape": (16, 24), "steps": 2,
+                       "tiles": {"t_s1": 8, "t_s2": 128, "t_t": 2}}]},
+        warmup=0, repeats=1, interpret=True,
+    )
+    import jax
+
+    assert run.device_kind == jax.devices()[0].device_kind
+    assert run.device_count == jax.device_count()
+    back = MeasurementRun.from_payload(run.to_payload())
+    assert (back.device_kind, back.device_count) == (
+        run.device_kind, run.device_count,
+    )
+
+
+def test_measure_cli_run_without_chip_exits_2(tmp_path, monkeypatch, capsys):
+    """No TPU and no --interpret: one line, exit 2, nothing measured."""
+    import jax
+
+    from repro.measure import cli
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    monkeypatch.setattr(cli, "enable_compile_cache", lambda: None)
+    with pytest.raises(SystemExit) as exc:
+        cli.main(["run", "--store", str(tmp_path)])
+    assert exc.value.code == 2
+    err = capsys.readouterr().err.strip().splitlines()
+    assert len(err) == 1 and "--interpret" in err[0]
+    assert not any(tmp_path.iterdir())
 
 
 def test_feasible_tiles_filters_model_infeasible():

@@ -13,6 +13,7 @@ from _hypothesis_compat import given, settings, st  # soft dep: skips, not error
 from repro.kernels.pallas_stencils import (
     DEFAULT_TILES,
     TILE_NAMES,
+    chip_tile_error,
     normalize_tiles,
     run_tiled,
     tile_footprint_cells,
@@ -87,7 +88,8 @@ def test_3d_tile_grid_matches_oracle(name, tiles):
 def test_bf16_inputs_upcast_like_reference(name):
     shape = (24, 40) if name == "heat2d" else (10, 12, 14)
     x = _rand(shape, jnp.bfloat16, seed=3)
-    got = run_tiled(name, x, steps=2, tiles={"t_s1": 8, "t_s2": 32, "t_t": 2})
+    got = run_tiled(name, x, steps=2, tiles={"t_s1": 8, "t_s2": 32, "t_t": 2},
+                   interpret=True)
     want = run_ref(name, x, steps=2)
     assert got.dtype == x.dtype
     np.testing.assert_allclose(
@@ -102,7 +104,8 @@ def test_k_is_occupancy_only():
     x = _rand((29, 31), seed=4)
     outs = [
         np.asarray(run_tiled("jacobi2d", x, steps=3,
-                             tiles={"t_s1": 8, "t_s2": 32, "t_t": 2, "k": k}))
+                             tiles={"t_s1": 8, "t_s2": 32, "t_t": 2, "k": k},
+                             interpret=True))
         for k in (1, 8, 32)
     ]
     for o in outs[1:]:
@@ -114,13 +117,15 @@ def test_time_tile_depth_is_semantics_preserving():
     x = _rand((25, 45), seed=5)
     want = run_ref("heat2d", x, steps=7)
     for t_t in (1, 2, 3, 7, 16):
-        got = run_tiled("heat2d", x, steps=7, tiles={"t_s1": 8, "t_s2": 32, "t_t": t_t})
+        got = run_tiled("heat2d", x, steps=7,
+                        tiles={"t_s1": 8, "t_s2": 32, "t_t": t_t}, interpret=True)
         assert_close(got, want)
 
 
 def test_borders_are_dirichlet():
     x = _rand((18, 22), seed=6)
-    y = run_tiled("laplacian2d", x, steps=3, tiles={"t_s1": 4, "t_s2": 32, "t_t": 2})
+    y = run_tiled("laplacian2d", x, steps=3,
+                  tiles={"t_s1": 4, "t_s2": 32, "t_t": 2}, interpret=True)
     np.testing.assert_array_equal(np.asarray(y[0]), np.asarray(x[0]))
     np.testing.assert_array_equal(np.asarray(y[-1]), np.asarray(x[-1]))
     np.testing.assert_array_equal(np.asarray(y[:, 0]), np.asarray(x[:, 0]))
@@ -135,14 +140,14 @@ def test_normalize_tiles_contract():
     with pytest.raises(ValueError, match=">= 1"):
         normalize_tiles({"t_t": 0})
     with pytest.raises(KeyError, match="unknown stencil"):
-        run_tiled("nosuch", jnp.zeros((4, 4)), steps=1)
+        run_tiled("nosuch", jnp.zeros((4, 4)), steps=1, interpret=True)
     with pytest.raises(ValueError, match="steps"):
-        run_tiled("heat2d", jnp.zeros((4, 4)), steps=-1)
+        run_tiled("heat2d", jnp.zeros((4, 4)), steps=-1, interpret=True)
 
 
 def test_zero_steps_is_identity():
     x = _rand((9, 9), seed=7)
-    assert run_tiled("heat2d", x, steps=0) is x
+    assert run_tiled("heat2d", x, steps=0, interpret=True) is x
 
 
 def test_footprint_grows_with_time_tile():
@@ -165,6 +170,32 @@ def test_footprint_grows_with_time_tile():
 def test_property_2d_any_tile_allclose(name, rows, cols, t_s1, t_s2, t_t, steps):
     x = _rand((rows, cols), seed=rows * cols)
     got = run_tiled(name, x, steps=steps,
-                    tiles={"t_s1": t_s1, "t_s2": t_s2, "t_t": t_t})
+                    tiles={"t_s1": t_s1, "t_s2": t_s2, "t_t": t_t}, interpret=True)
     want = run_ref(name, x, steps=steps)
     assert_close(got, want)
+
+
+@pytest.mark.parametrize(
+    "shape,tiles,legal",
+    [
+        ((1024, 1024), DEFAULT_TILES, True),
+        ((96, 96, 96), DEFAULT_TILES, True),  # t_s3 blocks the leading axis
+        ((1024, 1024), {"t_s1": 8, "t_s2": 32}, False),  # lanes want 128
+        ((1024, 1024), {"t_s1": 4, "t_s2": 128}, False),  # sublanes want 8
+        ((37, 53), {"t_s1": 64, "t_s2": 64}, True),  # one tile, whole extent
+        ((37, 53), {"t_s1": 5, "t_s2": 128}, False),
+    ],
+)
+def test_chip_tile_rule(shape, tiles, legal):
+    err = chip_tile_error(shape, tiles)
+    assert (err is None) == legal
+    if not legal:
+        assert "multiple of" in err
+
+
+def test_compiled_run_rejects_illegal_tile_before_lowering():
+    """interpret=False with a tile the TPU cannot block raises the rule
+    itself (on any backend -- nothing is lowered), never a skip."""
+    x = _rand((64, 256), seed=8)
+    with pytest.raises(ValueError, match="t_s2=32 \\(multiple of 128"):
+        run_tiled("heat2d", x, steps=2, tiles={"t_s2": 32}, interpret=False)

@@ -114,7 +114,7 @@ def test_ref_float64_inputs_keep_dtype_and_f32_accuracy(name):
     oracle share an arithmetic contract across input dtypes)."""
     shape = (5, 7, 9) if name in NAMES_3D else (7, 9)
     x = _rand(shape, seed=42)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         xin = jnp.asarray(x, jnp.float64)
         assert xin.dtype == jnp.float64
         got = run_ref(name, xin, steps=1)

@@ -128,3 +128,27 @@ def test_work_scaling(t_t, scale):
     t1 = _t("jacobi2d", small, 16, 128, 96, t_t=t_t)
     t2 = _t("jacobi2d", big, 16, 128, 96, t_t=t_t)
     assert t2 == pytest.approx(t1 * scale, rel=0.02)
+
+
+class _UlpOff(np.ndarray):
+    """Division that lands one ulp off the correctly rounded quotient, as
+    an accelerator's reciprocal-based f32 divide may."""
+
+    direction = np.inf
+
+    def __truediv__(self, other):
+        q = np.asarray(self) / np.asarray(other)
+        return np.nextafter(q, self.direction)
+
+
+@pytest.mark.parametrize("direction", [np.inf, -np.inf])
+def test_ceil_div_exact_under_inexact_division(direction):
+    from repro.core.timemodel import _ceil_div
+
+    a = np.array([4096.0, 100.0, 96.0, 1.0, 2048.0], np.float32)
+    b = np.array([128.0, 32.0, 32.0, 1.0, 6.0], np.float32)
+    want = np.ceil(a.astype(np.float64) / b)
+    off = a.view(_UlpOff)
+    off.direction = direction
+    np.testing.assert_array_equal(np.asarray(_ceil_div(np, off, b)), want)
+    np.testing.assert_array_equal(_ceil_div(np, a, b), want)
