@@ -1,8 +1,8 @@
 """Codesign query service: queries/sec cold (artifact miss -> full eq.-18
 sweep) vs warm (stored artifact -> vectorized re-reductions), then the
 fleet gateway's tax on top of warm (routing + LRU server pool, locally
-and over the HTTP wire), and the observability tax (repro.obs metrics +
-spans on vs disabled, asserted under 5%).
+and over the HTTP wire), and the resilience layer's tax (asserted under
+5%).
 
 Cold is measured against a throwaway store so the number is honest even
 when CI restored the persistent artifact cache; warm is measured against
@@ -29,7 +29,6 @@ import time
 import numpy as np
 
 from repro.core.timemodel import TITANX_GPU
-from repro.obs.metrics import set_disabled
 from repro.service import (
     ArtifactStore,
     CodesignServer,
@@ -195,33 +194,13 @@ def run() -> None:
         t_http_many = time.perf_counter() - t0
         assert all(not isinstance(x, Exception) for x in results)
 
-        # (d) observability tax: the same batched round trip with the
-        # repro.obs registry live vs disabled (the in-process switch behind
-        # REPRO_OBS_DISABLED=1; the server runs in THIS process, so the
-        # toggle covers both sides of the wire). Alternating best-of-4 laps
-        # de-noise the A/B before the <5% acceptance gate below.
-        t_obs = {False: float("inf"), True: float("inf")}
-        try:
-            for _ in range(4):
-                for disabled in (False, True):
-                    set_disabled(disabled)
-                    t0 = time.perf_counter()
-                    obs_results = client.query_many(batch_http)
-                    t_obs[disabled] = min(
-                        t_obs[disabled], time.perf_counter() - t0
-                    )
-                    assert all(
-                        not isinstance(x, Exception) for x in obs_results
-                    )
-        finally:
-            set_disabled(None)  # back to whatever the env says
-
-        # (e) resilience tax: the same batched round trip with the
+        # (d) resilience tax: the same batched round trip with the
         # admission-control + breaker + deadline layer live (the default
         # permissive GatewayResilience bundle) vs resilience=None. The
         # happy path through the layer is a handful of no-op checks
         # (inflight counter, disabled buckets, one contextvar read), so
-        # this A/B holds it to the same <5% ceiling as observability.
+        # this A/B holds it to a <5% ceiling. Alternating best-of-4 laps
+        # de-noise the A/B.
         res_bundle = gw.resilience
         t_res = {True: float("inf"), False: float("inf")}
         try:
@@ -259,19 +238,6 @@ def run() -> None:
         f"{qps_http_many:.0f} q/s",
     )
 
-    qps_obs_on = len(batch_http) / t_obs[False]
-    qps_obs_off = len(batch_http) / t_obs[True]
-    overhead = 1.0 - qps_obs_on / qps_obs_off
-    emit(
-        "service_obs_overhead", t_obs[False] / len(batch_http) * 1e6,
-        f"metrics+spans on {qps_obs_on:.0f} q/s vs off {qps_obs_off:.0f} q/s "
-        f"({overhead * 100:+.1f}% tax; acceptance ceiling 5%)",
-    )
-    assert overhead < 0.05, (
-        f"observability tax {overhead * 100:.1f}% >= 5% "
-        f"(on {qps_obs_on:.0f} q/s, off {qps_obs_off:.0f} q/s)"
-    )
-
     qps_res_on = len(batch_http) / t_res[True]
     qps_res_off = len(batch_http) / t_res[False]
     res_overhead = 1.0 - qps_res_on / qps_res_off
@@ -301,9 +267,6 @@ def run() -> None:
             "gateway_http_conn_per_req_qps": round(qps_http_cpr, 1),
             "gateway_http_qps": round(qps_gw_http, 1),
             "gateway_http_batched_qps": round(qps_http_many, 1),
-            "obs_on_qps": round(qps_obs_on, 1),
-            "obs_off_qps": round(qps_obs_off, 1),
-            "obs_overhead_pct": round(overhead * 100, 2),
             "resilience_on_qps": round(qps_res_on, 1),
             "resilience_off_qps": round(qps_res_off, 1),
             "resilience_overhead_pct": round(res_overhead * 100, 2),

@@ -170,8 +170,11 @@ def phase_sweep(store_dir, oracle_dir):
     snap = get_registry().snapshot()["repro_sweep_dispatch_seconds"]["samples"]
     by_phase = {s["labels"]["phase"]: s for s in snap if s["labels"]["engine"] == "jax"}
     parts = [f"{p} {by_phase[p]['count']} dispatches {by_phase[p]['sum']:.3f}s"
-             for p in ("first", "steady") if p in by_phase]
-    say("sweep", "jax dispatch seconds (first = compile included): " + ", ".join(parts))
+             for p in ("compile", "steady") if p in by_phase]
+    compiles = get_registry().snapshot()["repro_sweep_compiles_total"]["samples"]
+    n = sum(s["value"] for s in compiles if s["labels"]["engine"] == "jax")
+    say("sweep", f"jax dispatch seconds ({n:.0f} programs compiled or loaded): "
+        + ", ".join(parts))
     return store, arts, oracles
 
 

@@ -38,7 +38,7 @@ from .solver import LATTICE_2D, LATTICE_3D, TileLattice, decode_index, solve_cel
 from .timemodel import GPUSpec, MAXWELL_GPU, ProblemSize, stencil_time
 from .workload import Workload, WorkloadCell
 
-# ---- observability (repro.obs; no-ops under REPRO_OBS_DISABLED=1) --------
+# ---- observability (repro.obs) -------------------------------------------
 _REG = _obs_registry()
 _M_CODESIGN_SECONDS = _REG.histogram(
     "repro_codesign_seconds",
@@ -510,11 +510,11 @@ def codesign(
                 )
                 cell_time[ci] = t
                 cell_idx[ci] = i
-            # the seed oracle has no per-dispatch hook of its own: account
-            # its cell evaluations here so engine throughput is comparable
-            from repro.core.sweep import _M_CELL_EVALS
+            # the seed oracle has no per-dispatch hook of its own: count
+            # its optima here so engine throughput is comparable
+            from repro.core.sweep import _M_OPTIMA
 
-            _M_CELL_EVALS.labels(engine="numpy").inc(C * H)
+            _M_OPTIMA.labels(engine="numpy").inc(C * H)
     _M_CODESIGN_SECONDS.labels(engine=eng, family="stencil").observe(
         time.perf_counter() - t0
     )

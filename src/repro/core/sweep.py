@@ -36,15 +36,17 @@ to the NumPy reference solver instead of this module.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time
 import warnings
-from typing import Dict, Tuple
+from typing import Dict, Iterator, Tuple
 
 import numpy as np
 
 from repro.obs.metrics import get_registry as _obs_registry
+from repro.obs.trace import set_attrs, span
 
 from .solver import TileLattice
 from .solver import _STEPS as _SOLVER_STEPS
@@ -90,43 +92,70 @@ SW_NAMES = ("t_s1", "t_s2", "t_t", "k", "t_s3")
 SW_STEPS = tuple(float(_SOLVER_STEPS[k]) for k in SW_NAMES)
 SW_MINS = tuple(1.0 if k == "t_s1" else float(_SOLVER_STEPS[k]) for k in SW_NAMES)
 
-# ---- observability (repro.obs; no-ops under REPRO_OBS_DISABLED=1) --------
+# ---- observability (repro.obs) -------------------------------------------
 _REG = _obs_registry()
 _M_DISPATCH_SECONDS = _REG.histogram(
     "repro_sweep_dispatch_seconds",
-    "wall time of one compiled sweep dispatch (solve call through host "
-    "materialization), split by engine and compile phase: 'first' is the "
-    "initial dispatch of a (solver, shape) pair -- XLA tracing + "
-    "compilation included -- 'steady' is every re-dispatch of the cached "
-    "executable. An approximation of compile-vs-execute: jax keys its "
-    "executable cache the same way",
+    "wall time of one compiled sweep dispatch (solver lookup, input "
+    "conversion, solve call through host materialization), split by "
+    "engine and phase: 'compile' when JAX compiled a program or loaded "
+    "one from its persistent cache during the dispatch, 'steady' "
+    "otherwise",
     labels=("engine", "phase"),
 )
-_M_CELL_EVALS = _REG.counter(
-    "repro_sweep_cell_evals_total",
-    "optima-matrix entries produced (P sizes x H hardware points per "
-    "dispatch) -- divide by dispatch seconds for cells/sec",
+_M_OPTIMA = _REG.counter(
+    "repro_sweep_optima_total",
+    "optima-matrix entries produced: P problem sizes x H hardware points "
+    "per dispatch (each the argmin over a whole tile lattice)",
+    labels=("engine",),
+)
+_M_COMPILES = _REG.counter(
+    "repro_sweep_compiles_total",
+    "programs JAX compiled or loaded from its persistent cache during "
+    "sweep dispatches, counted on the dispatching thread",
     labels=("engine",),
 )
 
-#: (solver id, shapes) pairs whose first (compiling) dispatch has been
-#: seen; cleared alongside the solver caches in :func:`clear_caches`.
-_DISPATCH_SEEN: set = set()
-_DISPATCH_MU = threading.Lock()
+#: JAX's monitoring event around each backend compile, a load from the
+#: persistent compilation cache included.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-def _note_dispatch(engine: str, cache_key: tuple, p: int, h: int, dt: float) -> None:
-    """Record one dispatch, classified first/steady by whether this
-    (solver, shape) pair has dispatched before (mirrors jax's retrace
-    rule: a cached solver re-invoked on new shapes recompiles)."""
-    with _DISPATCH_MU:
-        first = cache_key not in _DISPATCH_SEEN
-        if first:
-            _DISPATCH_SEEN.add(cache_key)
+class _ThreadCompiles(threading.local):
+    n = 0
+
+
+_COMPILES = _ThreadCompiles()
+
+
+def _on_compile_event(event: str, duration: float, **_) -> None:
+    # JAX compiles on the thread that dispatches, so a per-thread count
+    # read before and after a dispatch is that dispatch's own
+    if event == _COMPILE_EVENT:
+        _COMPILES.n += 1
+
+
+if HAVE_JAX:
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
+@contextlib.contextmanager
+def _dispatch(engine: str, dims: int, p: int, h: int) -> Iterator[None]:
+    """One compiled dispatch: the ``sweep.dispatch`` span, its compile
+    count (the span's ``compiles`` attr), the phase-split wall time and
+    the optima counter."""
+    with span("sweep.dispatch", engine=engine, dims=dims, p=p, h=h):
+        n0 = _COMPILES.n
+        t0 = time.perf_counter()
+        yield
+        dt = time.perf_counter() - t0
+        compiles = _COMPILES.n - n0
+        set_attrs(compiles=compiles)
     _M_DISPATCH_SECONDS.labels(
-        engine=engine, phase="first" if first else "steady"
+        engine=engine, phase="compile" if compiles else "steady"
     ).observe(dt)
-    _M_CELL_EVALS.labels(engine=engine).inc(p * h)
+    _M_COMPILES.labels(engine=engine).inc(compiles)
+    _M_OPTIMA.labels(engine=engine).inc(p * h)
 
 
 def _require_jax():
@@ -262,7 +291,6 @@ def _cells_solver(dims: int, gpu: GPUSpec, lattice: TileLattice, chunk: int):
         return _solve_empty
     best_of = _best_of_factory(gpu, lat, keep_idx)
 
-    @jax.jit
     def solve(n_sm, n_v, m_sm, sizes, radius, c_iter, n_arrays):
         st = _traced_spec(dims, radius, c_iter, n_arrays)
         hw = jnp.stack([n_sm, n_v, m_sm], axis=1)  # (H, 3)
@@ -282,7 +310,9 @@ def _cells_solver(dims: int, gpu: GPUSpec, lattice: TileLattice, chunk: int):
         best_i = jnp.moveaxis(best_i, 0, 1).reshape(sizes.shape[0], -1)[:, :h]
         return best_t, best_i
 
-    return solve
+    # the program's name on a device trace: jit_sweep_2d / jit_sweep_3d
+    solve.__name__ = solve.__qualname__ = f"sweep_{dims}d"
+    return jax.jit(solve)
 
 
 @functools.lru_cache(maxsize=None)
@@ -339,6 +369,7 @@ def _sharded_cells_solver(
 
         return lax.fori_loop(0, h // chunk, one_chunk, (out_t, out_i))
 
+    shard_body.__name__ = shard_body.__qualname__ = f"sweep_{dims}d_sharded"
     sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
@@ -383,28 +414,22 @@ def sweep_cells(
     """
     _require_jax()
     lattice, sizes, chunk = _prep_cells(st, sizes, lattice, chunk)
-    solve = _cells_solver(st.dims, gpu, lattice, chunk)
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
     h = np.asarray(n_sm).size
-    t0 = time.perf_counter()
-    best_t, best_i = solve(
-        f32(np.asarray(n_sm).ravel()),
-        f32(np.asarray(n_v).ravel()),
-        f32(np.asarray(m_sm).ravel()),
-        f32(sizes),
-        f32(st.radius),
-        f32(st.c_iter),
-        f32(st.n_arrays),
-    )
-    out = (
-        np.asarray(best_t, np.float64),  # blocks until the dispatch is done
-        np.asarray(best_i, np.int64),
-    )
-    _note_dispatch(
-        "jax", (id(solve), sizes.shape, h), sizes.shape[0], h,
-        time.perf_counter() - t0,
-    )
-    return out
+    with _dispatch("jax", st.dims, sizes.shape[0], h):
+        solve = _cells_solver(st.dims, gpu, lattice, chunk)
+        best_t, best_i = solve(
+            f32(np.asarray(n_sm).ravel()),
+            f32(np.asarray(n_v).ravel()),
+            f32(np.asarray(m_sm).ravel()),
+            f32(sizes),
+            f32(st.radius),
+            f32(st.c_iter),
+            f32(st.n_arrays),
+        )
+        with span("sweep.fetch"):
+            # blocks until the dispatch is done, then copies and casts
+            return np.asarray(best_t, np.float64), np.asarray(best_i, np.int64)
 
 
 def sweep_cells_sharded(
@@ -456,34 +481,29 @@ def sweep_cells_sharded(
     h_pad = -(-h // quantum) * quantum
     if h_pad != h:
         cols = [np.concatenate([a, np.full(h_pad - h, a[0], a.dtype)]) for a in cols]
-    mesh, solve = _sharded_cells_solver(st.dims, gpu, lattice, chunk, devs)
-    shard = NamedSharding(mesh, P("hw"))
-    repl = NamedSharding(mesh, P())
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
-    t0 = time.perf_counter()
-    with warnings.catch_warnings():
-        # the hw slabs are donated for accelerator meshes (dead after the
-        # stack); on hosts where no output can alias them XLA drops the
-        # donation and warns -- expected, not actionable.
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable"
-        )
-        best_t, best_i = solve(
-            *(jax.device_put(a, shard) for a in cols),
-            jax.device_put(f32(sizes), repl),
-            f32(st.radius),
-            f32(st.c_iter),
-            f32(st.n_arrays),
-        )
-    out = (
-        np.asarray(best_t, np.float64)[:, :h],  # blocks on the dispatch
-        np.asarray(best_i, np.int64)[:, :h],
-    )
-    _note_dispatch(
-        "sharded", (id(solve), sizes.shape, h_pad), sizes.shape[0], h,
-        time.perf_counter() - t0,
-    )
-    return out
+    with _dispatch("sharded", st.dims, sizes.shape[0], h):
+        mesh, solve = _sharded_cells_solver(st.dims, gpu, lattice, chunk, devs)
+        shard = NamedSharding(mesh, P("hw"))
+        repl = NamedSharding(mesh, P())
+        with warnings.catch_warnings():
+            # the hw slabs are donated for accelerator meshes (dead after
+            # the stack); on hosts where no output can alias them XLA drops
+            # the donation and warns -- expected, not actionable.
+            warnings.filterwarnings(
+                "ignore", message="Some donated buffers were not usable"
+            )
+            best_t, best_i = solve(
+                *(jax.device_put(a, shard) for a in cols),
+                jax.device_put(f32(sizes), repl),
+                f32(st.radius),
+                f32(st.c_iter),
+                f32(st.n_arrays),
+            )
+        with span("sweep.fetch"):
+            # blocks on the dispatch, then copies, casts and drops the padding
+            return (np.asarray(best_t, np.float64)[:, :h],
+                    np.asarray(best_i, np.int64)[:, :h])
 
 
 def sweep_cell(
@@ -653,7 +673,3 @@ def clear_caches() -> None:
     _cells_solver.cache_clear()
     _sharded_cells_solver.cache_clear()
     _refine_descent.cache_clear()
-    with _DISPATCH_MU:
-        # cleared solvers recompile, so their next dispatch is 'first'
-        # again (and a recycled id() must not classify it 'steady')
-        _DISPATCH_SEEN.clear()

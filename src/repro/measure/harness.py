@@ -39,7 +39,7 @@ from repro.core.timemodel import (
 from repro.kernels.pallas_stencils import TILE_NAMES, normalize_tiles, run_tiled
 from repro.obs.metrics import get_registry as _obs_registry
 
-# ---- observability (repro.obs; no-ops under REPRO_OBS_DISABLED=1) --------
+# ---- observability (repro.obs) -------------------------------------------
 _REG = _obs_registry()
 _M_POINTS = _REG.counter(
     "repro_measure_points_total",

@@ -7,18 +7,18 @@ sweep/serve/gateway system (see ``docs/observability.md``):
   counters, gauges, and fixed-bucket histograms with snapshot/reset
   semantics and two exporters (Prometheus text + canonical JSON). The
   gateway serves it at ``GET /v1/metrics``.
-* :mod:`repro.obs.trace`   -- context-manager spans over the monotonic
-  clock with parent/child nesting and a per-request trace id that rides
-  the HTTP wire as an ``X-Repro-Trace`` header; a ``"trace": true``
+* :mod:`repro.obs.trace`   -- context-manager spans, each also a
+  ``repro.<name>`` profiler annotation on the device trace's clock; inside
+  a request trace they nest under a per-request trace id that rides the
+  HTTP wire as an ``X-Repro-Trace`` header, and a ``"trace": true``
   request envelope field returns the span tree in the response.
 * :mod:`repro.obs.logging` -- structured JSON line logging with a
   verbosity knob (the CLI ``serve --log-level`` flag).
 
 Design rule: observability is **additive, never on the answer path**.
-Untraced ``/v1/query`` responses stay byte-identical whether or not
-instrumentation is enabled, and ``REPRO_OBS_DISABLED=1`` turns every
-metric into a no-op (asserted < 5% throughput delta in
-``benchmarks/bench_service.py``).
+Untraced ``/v1/query`` responses stay byte-identical to the
+pre-instrumentation ones. What tracing costs is measured by the
+benchmark's traced against untraced runs (``bench/run.py --trace 0|1``).
 """
 
 from .exemplar import ExemplarStore  # noqa: F401
@@ -35,7 +35,6 @@ from .metrics import (  # noqa: F401
     Histogram,
     Registry,
     get_registry,
-    set_disabled,
 )
 from .trace import (  # noqa: F401
     TRACE_HEADER,
@@ -43,6 +42,7 @@ from .trace import (  # noqa: F401
     current_span,
     current_trace_id,
     new_trace_id,
+    set_attrs,
     span,
     trace,
 )

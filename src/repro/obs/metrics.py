@@ -18,9 +18,6 @@ built for hot paths measured in microseconds:
   format, version 0.0.4) and :meth:`Registry.render_json` (canonical JSON:
   sorted keys, compact separators -- equal states always render to equal
   bytes). Both render from the same snapshot so they can never disagree.
-* **kill switch** -- ``REPRO_OBS_DISABLED=1`` (or :func:`set_disabled`)
-  turns ``inc``/``set``/``observe`` into early returns on every child of
-  the default registry. Instrumented code never needs to branch.
 
 Histograms use **fixed buckets** chosen at registration (defaults:
 :data:`LATENCY_BUCKETS` seconds / :data:`SIZE_BUCKETS` counts); bucket
@@ -33,7 +30,6 @@ from __future__ import annotations
 import bisect
 import json
 import math
-import os
 import threading
 import time
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
@@ -47,12 +43,7 @@ __all__ = [
     "Family",
     "Registry",
     "get_registry",
-    "set_disabled",
 ]
-
-#: env var disabling the DEFAULT registry's instrumentation at import
-#: (benchmarks A/B the overhead against exactly this knob).
-DISABLED_ENV = "REPRO_OBS_DISABLED"
 
 #: default histogram buckets for wall-time observations, in seconds:
 #: 50 us (an LRU-hit query) up through 10 s (a cold sweep build).
@@ -68,16 +59,13 @@ SIZE_BUCKETS: Tuple[float, ...] = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)
 class Counter:
     """Monotonically increasing float (negative increments rejected)."""
 
-    __slots__ = ("_mu", "_value", "_family")
+    __slots__ = ("_mu", "_value")
 
-    def __init__(self, family: "Family"):
+    def __init__(self):
         self._mu = threading.Lock()
         self._value = 0.0
-        self._family = family
 
     def inc(self, n: float = 1.0) -> None:
-        if self._family._registry.disabled:
-            return
         if n < 0:
             raise ValueError(f"counter increment must be >= 0, got {n}")
         with self._mu:
@@ -99,22 +87,17 @@ class Counter:
 class Gauge:
     """A value that goes up and down (pool occupancy, last-access stamp)."""
 
-    __slots__ = ("_mu", "_value", "_family")
+    __slots__ = ("_mu", "_value")
 
-    def __init__(self, family: "Family"):
+    def __init__(self):
         self._mu = threading.Lock()
         self._value = 0.0
-        self._family = family
 
     def set(self, v: float) -> None:
-        if self._family._registry.disabled:
-            return
         with self._mu:
             self._value = float(v)
 
     def inc(self, n: float = 1.0) -> None:
-        if self._family._registry.disabled:
-            return
         with self._mu:
             self._value += n
 
@@ -143,9 +126,9 @@ class Histogram:
     bucket's cumulative count is the overflow.
     """
 
-    __slots__ = ("_mu", "_buckets", "_counts", "_sum", "_count", "_family")
+    __slots__ = ("_mu", "_buckets", "_counts", "_sum", "_count")
 
-    def __init__(self, family: "Family", buckets: Sequence[float]):
+    def __init__(self, buckets: Sequence[float]):
         b = tuple(float(x) for x in buckets)
         if not b or any(b[i] >= b[i + 1] for i in range(len(b) - 1)):
             raise ValueError(f"buckets must be strictly increasing, got {b}")
@@ -154,11 +137,8 @@ class Histogram:
         self._counts = [0] * len(b)
         self._sum = 0.0
         self._count = 0
-        self._family = family
 
     def observe(self, v: float) -> None:
-        if self._family._registry.disabled:
-            return
         v = float(v)
         i = bisect.bisect_left(self._buckets, v)
         with self._mu:
@@ -220,11 +200,10 @@ class Family:
     child, so unlabeled metrics read naturally (``family.inc()``)."""
 
     __slots__ = ("name", "help", "kind", "labelnames", "_buckets",
-                 "_children", "_mu", "_registry")
+                 "_children", "_mu")
 
     def __init__(
         self,
-        registry: "Registry",
         name: str,
         help: str,
         kind: str,
@@ -246,12 +225,11 @@ class Family:
             self._buckets = None
         self._children: Dict[Tuple[str, ...], Any] = {}
         self._mu = threading.Lock()
-        self._registry = registry
 
     def _make_child(self):
         if self.kind == "histogram":
-            return Histogram(self, self._buckets or LATENCY_BUCKETS)
-        return _KINDS[self.kind](self)
+            return Histogram(self._buckets or LATENCY_BUCKETS)
+        return _KINDS[self.kind]()
 
     def labels(self, **kv: Any):
         """The child for one label-value assignment (cached). Values are
@@ -334,12 +312,9 @@ class Registry:
     init order never matters; a *conflicting* re-registration raises.
     """
 
-    def __init__(self, disabled: Optional[bool] = None):
+    def __init__(self):
         self._mu = threading.Lock()
         self._families: Dict[str, Family] = {}
-        if disabled is None:
-            disabled = os.environ.get(DISABLED_ENV, "") == "1"
-        self.disabled = bool(disabled)
 
     # ---- registration -----------------------------------------------------
     def _register(
@@ -360,7 +335,7 @@ class Registry:
                         f"{tuple(labels)}"
                     )
                 return fam
-            fam = Family(self, name, help, kind, labels, buckets)
+            fam = Family(name, help, kind, labels, buckets)
             self._families[name] = fam
             return fam
 
@@ -456,19 +431,10 @@ def _labelstr(labels: Mapping[str, Any]) -> str:
 
 
 #: THE default registry every instrumented subsystem registers into (and
-#: the one ``GET /v1/metrics`` serves). Honors ``REPRO_OBS_DISABLED=1``.
+#: the one ``GET /v1/metrics`` serves).
 _DEFAULT = Registry()
 
 
 def get_registry() -> Registry:
     return _DEFAULT
 
-
-def set_disabled(disabled: Optional[bool] = None) -> bool:
-    """Flip the default registry's kill switch; ``None`` re-reads
-    :data:`DISABLED_ENV` (how benchmarks A/B the instrumentation overhead
-    in-process). Returns the new state."""
-    if disabled is None:
-        disabled = os.environ.get(DISABLED_ENV, "") == "1"
-    _DEFAULT.disabled = bool(disabled)
-    return _DEFAULT.disabled

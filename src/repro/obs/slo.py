@@ -343,7 +343,7 @@ class SLOTracker:
         private registry so families/labels render in the exact same
         format as ``/v1/metrics``."""
         rep = self.report() if report is None else report
-        reg = Registry(disabled=False)
+        reg = Registry()
         burn = reg.gauge(
             "repro_slo_burn_rate",
             "error-budget burn rate (1.0 = spending exactly the budget)",

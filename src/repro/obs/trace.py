@@ -1,10 +1,22 @@
-"""Context-manager spans over the monotonic clock.
+"""Context-manager spans over the monotonic clock, and on the profiler's.
 
-A *trace* is one request's tree of timed spans. The API is built around
+Every :func:`span` and :func:`trace` has two outputs:
+
+* a profiler annotation named ``repro.<name>`` carrying the span's attrs
+  as its arguments (:class:`jax.profiler.TraceAnnotation`). Under a
+  profiler session it lands on the host planes of the ``.xplane.pb``, on
+  the same clock as the device's ``XLA Ops``, so an idle gap on the chip
+  can be put down to the innermost span around it. With no session it
+  costs about a microsecond; in a process that has not imported JAX it is
+  a null context (``repro.obs`` itself never imports JAX);
+* inside a request trace, a node of that request's span tree.
+
+A *trace* is one request's tree of timed spans. The tree is built around
 two costs-nothing-when-off invariants:
 
 * With no active trace, :func:`span` yields ``None`` without allocating a
-  node -- instrumented code pays one contextvar read.
+  node -- instrumented code pays one contextvar read (plus the
+  annotation).
 * Span trees are plain dicts the moment the root closes, so encoding them
   is just JSON; nothing observability-shaped touches the answer path.
 
@@ -30,6 +42,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import sys
 import time
 import uuid
 from typing import Any, Dict, Iterator, List, Optional
@@ -40,6 +53,7 @@ __all__ = [
     "current_span",
     "current_trace_id",
     "new_trace_id",
+    "set_attrs",
     "span",
     "trace",
 ]
@@ -48,9 +62,69 @@ __all__ = [
 #: back when the client supplied one, minted by the gateway otherwise.
 TRACE_HEADER = "X-Repro-Trace"
 
+#: prefix of every span's profiler annotation (the trace reducers'
+#: filter for the program's own host events).
+ANNOTATION_PREFIX = "repro."
+
 _ACTIVE: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
     "repro_obs_active_span", default=None
 )
+#: the innermost open annotation on this context, for :func:`set_attrs`.
+_ANNOTATION: contextvars.ContextVar[Any] = contextvars.ContextVar(
+    "repro_obs_annotation", default=None
+)
+
+
+class _NullAnnotation:
+    """Stands in for the profiler annotation where JAX is not loaded."""
+
+    __slots__ = ()
+
+    def __init__(self, *name: str, **attrs: Any):
+        pass
+
+    def __enter__(self) -> "_NullAnnotation":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **attrs: Any) -> None:
+        return None
+
+
+_NULL_ANNOTATION = _NullAnnotation()
+_ANNOTATION_CLS: Any = None
+
+
+def _annotation(name: str, attrs: Dict[str, Any]) -> Any:
+    """A ``repro.<name>`` profiler annotation, or a null one.
+
+    A profiler session needs JAX in this process, so until something else
+    imports JAX there is nothing to record and nothing is imported here;
+    the class is looked up once JAX is loaded and kept."""
+    global _ANNOTATION_CLS
+    cls = _ANNOTATION_CLS
+    if cls is None:
+        if "jax" not in sys.modules:
+            return _NULL_ANNOTATION
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:  # a JAX that fails to import
+            cls = _NullAnnotation
+        _ANNOTATION_CLS = cls
+    return cls(ANNOTATION_PREFIX + name, **attrs)
+
+
+@contextlib.contextmanager
+def _annotated(name: str, attrs: Dict[str, Any]) -> Iterator[None]:
+    annotation = _annotation(name, attrs)
+    with annotation:
+        token = _ANNOTATION.set(annotation)
+        try:
+            yield
+        finally:
+            _ANNOTATION.reset(token)
 
 
 def new_trace_id() -> str:
@@ -136,28 +210,44 @@ def trace(
     """Open a ROOT span, starting a new trace on this context. Always
     yields a real :class:`Span` (unlike :func:`span`, which no-ops when
     nothing is tracing)."""
-    root = Span(name, trace_id or new_trace_id(), attrs=attrs or None)
-    root._enter()
-    try:
-        yield root
-    finally:
-        root._exit()
+    with _annotated(name, attrs):
+        root = Span(name, trace_id or new_trace_id(), attrs=attrs or None)
+        root._enter()
+        try:
+            yield root
+        finally:
+            root._exit()
 
 
 @contextlib.contextmanager
 def span(name: str, **attrs: Any) -> Iterator[Optional[Span]]:
     """Open a child span under the active trace. With NO active trace
-    this yields ``None`` without allocating -- instrumentation stays
-    near-free on untraced requests."""
-    parent = _ACTIVE.get()
-    if parent is None:
-        yield None
+    this yields ``None`` without allocating a node -- instrumentation
+    stays near-free on untraced requests. The profiler annotation opens
+    either way."""
+    with _annotated(name, attrs):
+        parent = _ACTIVE.get()
+        if parent is None:
+            yield None
+            return
+        child = Span(name, parent.trace_id, root_t0=parent._root_t0,
+                     attrs=attrs or None)
+        parent.children.append(child)
+        child._enter()
+        try:
+            yield child
+        finally:
+            child._exit()
+
+
+def set_attrs(**attrs: Any) -> None:
+    """Add attrs to the innermost open span, for values known only once
+    its work has run (a dispatch's compile count): to its profiler
+    annotation, and to its tree node when a request is being traced."""
+    annotation = _ANNOTATION.get()
+    if annotation is None:
         return
-    child = Span(name, parent.trace_id, root_t0=parent._root_t0,
-                 attrs=attrs or None)
-    parent.children.append(child)
-    child._enter()
-    try:
-        yield child
-    finally:
-        child._exit()
+    annotation.set_metadata(**attrs)
+    node = _ACTIVE.get()
+    if node is not None:
+        node.attrs.update(attrs)

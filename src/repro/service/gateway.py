@@ -113,7 +113,7 @@ ROUTE_SELECTORS = (
 #: selectors matched as subsets rather than exact equality.
 _SUBSET_SELECTORS = ("stencils", "models", "ops")
 
-# ---- observability (repro.obs; no-ops under REPRO_OBS_DISABLED=1) --------
+# ---- observability (repro.obs) -------------------------------------------
 _LOG = get_logger("repro.gateway")
 _REG = _obs_registry()
 _M_REQUESTS = _REG.counter(
@@ -643,12 +643,10 @@ class Gateway:
         telemetry snapshots) plus the persistent usage ledger (the
         ``/v1/artifacts`` rows and the ``gc`` retention plan). The single
         choke point for routed-query hits, so the two can never double
-        count. No-ops under the ``REPRO_OBS_DISABLED`` kill switch."""
+        count."""
         _M_ART_REQUESTS.labels(artifact=key).inc(n)
         _M_ART_LAST.labels(artifact=key).set(time.time())
         _M_ART_SECONDS.labels(artifact=key).observe(dispatch_s)
-        if _REG.disabled:
-            return
         with self._mu:
             row = self._index.get(key)
             root = row["store"].root if row is not None else None
@@ -660,8 +658,6 @@ class Gateway:
     def _note_bytes(self, key: str, nbytes: int) -> None:
         """Response-byte accounting for the single-answer routes (the
         batched route's shared envelope is not attributed per artifact)."""
-        if _REG.disabled:
-            return
         with self._mu:
             row = self._index.get(key)
             root = row["store"].root if row is not None else None
@@ -1136,7 +1132,7 @@ class _Handler(BaseHTTPRequestHandler):
             _M_REQUESTS.labels(route=route).inc()
             _M_REQUEST_SECONDS.labels(route=route).observe(dt)
             status = getattr(self, "_last_status", None)
-            if status is not None and not _REG.disabled:
+            if status is not None:
                 self.gateway.slo.record(route, dt, ok=status < 500)
 
     def _send_exemplars(self, query: str) -> None:
@@ -1156,9 +1152,8 @@ class _Handler(BaseHTTPRequestHandler):
     def _capture(self) -> bool:
         """Whether this request should record an internal span tree for
         the tail-exemplar ring even though the client didn't ask for one
-        (never perturbs response bytes; disabled with the kill switch so
-        the obs-overhead A/B measures the whole capture path)."""
-        return self.gateway.exemplars is not None and not _REG.disabled
+        (never perturbs response bytes)."""
+        return self.gateway.exemplars is not None
 
     def _answer_query(self, data: bytes) -> None:
         """POST /v1/query: the one route with opt-in tracing. Untraced
@@ -1301,7 +1296,7 @@ class _Handler(BaseHTTPRequestHandler):
             _M_REQUESTS.labels(route=route).inc()
             _M_REQUEST_SECONDS.labels(route=route).observe(dt)
             status = getattr(self, "_last_status", None)
-            if status is not None and not _REG.disabled:
+            if status is not None:
                 gw = self.gateway
                 gw.slo.record(route, dt, ok=status < 500)
                 if gw.exemplars is not None and (

@@ -33,7 +33,7 @@ from .store import Artifact
 
 __all__ = ["QueryRequest", "QueryResponse", "QueryEngine"]
 
-# ---- observability (repro.obs; no-ops under REPRO_OBS_DISABLED=1) --------
+# ---- observability (repro.obs) -------------------------------------------
 _REG = _obs_registry()
 _M_LRU_HITS = _REG.counter(
     "repro_query_lru_hits_total",

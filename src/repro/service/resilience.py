@@ -93,7 +93,7 @@ DEADLINE_HEADER = "X-Repro-Deadline-Ms"
 #: the remote address is the fallback key.
 CLIENT_HEADER = "X-Repro-Client"
 
-# ---- observability (repro.obs; no-ops under REPRO_OBS_DISABLED=1) --------
+# ---- observability (repro.obs) -------------------------------------------
 _LOG = get_logger("repro.resilience")
 _REG = _obs_registry()
 _M_DEADLINE = _REG.counter(

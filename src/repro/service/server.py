@@ -61,7 +61,7 @@ from .store import Artifact, ArtifactStore
 
 __all__ = ["CodesignServer", "LMServer", "server_from_artifact"]
 
-# ---- observability (repro.obs; no-ops under REPRO_OBS_DISABLED=1) --------
+# ---- observability (repro.obs) -------------------------------------------
 _LOG = get_logger("repro.server")
 _REG = _obs_registry()
 _M_BATCH_SIZE = _REG.histogram(

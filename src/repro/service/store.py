@@ -42,6 +42,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.obs.metrics import get_registry as _obs_registry
+from repro.obs.trace import span
 
 try:  # POSIX file locks for the cross-process build path
     import fcntl
@@ -112,7 +113,7 @@ KINDS = ("sweep", "measurement", "calibration", "telemetry", "portfolio")
 #: matrix share one key.
 _DIGEST_ENGINE = {"sharded": "jax"}
 
-# ---- observability (repro.obs; no-ops under REPRO_OBS_DISABLED=1) --------
+# ---- observability (repro.obs) -------------------------------------------
 _REG = _obs_registry()
 _M_BUILDS = _REG.counter(
     "repro_store_builds_total",
@@ -591,7 +592,7 @@ class ArtifactStore:
                     fcntl.flock(ent[0], fcntl.LOCK_UN)
                     os.close(ent[0])
 
-    def _staged_write(self, key: str, write_files) -> Artifact:
+    def _staged_write(self, key: str, kind: str, write_files) -> Artifact:
         """The shared commit discipline of :meth:`put` / :meth:`put_json`:
         under the cross-process build lock, re-check for a racing winner,
         stage via ``write_files(tmp_dir)`` in a temp dir, and
@@ -599,25 +600,31 @@ class ArtifactStore:
         when a concurrent same-key builder's artifact is already there
         (content addressing guarantees the bytes match). Lives in ONE
         place because the lost-race tolerance is subtle enough that two
-        copies would drift."""
-        with self.build_lock(key):
+        copies would drift. Spans: ``store.lock``, ``store.write`` (attr
+        ``kind``), ``store.commit`` and ``store.reload``."""
+        with contextlib.ExitStack() as held:
+            with span("store.lock"):
+                held.enter_context(self.build_lock(key))
             existing = self.get(key)
             if existing is not None:  # a racing builder finished first
                 return existing
             tmp = tempfile.mkdtemp(prefix=f".stage-{key}-", dir=self.root)
             try:
-                write_files(tmp)
-                try:
-                    os.replace(tmp, self._path(key))
-                except OSError:
-                    if not os.path.exists(
-                        os.path.join(self._path(key), "manifest.json")
-                    ):
-                        raise  # real failure, not a lost same-key race
+                with span("store.write", kind=kind):
+                    write_files(tmp)
+                with span("store.commit"):
+                    try:
+                        os.replace(tmp, self._path(key))
+                    except OSError:
+                        if not os.path.exists(
+                            os.path.join(self._path(key), "manifest.json")
+                        ):
+                            raise  # real failure, not a lost same-key race
             finally:
                 if os.path.exists(tmp):
                     shutil.rmtree(tmp, ignore_errors=True)
-        art = self.get(key)
+        with span("store.reload"):
+            art = self.get(key)
         assert art is not None
         _M_BUILDS.labels(kind=art.kind).inc()  # this process staged it
         return art
@@ -668,45 +675,58 @@ class ArtifactStore:
         routing is not part of the content address, so this never moves
         the key. Dispatches on the result's cell family: LM results
         (:class:`repro.core.lmcells.LMCodesignResult`) key via
-        :func:`lm_artifact_spec` (the tile-lattice pins do not apply)."""
-        if getattr(result, "family", "stencil") == "lm":
-            spec = lm_artifact_spec(
-                result.workload, result.hw, engine, result.gpu_name
-            )
-        else:
-            lat2 = lattice_2d or next(
-                (lat for lat in result.lattices if len(lat.t_s3) == 1), LATTICE_2D
-            )
-            lat3 = lattice_3d or next(
-                (lat for lat in result.lattices if len(lat.t_s3) > 1), LATTICE_3D
-            )
-            spec = artifact_spec(
-                result.workload, result.gpu, result.hw, engine, lat2, lat3
-            )
-        key = spec_key(spec)
-        manifest, arrays = result.artifact_payload()
-        manifest.update(
-            format_version=FORMAT_VERSION,
-            kind="sweep",
-            key=key,
-            spec=spec,
-            engine=engine,
-            shapes={"cells": int(arrays["cell_time"].shape[0]),
-                    "hw": int(arrays["cell_time"].shape[1])},
-            extra=extra or {},
-        )
-        if routing_extra:
-            manifest["routing"] = {**manifest.get("routing", {}), **routing_extra}
-        def write_files(tmp: str) -> None:
-            np.save(os.path.join(tmp, "cell_time.npy"), arrays["cell_time"])
-            np.savez_compressed(
-                os.path.join(tmp, "arrays.npz"),
-                **{k: v for k, v in arrays.items() if k != "cell_time"},
-            )
-            with open(os.path.join(tmp, "manifest.json"), "w") as f:
-                json.dump(manifest, f, indent=1)
+        :func:`lm_artifact_spec` (the tile-lattice pins do not apply).
 
-        return self._staged_write(key, write_files)
+        Spans: ``store.put`` around the whole call; under it ``store.key``
+        (the spec and its sha256), then those of the staged write, whose
+        ``store.write`` holds ``store.write.times`` (``np.save`` of the
+        optima), ``store.write.argmins`` (``savez_compressed`` of the tile
+        argmins and hardware columns) and ``store.write.manifest``."""
+        with span("store.put"):
+            with span("store.key"):
+                if getattr(result, "family", "stencil") == "lm":
+                    spec = lm_artifact_spec(
+                        result.workload, result.hw, engine, result.gpu_name
+                    )
+                else:
+                    lat2 = lattice_2d or next(
+                        (lat for lat in result.lattices if len(lat.t_s3) == 1),
+                        LATTICE_2D,
+                    )
+                    lat3 = lattice_3d or next(
+                        (lat for lat in result.lattices if len(lat.t_s3) > 1),
+                        LATTICE_3D,
+                    )
+                    spec = artifact_spec(
+                        result.workload, result.gpu, result.hw, engine, lat2, lat3
+                    )
+                key = spec_key(spec)
+            manifest, arrays = result.artifact_payload()
+            manifest.update(
+                format_version=FORMAT_VERSION,
+                kind="sweep",
+                key=key,
+                spec=spec,
+                engine=engine,
+                shapes={"cells": int(arrays["cell_time"].shape[0]),
+                        "hw": int(arrays["cell_time"].shape[1])},
+                extra=extra or {},
+            )
+            if routing_extra:
+                manifest["routing"] = {**manifest.get("routing", {}), **routing_extra}
+            def write_files(tmp: str) -> None:
+                with span("store.write.times"):
+                    np.save(os.path.join(tmp, "cell_time.npy"), arrays["cell_time"])
+                with span("store.write.argmins"):
+                    np.savez_compressed(
+                        os.path.join(tmp, "arrays.npz"),
+                        **{k: v for k, v in arrays.items() if k != "cell_time"},
+                    )
+                with span("store.write.manifest"):
+                    with open(os.path.join(tmp, "manifest.json"), "w") as f:
+                        json.dump(manifest, f, indent=1)
+
+            return self._staged_write(key, "sweep", write_files)
 
     def put_json(
         self,
@@ -749,7 +769,7 @@ class ArtifactStore:
             with open(os.path.join(tmp, "manifest.json"), "w") as f:
                 json.dump(manifest, f, indent=1)
 
-        return self._staged_write(key, write_files)
+        return self._staged_write(key, kind, write_files)
 
     def upgrade_manifests(self) -> List[str]:
         """Backfill manifests written by older writers in place.

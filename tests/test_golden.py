@@ -133,7 +133,7 @@ def _error() -> bytes:
 def _metrics_json() -> bytes:
     # a private registry with one of each family kind and fixed
     # observations: the canonical /v1/metrics?format=json rendering
-    reg = Registry(disabled=False)
+    reg = Registry()
     c = reg.counter("repro_requests_total", "requests", labels=("endpoint",))
     c.labels(endpoint="/v1/route").inc(3)
     c.labels(endpoint="/v1/query").inc(5)

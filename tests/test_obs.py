@@ -23,7 +23,7 @@ from repro.core import MAXWELL, enumerate_hw_space
 from repro.core.timemodel import MAXWELL_GPU, TITANX_GPU
 from repro.core.workload import paper_workload
 from repro.obs import configure_logging, get_logger
-from repro.obs.metrics import Registry, get_registry, set_disabled
+from repro.obs.metrics import Registry
 from repro.obs.trace import current_trace_id, span, trace
 from repro.service import (
     ArtifactStore,
@@ -82,7 +82,7 @@ def _counter_value(snapshot, name, **labels):
 # metrics registry
 # ---------------------------------------------------------------------------
 def test_counter_and_gauge_basics():
-    reg = Registry(disabled=False)
+    reg = Registry()
     c = reg.counter("c_total", "help", labels=("route",))
     c.labels(route="/a").inc()
     c.labels(route="/a").inc(2.5)
@@ -103,7 +103,7 @@ def test_counter_and_gauge_basics():
 
 
 def test_family_get_never_mints_children():
-    reg = Registry(disabled=False)
+    reg = Registry()
     c = reg.counter("c_total", labels=("k",))
     assert c.get(k="x") is None
     assert reg.snapshot()["c_total"]["samples"] == []
@@ -112,7 +112,7 @@ def test_family_get_never_mints_children():
 
 
 def test_histogram_bucket_placement():
-    reg = Registry(disabled=False)
+    reg = Registry()
     h = reg.histogram("h", buckets=(1.0, 2.0, 4.0))
     for v in (0.5, 1.0, 1.5, 4.0, 99.0):  # 99 -> +Inf overflow
         h.observe(v)
@@ -124,7 +124,7 @@ def test_histogram_bucket_placement():
 
 
 def test_metrics_thread_safety_exact_counts():
-    reg = Registry(disabled=False)
+    reg = Registry()
     c = reg.counter("c_total", labels=("t",))
     h = reg.histogram("h", buckets=(0.5,))
     n_threads, n_iter = 8, 10_000
@@ -146,7 +146,7 @@ def test_metrics_thread_safety_exact_counts():
 
 
 def test_reset_zeroes_but_preserves_child_identity():
-    reg = Registry(disabled=False)
+    reg = Registry()
     c = reg.counter("c_total", labels=("k",))
     child = c.labels(k="x")
     child.inc(5)
@@ -158,7 +158,7 @@ def test_reset_zeroes_but_preserves_child_identity():
 
 
 def test_exporter_goldens():
-    reg = Registry(disabled=False)
+    reg = Registry()
     reg.counter("req_total", "requests", labels=("route",)).labels(
         route="/v1/query"
     ).inc(3)
@@ -187,20 +187,6 @@ def test_exporter_goldens():
     ]
     # canonical: equal state renders equal bytes
     assert reg.render_json() == reg.render_json()
-
-
-def test_disabled_mode_drops_everything():
-    reg = get_registry()
-    c = reg.counter("test_obs_disabled_total")
-    before = c.value
-    set_disabled(True)
-    try:
-        c.inc()
-        assert c.value == before
-    finally:
-        set_disabled(None)  # back to the REPRO_OBS_DISABLED env default
-    c.inc()
-    assert c.value == before + 1
 
 
 # ---------------------------------------------------------------------------
