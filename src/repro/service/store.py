@@ -9,8 +9,9 @@ re-reduction over it. This module persists :class:`repro.core.codesign
 * one directory per artifact: ``manifest.json`` (workload cells with full
   stencil specs, GPU constants, lattices, shapes, spec) + ``cell_time.npy``
   (the big (C, H) float64 matrix, written raw so it can be **memory-mapped**
-  on load) + ``arrays.npz`` (compressed: tile argmins and the hardware-space
-  columns);
+  on load) + ``arrays.npz`` (stored, not deflated: the tile or plan argmins,
+  each integer member narrowed to the smallest signed width that holds its
+  own range, and the float64 hardware-space columns);
 * **content-addressed keys**: sha256 over a canonical-JSON spec of
   (stencil set incl. numeric model constants, size grid, hardware-space
   digest, GPU constants, lattices, engine, format version). Same question
@@ -18,7 +19,8 @@ re-reduction over it. This module persists :class:`repro.core.codesign
   a different key (see ``tests/test_service.py``);
 * lazy loading: :class:`Artifact` reads the manifest eagerly (small JSON)
   and materializes arrays on first attribute access -- ``cell_time`` as an
-  ``mmap_mode="r"`` view, the npz members on demand;
+  ``mmap_mode="r"`` view, the npz members on demand, integer members
+  widened back to int64;
 * atomic writes: artifacts are staged in a temp directory and renamed into
   place, so readers never observe a half-written artifact; an exclusive
   per-key ``flock`` (:meth:`ArtifactStore.build_lock`) serializes
@@ -42,7 +44,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.obs.metrics import get_registry as _obs_registry
-from repro.obs.trace import span
+from repro.obs.trace import set_attrs, span
 
 try:  # POSIX file locks for the cross-process build path
     import fcntl
@@ -87,6 +89,10 @@ DEFAULT_LOCK_TIMEOUT_S = 600.0
 #: bump when the on-disk layout or the solver semantics change; old
 #: artifacts then read as misses (the store rebuilds, never mis-serves).
 FORMAT_VERSION = 1
+
+#: signed-integer widths an ``arrays.npz`` member may be stored at,
+#: narrowest first.
+_INT_WIDTHS = (np.int8, np.int16, np.int32, np.int64)
 
 #: manifest kinds one store can hold. "sweep" is the original (C, H)
 #: optima matrix (manifest + cell_time.npy + arrays.npz); "measurement"
@@ -250,12 +256,26 @@ def spec_key(spec: dict) -> str:
     return hashlib.sha256(_canonical_json(spec).encode()).hexdigest()[:20]
 
 
+def _narrow(a: np.ndarray) -> np.ndarray:
+    """``a`` at the narrowest signed-integer width that holds its own
+    minimum and maximum (-1 marks an infeasible cell); other dtypes, and
+    empty arrays, unchanged. Decided from the values alone, so two
+    builders of one key write the same members."""
+    if a.dtype.kind != "i" or a.size == 0:
+        return a
+    lo, hi = int(a.min()), int(a.max())
+    dt = next(dt for dt in _INT_WIDTHS
+              if np.iinfo(dt).min <= lo and hi <= np.iinfo(dt).max)
+    return a.astype(dt, copy=False)
+
+
 class Artifact:
     """Lazy read handle over one stored sweep.
 
     The manifest is loaded eagerly; ``cell_time`` is an mmap-backed view
     materialized on first access (queries that never touch a row never page
-    it in), and the smaller arrays decompress from the npz on demand.
+    it in), and the smaller arrays load from the npz on demand, integer
+    members widened back to int64 whatever width they were stored at.
     """
 
     def __init__(self, path: str):
@@ -385,7 +405,10 @@ class Artifact:
         if name not in self._cache:
             if self._npz is None:
                 self._npz = np.load(os.path.join(self.path, "arrays.npz"))
-            self._cache[name] = self._npz[name]
+            a = self._npz[name]
+            if a.dtype.kind == "i" and a.dtype != np.int64:
+                a = a.astype(np.int64)
+            self._cache[name] = a
         return self._cache[name]
 
     @property
@@ -680,8 +703,11 @@ class ArtifactStore:
         Spans: ``store.put`` around the whole call; under it ``store.key``
         (the spec and its sha256), then those of the staged write, whose
         ``store.write`` holds ``store.write.times`` (``np.save`` of the
-        optima), ``store.write.argmins`` (``savez_compressed`` of the tile
-        argmins and hardware columns) and ``store.write.manifest``."""
+        optima), ``store.write.argmins`` (``np.savez``, members stored
+        without compression, of the argmins narrowed by :func:`_narrow` and
+        the hardware columns; attrs ``bytes``, the file's size, and
+        ``idx_dtype``, the width the argmins were stored at) and
+        ``store.write.manifest``."""
         with span("store.put"):
             with span("store.key"):
                 if getattr(result, "family", "stencil") == "lm":
@@ -718,10 +744,13 @@ class ArtifactStore:
                 with span("store.write.times"):
                     np.save(os.path.join(tmp, "cell_time.npy"), arrays["cell_time"])
                 with span("store.write.argmins"):
-                    np.savez_compressed(
-                        os.path.join(tmp, "arrays.npz"),
-                        **{k: v for k, v in arrays.items() if k != "cell_time"},
-                    )
+                    members = {k: _narrow(v) for k, v in arrays.items()
+                               if k != "cell_time"}
+                    path = os.path.join(tmp, "arrays.npz")
+                    np.savez(path, **members)
+                    idx_dtype = next(str(v.dtype) for k, v in members.items()
+                                     if k.endswith("_idx"))
+                    set_attrs(bytes=os.path.getsize(path), idx_dtype=idx_dtype)
                 with span("store.write.manifest"):
                     with open(os.path.join(tmp, "manifest.json"), "w") as f:
                         json.dump(manifest, f, indent=1)

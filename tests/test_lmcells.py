@@ -179,6 +179,7 @@ def test_artifact_round_trip_bit_identity(tmp_path, wl, hw, oracle):
     assert type(back).__name__ == "LMCodesignResult"
     assert np.array_equal(back.cell_time, oracle.cell_time)
     assert np.array_equal(back.cell_plan_idx, oracle.cell_plan_idx)
+    assert back.cell_plan_idx.dtype == np.int64
     assert back.gpu_name == oracle.gpu_name == LM_GPU_NAME
     assert [c.label for c in back.workload.cells] == [c.label for c in wl.cells]
     np.testing.assert_array_equal(back.cell_freqs(), oracle.cell_freqs())

@@ -2,7 +2,11 @@
 engine-free warm queries (the acceptance property), microbatching vs the
 sequential oracle, what-ifs, and LRU eviction."""
 
+import dataclasses
+import os
+import shutil
 import threading
+import zipfile
 
 import numpy as np
 import pytest
@@ -58,9 +62,99 @@ def test_artifact_round_trip_bit_identical(built):
     np.testing.assert_array_equal(
         np.asarray(res.cell_tile_idx), fresh.cell_tile_idx
     )
+    # stored narrow, read back at the width the caller built it with
+    assert art.cell_tile_idx.dtype == np.int64
+    assert np.asarray(res.cell_tile_idx).dtype == np.int64
     # reconstructed workload/lattices decode tiles like the original
     ci, hi = 0, int(np.nonzero(fresh.cell_tile_idx[0] >= 0)[0][0])
     assert res.tiles_for(ci, hi) == fresh.tiles_for(ci, hi)
+
+
+def narrowest_int(a):
+    """The narrowest signed-integer dtype that holds ``a``'s range."""
+    return next(
+        np.dtype(dt) for dt in (np.int8, np.int16, np.int32, np.int64)
+        if np.iinfo(dt).min <= a.min() and a.max() <= np.iinfo(dt).max
+    )
+
+
+@pytest.mark.parametrize(
+    "member", ["cell_tile_idx", "hw_n_sm", "hw_n_v", "hw_m_sm", "hw_area"]
+)
+def test_arrays_npz_members_are_stored_uncompressed_and_narrow(built, member):
+    """Each member of a fresh ``arrays.npz`` is written without deflate;
+    an integer member at the narrowest width that holds its range, a float
+    member at float64; the handle reads each back at its built dtype."""
+    store, srv, fresh = built
+    art = store.get(srv.key)
+    path = os.path.join(art.path, "arrays.npz")
+    with zipfile.ZipFile(path) as zf:
+        info = zf.getinfo(f"{member}.npy")
+    assert info.compress_type == zipfile.ZIP_STORED
+    if member == "cell_tile_idx":
+        built_as, read, want = (fresh.cell_tile_idx, art.cell_tile_idx,
+                                narrowest_int(fresh.cell_tile_idx))
+    else:
+        built_as, read = getattr(fresh.hw, member[3:]), art.hw_column(member[3:])
+        want = np.dtype(np.float64)
+    with np.load(path) as z:
+        on_disk = z[member]
+    assert on_disk.dtype == want
+    np.testing.assert_array_equal(on_disk, built_as)
+    assert read.dtype == np.asarray(built_as).dtype
+    np.testing.assert_array_equal(read, built_as)
+
+
+def test_artifact_written_compressed_at_int64_still_reads(built, tmp_path):
+    """An artifact whose ``arrays.npz`` was written the earlier way
+    (deflated, int64 argmins) reads unchanged: same key, same values."""
+    store, srv, fresh = built
+    old = ArtifactStore(str(tmp_path))
+    src = store.get(srv.key).path
+    dst = os.path.join(old.root, os.path.basename(src))
+    shutil.copytree(src, dst)
+    path = os.path.join(dst, "arrays.npz")
+    np.savez_compressed(
+        path,
+        cell_tile_idx=np.asarray(fresh.cell_tile_idx, np.int64),
+        **{f"hw_{n}": getattr(fresh.hw, n) for n in ("n_sm", "n_v", "m_sm", "area")},
+    )
+    with zipfile.ZipFile(path) as zf:
+        assert {i.compress_type for i in zf.infolist()} == {zipfile.ZIP_DEFLATED}
+    art = old.get(srv.key)
+    assert art is not None and art.key == srv.key
+    assert art.cell_tile_idx.dtype == np.int64
+    np.testing.assert_array_equal(art.cell_tile_idx, fresh.cell_tile_idx)
+    for name in ("n_sm", "n_v", "m_sm", "area"):
+        col = art.hw_column(name)
+        assert col.dtype == np.float64
+        np.testing.assert_array_equal(col, getattr(fresh.hw, name))
+    res = art.to_result()
+    np.testing.assert_array_equal(np.asarray(res.cell_time), fresh.cell_time)
+    np.testing.assert_array_equal(res.cell_tile_idx, fresh.cell_tile_idx)
+    np.testing.assert_array_equal(res.gflops(), fresh.gflops())
+
+
+@pytest.mark.parametrize(
+    "hi,dtype",
+    [(-1, np.int8), (127, np.int8), (2879, np.int16), (32767, np.int16),
+     (32768, np.int32), (2**31, np.int64)],
+)
+def test_argmins_narrow_by_their_own_range(built, tmp_path, hi, dtype):
+    """-1 (infeasible) survives narrowing, and the width follows the
+    largest index: above 32,767 the argmins are stored as int32 and read
+    back exactly."""
+    _, _, fresh = built
+    idx = np.full(fresh.cell_tile_idx.shape, -1, np.int64)
+    idx[0, 0] = hi
+    idx[-1, -1] = min(hi, 3)
+    result = dataclasses.replace(fresh, cell_tile_idx=idx)
+    art = ArtifactStore(str(tmp_path)).put(result, engine="auto")
+    with np.load(os.path.join(art.path, "arrays.npz")) as z:
+        assert z["cell_tile_idx"].dtype == np.dtype(dtype)
+    assert art.cell_tile_idx.dtype == np.int64
+    np.testing.assert_array_equal(art.cell_tile_idx, idx)
+    np.testing.assert_array_equal(art.to_result().cell_tile_idx, idx)
 
 
 def test_store_key_tracks_hardware_spec(built):

@@ -213,7 +213,7 @@ def test_store_spans_nest_as_documented(tmp_path, kind):
         result = codesign(paper_workload(["heat2d"]), gpu=MAXWELL_GPU,
                           hw=small_hw(), engine="numpy")
         with trace("q") as root:
-            store.put(result, engine="numpy")
+            art = store.put(result, engine="numpy")
         (top,) = root.tree()["children"]
         assert top["name"] == "store.put"
         spans = top["children"]
@@ -230,3 +230,10 @@ def test_store_spans_nest_as_documented(tmp_path, kind):
     assert [names(s) for s in spans] == want
     (write,) = [s for s in spans if s["name"] == "store.write"]
     assert write["attrs"] == {"kind": kind}
+    if kind == "sweep":  # the argmins' write says what it put on disk
+        (argmins,) = [s for s in write["children"]
+                      if s["name"] == "store.write.argmins"]
+        assert argmins["attrs"] == {
+            "bytes": os.path.getsize(os.path.join(art.path, "arrays.npz")),
+            "idx_dtype": "int16",
+        }
