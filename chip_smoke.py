@@ -22,7 +22,10 @@ Phases (one chip), each printing one line that names what it compared:
   ``kernels/ref.py``; one banded kernel per dimensionality likewise.
 * fit       -- ``measure.cli fit`` on that measurement and ``measure.cli
   build`` on the calibrated GPU (the loop running; no gate on the error).
-* lm        -- ``lm_codesign`` jax against numpy on the default workload.
+* lm        -- ``lm_codesign`` jax against numpy on the default workload
+  (512 chips) and on DeepSeek-V3's question (2,048 chips), the answer
+  feasible for every cell, and a repeat's ``lm.dispatch`` spans compiling
+  nothing.
 * portfolio -- ``optimize_portfolio_arrays`` jax against numpy, K=3.
 
 Everything runs in this one process (a second process could not open the
@@ -279,30 +282,55 @@ def phase_fit(store_dir, measurement_key):
 def phase_lm():
     import numpy as np
 
-    from repro.core.lmcells import lm_cell_roofline, lm_codesign, lm_sw_lattice, lm_workload
+    from repro.configs.base import SHAPES, ShapeSpec
+    from repro.core.lmcells import (enumerate_lm_hw_space, lm_cell_roofline, lm_codesign,
+                                    lm_sw_lattice, lm_workload)
+    from repro.obs.trace import trace
 
-    wl = lm_workload()
-    t0 = time.perf_counter()
-    res_jx = lm_codesign(wl, engine="jax")
-    t_jx = time.perf_counter() - t0
-    res_np = lm_codesign(wl, engine="numpy")
-    feas = np.isfinite(res_np.cell_time)
-    check(np.array_equal(feas, np.isfinite(res_jx.cell_time)), "LM feasibility differs")
-    rel = np.abs(res_jx.cell_time[feas] - res_np.cell_time[feas]) / res_np.cell_time[feas]
-    check(rel.max(initial=0.0) <= RTOL, f"LM max rel time err {rel.max():.3g}")
-    diffs = 0
-    for ci, cell in enumerate(wl.cells):
-        lat = lm_sw_lattice(cell.op)
-        for hi in np.nonzero(feas[ci] & (res_jx.cell_plan_idx[ci] != res_np.cell_plan_idx[ci]))[0]:
-            p = res_np.hw.point(int(hi))
-            r = lm_cell_roofline(cell, lat.plan(p["pod"], p["data"], p["model"],
-                                                int(res_jx.cell_plan_idx[ci, hi])))
-            check(r["feasible"] and abs(r["bound_s"] - res_np.cell_time[ci, hi])
-                  <= RTOL * res_np.cell_time[ci, hi], f"LM {cell.label} hw {hi} not tied")
-            diffs += 1
-    say("lm", f"lm_codesign jax vs numpy: {len(wl.cells)} cells x {len(res_np.hw)} "
-        f"meshes, feasibility exact, max rel err {rel.max(initial=0.0):.3g}, "
-        f"{diffs} differing plans all tied; jax {t_jx:.2f}s OK")
+    deepseek = lm_workload(archs=["deepseek-v3-671b"], name="deepseek-v3", shapes={
+        "prefill": SHAPES["prefill_32k"], "decode": SHAPES["decode_32k"],
+        "train": ShapeSpec("train_3072x4k", 4096, 3072, "train")})
+    for name, wl, max_chips in (("default pair", lm_workload(), 512),
+                                ("deepseek-v3", deepseek, 2048)):
+        hw = enumerate_lm_hw_space(max_chips=max_chips)
+        t0 = time.perf_counter()
+        res_jx = lm_codesign(wl, hw=hw, engine="jax")
+        t_jx = time.perf_counter() - t0
+        res_np = lm_codesign(wl, hw=hw, engine="numpy")
+        feas = np.isfinite(res_np.cell_time)
+        check(np.array_equal(feas, np.isfinite(res_jx.cell_time)), f"LM {name} feasibility differs")
+        rel = np.abs(res_jx.cell_time[feas] - res_np.cell_time[feas]) / res_np.cell_time[feas]
+        check(rel.max(initial=0.0) <= RTOL, f"LM {name} max rel time err {rel.max():.3g}")
+        diffs = 0
+        for ci, cell in enumerate(wl.cells):
+            lat = lm_sw_lattice(cell.op)
+            for hi in np.nonzero(feas[ci] & (res_jx.cell_plan_idx[ci] != res_np.cell_plan_idx[ci]))[0]:
+                p = res_np.hw.point(int(hi))
+                r = lm_cell_roofline(cell, lat.plan(p["pod"], p["data"], p["model"],
+                                                    int(res_jx.cell_plan_idx[ci, hi])))
+                check(r["feasible"] and abs(r["bound_s"] - res_np.cell_time[ci, hi])
+                      <= RTOL * res_np.cell_time[ci, hi], f"LM {cell.label} hw {hi} not tied")
+                diffs += 1
+        best, gflops = res_np.best(max_chips)
+        check(np.isfinite(gflops) and gflops > 0 and feas[:, best].all(),
+              f"LM {name}: no design feasible for every cell within {max_chips} chips")
+        # a repeat of the question: one lm.dispatch per cell, each holding
+        # its lm.fetch, none compiling
+        with trace("lm") as root:
+            lm_codesign(wl, hw=hw, engine="jax")
+        (top,) = root.tree()["children"]
+        dispatches = top["children"]
+        check(top["name"] == "lm.codesign"
+              and [d["name"] for d in dispatches] == ["lm.dispatch"] * len(wl.cells)
+              and all([c["name"] for c in d.get("children", [])] == ["lm.fetch"]
+                      for d in dispatches), f"LM {name}: spans {top}")
+        compiles = [d["attrs"]["compiles"] for d in dispatches]
+        check(compiles == [0] * len(wl.cells), f"LM {name}: repeat compiled {compiles}")
+        say("lm", f"{name}: lm_codesign jax vs numpy: {len(wl.cells)} cells x {len(res_np.hw)} "
+            f"meshes, feasibility exact, max rel err {rel.max(initial=0.0):.3g}, "
+            f"{diffs} differing plans all tied; best {res_np.hw.point(best)} at "
+            f"{gflops:.6g} GFLOP/s; repeat {len(dispatches)} dispatches, compiles {compiles}; "
+            f"jax {t_jx:.2f}s OK")
 
 
 def phase_portfolio(oracles):

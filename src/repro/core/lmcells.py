@@ -19,21 +19,27 @@ The mapping onto the paper's decomposition:
 Two engines, mirroring :mod:`repro.core.codesign`: ``"numpy"`` evaluates the
 scalar oracle's exact float64 expressions vectorized over the whole
 ``(hw, sw)`` grid, and ``"jax"`` jits the identical traceable body in
-float32 (one compile per op kind -- cell constants enter as traced
-scalars). :func:`lm_cell_roofline` is the plain-scalar oracle both are
-parity-tested against; for the three standard ops it reproduces
-:func:`repro.core.lmtime.lm_roofline` term for term.
+float32 (one compile per op kind, named ``jit_lm_grid_<op>`` -- cell
+constants enter as traced scalars). :func:`lm_cell_roofline` is the
+plain-scalar oracle both are parity-tested against, and what
+:func:`repro.core.lmtime.lm_roofline` evaluates; its docstring names the
+source of each term beyond the weights (attention over context, expert
+parallelism, sequence parallelism).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..configs.base import SHAPES, ArchConfig, ShapeSpec
+from ..obs.compiles import compiles_so_far, listen_for_compiles
+from ..obs.metrics import get_registry as _obs_registry
+from ..obs.trace import set_attrs, span
 from .lmtime import HW, MeshPlan
 from .pareto import pareto_mask
 from .workload import Workload
@@ -46,6 +52,8 @@ __all__ = [
     "LM_GPU_NAME",
     "enumerate_lm_hw_space",
     "lm_sw_lattice",
+    "attention_flops",
+    "lm_cell",
     "lm_cells_for",
     "lm_workload",
     "lm_cell_roofline",
@@ -69,9 +77,13 @@ class LMCell:
     """One LM workload cell: an op of one model at one shape.
 
     All numeric fields are plain Python scalars precomputed at build time
-    (parameter counts via ``jax.eval_shape``, KV bytes via
-    :func:`repro.serve.kvcache.cache_bytes`), so a cell round-trips through
-    a JSON manifest and the sweep never re-touches model code.
+    (parameter counts, the routed experts' among them, via
+    ``jax.eval_shape``, KV bytes via
+    :func:`repro.serve.kvcache.cache_bytes`, attention FLOPs via
+    :func:`attention_flops`), so a cell round-trips through a JSON manifest
+    and the sweep never re-touches model code. The MoE fields are set on
+    every cell of an MoE model: the expert-parallel group of every op is
+    sized by ``moe_n_experts``.
     """
 
     model: str  # arch name, e.g. "llama3-8b"
@@ -87,6 +99,8 @@ class LMCell:
     moe_top_k: int = 0
     moe_capacity: float = 0.0
     moe_n_experts: int = 0
+    attn_flops: float = 0.0  # the part of ``flops`` that is attention over context
+    n_routed: int = 0  # routed-expert parameters, spread by expert parallelism
 
     def __post_init__(self):
         if self.op not in LM_OPS:
@@ -121,6 +135,8 @@ class LMCell:
             "moe_top_k": int(self.moe_top_k),
             "moe_capacity": float(self.moe_capacity),
             "moe_n_experts": int(self.moe_n_experts),
+            "attn_flops": float(self.attn_flops),
+            "n_routed": int(self.n_routed),
         }
 
 
@@ -162,11 +178,11 @@ def enumerate_lm_hw_space(
     power-of-two data/model axes (the shapes XLA meshes actually take),
     sorted by (chips, pod, model) for a deterministic content address.
 
-    The 512 default is the smallest power of two at which EVERY default
-    cell fits HBM somewhere -- Mixtral-8x22B's train step needs 512 v5e
-    chips -- so the default pair artifact has a non-empty answer for its
-    own uniform mix (a mix is infeasible at a mesh where *any* workload
-    cell is infeasible, zero-weighted or not; see docs/lm_codesign.md)."""
+    At the 512 default every default cell fits HBM somewhere --
+    Mixtral-8x22B's train step first fits at 256 v5e chips -- so the
+    default pair artifact has a non-empty answer for its own uniform mix
+    (a mix is infeasible at a mesh where *any* workload cell is
+    infeasible, zero-weighted or not; see docs/lm_codesign.md)."""
     rows: List[Tuple[int, int, int]] = []
     pows = [1 << j for j in range(max_chips.bit_length()) if (1 << j) <= max_chips]
     for pod in (1, 2) if multi_pod else (1,):
@@ -245,6 +261,97 @@ def lm_sw_lattice(op: str) -> LMSwLattice:
 # ---------------------------------------------------------------------------
 # Cell builders
 # ---------------------------------------------------------------------------
+def attention_flops(cfg: ArchConfig, shape: ShapeSpec) -> float:
+    """FLOPs per step of attention over context: the score (Q.K) and value
+    (P.V) matmuls of every attention layer of the stack, which the weight
+    term ``2 * n_active`` per token leaves out. Per layer kind:
+
+    * ``full`` (Vaswani et al., arXiv:1706.03762): each of ``n_heads``
+      query heads scores and mixes at ``head_dim`` (GQA shares K/V, not
+      this work);
+    * ``swa`` (Mistral 7B, arXiv:2310.06825 section 2): the same, each
+      query seeing at most ``window`` keys;
+    * ``mla`` (DeepSeek-V3, arXiv:2412.19437 section 2.1.1): prefill and
+      train in the naive form, scores at ``head_dim + rope_head_dim`` (the
+      nope and rope parts, 192) and values at ``v_head_dim`` (128); decode
+      in the absorbed form (DeepSeek-V2, arXiv:2405.04434 section 2.1.2),
+      where each cached position is the ``kv_lora_rank + rope_head_dim``
+      latent: scores at that width, values at ``kv_lora_rank``;
+    * ``ssm`` layers add nothing here (their scan is not priced yet), and
+      neither do an encoder's layers, cross-attention or the MTP block.
+
+    Prefill counts the causal pairs of each sequence (query ``i`` sees
+    keys ``1..i``, or the last ``window`` of them) at 2 FLOPs a
+    multiply-add; a train step is 3x its forward (the backward pass twice
+    the forward); decode is one query per sequence over ``seq_len`` cached
+    positions.
+    """
+    a = cfg.attn
+    s, b = shape.seq_len, shape.global_batch
+    w = a.window if a.kind == "swa" and a.window else s
+    if a.kind == "mla" and shape.kind == "decode":
+        qk, v = a.kv_lora_rank + a.rope_head_dim, a.kv_lora_rank
+    elif a.kind == "mla":
+        qk, v = cfg.head_dim_ + a.rope_head_dim, a.v_head_dim
+    else:
+        qk = v = cfg.head_dim_
+    m = min(s, w)
+    pairs = m if shape.kind == "decode" else m * (m + 1) // 2 + (s - m) * m
+    n_attn = sum(1 for mixer, _ in cfg.layer_kinds() if mixer == "attn")
+    fwd = 2.0 * cfg.n_heads * (qk + v) * pairs * b * n_attn
+    return 3.0 * fwd if shape.kind == "train" else fwd
+
+
+def lm_cell(
+    cfg: ArchConfig,
+    op: str,
+    shape: ShapeSpec,
+    n_params: int,
+    n_active: int,
+    n_routed: int,
+    freq: float = 1.0,
+) -> LMCell:
+    """One cell of ``cfg``. The parameter counts are passed in, since each
+    costs an ``eval_shape`` of the model; everything else is computed here.
+    ``flops`` is the weight matmuls (``2 * n_active`` a token, ``6 *`` in
+    training) plus :func:`attention_flops`; ``moe_dispatch`` counts its
+    router matmul."""
+    from ..serve.kvcache import cache_bytes
+
+    tokens = shape.tokens if shape.kind != "decode" else shape.global_batch
+    moe = cfg.moe
+    moe_consts = (
+        {"moe_top_k": moe.top_k, "moe_capacity": moe.capacity_factor,
+         "moe_n_experts": moe.n_experts}
+        if moe is not None
+        else {}
+    )
+    if op == "moe_dispatch":
+        attn, kv = 0.0, 0
+        flops = 2.0 * cfg.d_model * moe.n_experts * tokens
+    else:
+        if shape.kind != op:
+            raise ValueError(f"shape {shape.name!r} is kind {shape.kind!r}, not {op!r}")
+        attn = attention_flops(cfg, shape)
+        flops = (6.0 if op == "train" else 2.0) * n_active * tokens + attn
+        kv = int(cache_bytes(cfg, shape.global_batch, shape.seq_len)) if op == "decode" else 0
+    return LMCell(
+        model=cfg.name,
+        op=op,
+        shape=shape,
+        freq=freq,
+        n_params=n_params,
+        n_active=n_active,
+        kv_bytes=kv,
+        d_model=cfg.d_model,
+        n_layers=cfg.n_layers,
+        flops=flops,
+        attn_flops=attn,
+        n_routed=n_routed,
+        **moe_consts,
+    )
+
+
 def lm_cells_for(
     cfg: ArchConfig,
     shapes: Optional[Dict[str, ShapeSpec]] = None,
@@ -253,12 +360,12 @@ def lm_cells_for(
     """Unnormalized cells for one architecture: prefill + decode@batch-64 +
     train step, plus the MoE dispatch op when the config routes experts.
 
-    ``shapes`` overrides the per-op shape table (keys: op names); parameter
+    ``shapes`` overrides the per-op shape table (keys: op names; the
+    dispatch takes the decode shape unless given its own); parameter
     counts come from ``jax.eval_shape`` over the real model init, so they
     are exact without allocating anything.
     """
-    from ..models.model import active_params, count_params
-    from ..serve.kvcache import cache_bytes
+    from ..models.model import active_params, count_params, routed_expert_params
 
     shapes = {
         "prefill": SHAPES["prefill_32k"],
@@ -266,55 +373,12 @@ def lm_cells_for(
         "train": SHAPES["train_4k"],
         **(shapes or {}),
     }
-    n_params = int(count_params(cfg))
-    n_active = int(active_params(cfg))
-    cells: List[LMCell] = []
-    for op in ("prefill", "decode", "train"):
-        shape = shapes[op]
-        if shape.kind != op:
-            raise ValueError(f"shape {shape.name!r} is kind {shape.kind!r}, not {op!r}")
-        tokens = shape.tokens if op != "decode" else shape.global_batch
-        mult = 6.0 if op == "train" else 2.0
-        kv = (
-            int(cache_bytes(cfg, shape.global_batch, shape.seq_len))
-            if op == "decode"
-            else 0
-        )
-        cells.append(
-            LMCell(
-                model=cfg.name,
-                op=op,
-                shape=shape,
-                freq=freq,
-                n_params=n_params,
-                n_active=n_active,
-                kv_bytes=kv,
-                d_model=cfg.d_model,
-                n_layers=cfg.n_layers,
-                flops=mult * n_active * tokens,
-            )
-        )
+    counts = (int(count_params(cfg)), int(active_params(cfg)), int(routed_expert_params(cfg)))
+    ops = ["prefill", "decode", "train"]
     if cfg.moe is not None:
-        shape = shapes.get("moe_dispatch", shapes["decode"])
-        tokens = shape.tokens if shape.kind != "decode" else shape.global_batch
-        cells.append(
-            LMCell(
-                model=cfg.name,
-                op="moe_dispatch",
-                shape=shape,
-                freq=freq,
-                n_params=n_params,
-                n_active=n_active,
-                kv_bytes=0,
-                d_model=cfg.d_model,
-                n_layers=cfg.n_layers,
-                flops=2.0 * cfg.d_model * cfg.moe.n_experts * tokens,
-                moe_top_k=cfg.moe.top_k,
-                moe_capacity=cfg.moe.capacity_factor,
-                moe_n_experts=cfg.moe.n_experts,
-            )
-        )
-    return cells
+        ops.append("moe_dispatch")
+        shapes.setdefault("moe_dispatch", shapes["decode"])
+    return [lm_cell(cfg, op, shapes[op], *counts, freq=freq) for op in ops]
 
 
 def lm_workload(
@@ -351,39 +415,64 @@ def _div_ok(op: str, gb: int, data_shards: int, microbatches: int) -> bool:
 def lm_cell_roofline(cell: LMCell, plan: MeshPlan) -> Dict:
     """Plain-scalar reference model for one (cell, plan) point.
 
-    For prefill/decode/train this mirrors
-    :func:`repro.core.lmtime.lm_roofline` expression for expression (a
-    test asserts term-level equality against it); ``moe_dispatch`` is
-    defined here: the dispatch+combine all-to-all of ``capacity * top_k``
-    routed tokens over the model axis as expert parallelism, plus the
-    router matmul, with weight-fit feasibility. Adds the mesh
-    shardability constraint (``div_ok``) on top of the HBM fit;
-    ``feasible`` is their conjunction and is what the sweep masks on.
+    :func:`repro.core.lmtime.lm_roofline` builds a cell and calls this, so
+    the two cannot drift; :func:`_grid_times` is its vectorized twin, term
+    for term in the same expression order. ``moe_dispatch`` is defined
+    here only: the dispatch+combine all-to-all of ``capacity * top_k``
+    routed tokens over the expert-parallel group, plus the router matmul,
+    with weight-fit feasibility. Adds the mesh shardability constraint
+    (``div_ok``) on top of the HBM fit; ``feasible`` is their conjunction
+    and is what the sweep masks on.
+
+    Sources of the terms beyond the three rooflines:
+
+    * attention over context is in ``cell.flops`` (:func:`attention_flops`);
+    * expert parallelism (DeepSeek-V3, arXiv:2412.19437 section 3.4:
+      attention data-parallel, experts expert-parallel over the same
+      chips): the routed experts (``cell.n_routed``) spread over the
+      ``data x model`` chips of a pod, at most one group per expert
+      (``ep``); where the model axis is wider than that group, each expert
+      is also split over it (tensor parallelism within experts, as
+      :mod:`repro.sharding.partition` does), so an expert shard lives on
+      ``max(ep, model)`` chips. FSDP spreads it over every chip, as it does
+      the other weights. The other weights divide over ``model`` (``x
+      data`` under FSDP) as before. Expert gradients reduce over the
+      replicas of their shard, the other gradients over the data axes;
+    * sequence parallelism (Korthikanti et al., arXiv:2205.05198): ops over
+      whole sequences keep the residual-stream activations split over the
+      model axis, in the HBM fit and the activation traffic; each tensor
+      all-reduce becomes a reduce-scatter plus an all-gather of the same
+      bytes, so the TP term stays.
     """
     chips = plan.chips
     ds = plan.data_shards
     tokens = cell.tokens
     peak, hbm_bw = HW["peak_flops_bf16"], HW["hbm_bw"]
     ici_bw = HW["ici_links"] * HW["ici_link_bw"]
+    ep = min(plan.data * plan.model, cell.moe_n_experts)
+    e_shards = max(ep, plan.model)
+    n_other = cell.n_params - cell.n_routed
+    w_shards = plan.model * (ds if plan.fsdp else 1)
+    e_div = chips if plan.fsdp else e_shards
+    w_bytes = 2.0 * n_other / w_shards + 2.0 * cell.n_routed / e_div
     if cell.op == "moe_dispatch":
-        tokens_local = tokens / ds
         toks_chip = cell.moe_capacity * cell.moe_top_k * tokens / chips
         t_compute = 2.0 * cell.d_model * cell.moe_n_experts * tokens / chips / peak
         t_memory = 2.0 * toks_chip * cell.d_model * 2.0 / hbm_bw
-        ep_factor = (plan.model - 1) / plan.model
+        ep_factor = (ep - 1) / ep
         t_coll = 2.0 * toks_chip * cell.d_model * 2.0 * ep_factor / ici_bw
-        w_shards = plan.model * (ds if plan.fsdp else 1)
-        hbm = 2.0 * cell.n_params / w_shards
+        hbm = w_bytes
     else:
         train = cell.op == "train"
         n_layers_eff = max(cell.n_layers, 1)
         recompute = 1.0 + (0.5 if (train and plan.remat == "full") else 0.0)
         t_compute = cell.flops * recompute / (chips * peak)
         passes = (2.0 if train else 1.0) * plan.microbatches
-        w_shards = plan.model * (ds if plan.fsdp else 1)
-        weight_traffic = 2.0 * cell.n_params / w_shards * passes
+        weight_traffic = w_bytes * passes
         tokens_local = tokens / ds
         act_traffic = 12.0 * tokens_local * cell.d_model * 2.0 * n_layers_eff
+        if cell.op != "decode":
+            act_traffic = act_traffic / plan.model
         opt_traffic = (12.0 * cell.n_params / chips) if train else 0.0
         kv_traffic = cell.kv_bytes / chips if cell.op == "decode" else 0.0
         t_memory = (weight_traffic + act_traffic + opt_traffic + kv_traffic) / hbm_bw
@@ -395,19 +484,28 @@ def lm_cell_roofline(cell: LMCell, plan: MeshPlan) -> Dict:
             ar_per_layer * n_layers_eff * tokens_local * cell.d_model * 2.0 * tp_factor
         ) * plan.microbatches
         dp_factor = 0.0 if ds == 1 or not train else 2.0 * (ds - 1) / ds
+        e_replicas = chips / e_shards
+        e_dp_factor = 0.0 if e_replicas == 1 or not train else 2.0 * (e_replicas - 1) / e_replicas
         grad_bytes_unit = 1.0 if plan.compress_grads else 4.0
-        dp_bytes = grad_bytes_unit * cell.n_params / plan.model * dp_factor
-        fsdp_bytes = 2.0 * cell.n_params / plan.model * passes if plan.fsdp else 0.0
+        dp_bytes = (
+            grad_bytes_unit * n_other / plan.model * dp_factor
+            + grad_bytes_unit * cell.n_routed / e_shards * e_dp_factor
+        )
+        fsdp_bytes = (
+            (2.0 * n_other / plan.model + 2.0 * cell.n_routed / e_shards) * passes
+            if plan.fsdp
+            else 0.0
+        )
         pod_fraction = 0.0 if plan.pod == 1 else (plan.pod - 1) / plan.pod
         dci_bytes = dp_bytes * pod_fraction
         ici_bytes = tp_bytes + fsdp_bytes + dp_bytes * (1 - pod_fraction)
         t_coll = ici_bytes / ici_bw + dci_bytes / HW["dci_link_bw"]
-        hbm = 2.0 * cell.n_params / w_shards
+        hbm = w_bytes
         if train:
             hbm += 12.0 * cell.n_params / chips
             hbm += 3.0 * (tokens_local / plan.microbatches) * cell.d_model * 2.0 * (
                 n_layers_eff
-            ) * (1.0 if plan.remat == "full" else 4.0)
+            ) * (1.0 if plan.remat == "full" else 4.0) / plan.model
         if cell.op == "decode":
             hbm += cell.kv_bytes / chips
     terms = {"compute_s": t_compute, "memory_s": t_memory, "collective_s": t_coll}
@@ -434,34 +532,41 @@ def _grid_times(op, consts, pod, data, model, mb, remat, fsdp, compress, xp):
     ``op`` is the only static branch (cell *structure*); every numeric
     input is an ``xp`` array or scalar, so the body traces under
     ``jax.vmap``/``jit`` and evaluates bit-exactly against the scalar
-    oracle under ``xp=numpy`` float64 (identical expression order).
+    oracle under ``xp=numpy`` float64 (identical expression order; the
+    sources of the terms are in :func:`lm_cell_roofline`'s docstring).
     Hardware columns arrive shaped (H, 1), software columns (L,); all
     terms broadcast to (H, L).
     """
     (tokens, gb, n_params, kv_bytes, d_model, n_layers_eff, flops,
-     top_k, capacity, n_experts) = consts
+     top_k, capacity, n_experts, n_routed) = consts
     chips = pod * data * model
     ds = pod * data
     peak, hbm_bw = HW["peak_flops_bf16"], HW["hbm_bw"]
     ici_bw = HW["ici_links"] * HW["ici_link_bw"]
     one = xp.ones_like(mb)  # broadcast helper: (L,)
+    ep = xp.minimum(data * model, n_experts)
+    e_shards = xp.maximum(ep, model)
+    n_other = n_params - n_routed
+    w_shards = model * (1.0 + fsdp * (ds - 1.0))
+    e_div = e_shards + fsdp * (chips - e_shards)
+    w_bytes = 2.0 * n_other / w_shards + 2.0 * n_routed / e_div
     if op == "moe_dispatch":
         toks_chip = capacity * top_k * tokens / chips
         t_compute = (2.0 * d_model * n_experts * tokens / chips / peak) * one
         t_memory = (2.0 * toks_chip * d_model * 2.0 / hbm_bw) * one
-        ep_factor = (model - 1) / model
+        ep_factor = (ep - 1) / ep
         t_coll = (2.0 * toks_chip * d_model * 2.0 * ep_factor / ici_bw) * one
-        w_shards = model * (1.0 + fsdp * (ds - 1.0))
-        hbm = 2.0 * n_params / w_shards
+        hbm = w_bytes
     else:
         train = op == "train"
         recompute = 1.0 + 0.5 * remat if train else one
         t_compute = flops * recompute / (chips * peak)
         passes = (2.0 if train else 1.0) * mb
-        w_shards = model * (1.0 + fsdp * (ds - 1.0))
-        weight_traffic = 2.0 * n_params / w_shards * passes
+        weight_traffic = w_bytes * passes
         tokens_local = tokens / ds
         act_traffic = 12.0 * tokens_local * d_model * 2.0 * n_layers_eff
+        if op != "decode":
+            act_traffic = act_traffic / model
         opt_traffic = 12.0 * n_params / chips if train else 0.0
         kv_traffic = kv_bytes / chips if op == "decode" else 0.0
         t_memory = (weight_traffic + act_traffic + opt_traffic + kv_traffic) / hbm_bw
@@ -471,18 +576,23 @@ def _grid_times(op, consts, pod, data, model, mb, remat, fsdp, compress, xp):
             ar_per_layer * n_layers_eff * tokens_local * d_model * 2.0 * tp_factor
         ) * mb
         dp_factor = 2.0 * (ds - 1.0) / ds if train else 0.0
+        e_replicas = chips / e_shards
+        e_dp_factor = 2.0 * (e_replicas - 1.0) / e_replicas if train else 0.0
         grad_bytes_unit = 4.0 - 3.0 * compress
-        dp_bytes = grad_bytes_unit * n_params / model * dp_factor
-        fsdp_bytes = fsdp * (2.0 * n_params / model * passes)
+        dp_bytes = (
+            grad_bytes_unit * n_other / model * dp_factor
+            + grad_bytes_unit * n_routed / e_shards * e_dp_factor
+        )
+        fsdp_bytes = fsdp * ((2.0 * n_other / model + 2.0 * n_routed / e_shards) * passes)
         pod_fraction = (pod - 1.0) / pod
         dci_bytes = dp_bytes * pod_fraction
         ici_bytes = tp_bytes + fsdp_bytes + dp_bytes * (1 - pod_fraction)
         t_coll = ici_bytes / ici_bw + dci_bytes / HW["dci_link_bw"]
-        hbm = 2.0 * n_params / w_shards
+        hbm = w_bytes
         if train:
             hbm = hbm + 12.0 * n_params / chips + 3.0 * (
                 tokens_local / mb
-            ) * d_model * 2.0 * n_layers_eff * (4.0 - 3.0 * remat)
+            ) * d_model * 2.0 * n_layers_eff * (4.0 - 3.0 * remat) / model
         if op == "decode":
             hbm = hbm + kv_bytes / chips
     bound = xp.maximum(t_compute, xp.maximum(t_memory, t_coll))
@@ -507,24 +617,49 @@ def _cell_consts(cell: LMCell) -> Tuple[float, ...]:
         float(cell.moe_top_k),
         float(cell.moe_capacity),
         float(cell.moe_n_experts),
+        float(cell.n_routed),
     )
+
+
+_REG = _obs_registry()
+_M_COMPILES = _REG.counter(
+    "repro_lm_compiles_total",
+    "programs JAX compiled or loaded from its persistent cache during LM "
+    "grid dispatches, counted on the dispatching thread",
+    labels=("op",),
+)
+
+
+@contextlib.contextmanager
+def _dispatch(op: str, h: int, l: int) -> Iterator[None]:
+    """One cell's grid dispatch: the ``lm.dispatch`` span and its compile
+    count (the span's ``compiles`` attr and the counter), read from the
+    per-thread count of JAX's compile events."""
+    listen_for_compiles()
+    with span("lm.dispatch", op=op, h=h, l=l):
+        n0 = compiles_so_far()
+        yield
+        compiles = compiles_so_far() - n0
+        set_attrs(compiles=compiles)
+    _M_COMPILES.labels(op=op).inc(compiles)
 
 
 _JIT_CACHE: Dict[str, object] = {}
 
 
 def _jax_grid_fn(op: str):
-    """One compiled grid evaluator per op kind; constants are traced, so
-    every cell of an op reuses the same executable."""
+    """One compiled grid evaluator per op kind, named ``jit_lm_grid_<op>``
+    in HLO and on the device trace; constants are traced, so every cell of
+    an op reuses the same executable."""
     if op not in _JIT_CACHE:
         import jax
         import jax.numpy as jnp
 
-        _JIT_CACHE[op] = jax.jit(
-            lambda consts, pod, data, model, mb, remat, fsdp, compress: _grid_times(
-                op, consts, pod, data, model, mb, remat, fsdp, compress, jnp
-            )
-        )
+        def grid(consts, pod, data, model, mb, remat, fsdp, compress):
+            return _grid_times(op, consts, pod, data, model, mb, remat, fsdp, compress, jnp)
+
+        grid.__name__ = grid.__qualname__ = f"lm_grid_{op}"
+        _JIT_CACHE[op] = jax.jit(grid)
     return _JIT_CACHE[op]
 
 
@@ -722,7 +857,9 @@ def lm_codesign(
     same body in float32; ``"auto"`` picks jax when importable. Infeasible
     (cell, hw) combinations -- HBM overflow or unshardable batch at every
     software setting -- carry ``+inf`` time and plan index ``-1``, exactly
-    the stencil sweep's convention.
+    the stencil sweep's convention. One ``lm.codesign`` span holds an
+    ``lm.dispatch`` span per cell (its ``compiles`` attr counted as the
+    stencil sweep counts them), which holds the jax engine's ``lm.fetch``.
     """
     if getattr(workload, "family", "stencil") != "lm":
         raise ValueError(f"lm_codesign wants an LM workload, got {workload.family!r}")
@@ -733,31 +870,35 @@ def lm_codesign(
     cell_time = np.empty((C, H))
     cell_idx = np.empty((C, H), dtype=np.int64)
     lattices = [lm_sw_lattice(c.op) for c in workload.cells]
-    for ci, cell in enumerate(workload.cells):
-        lat = lattices[ci]
-        consts = _cell_consts(cell)
-        if eng == "jax":
-            import jax.numpy as jnp
+    with span("lm.codesign", engine=eng, cells=C, h=H):
+        for ci, cell in enumerate(workload.cells):
+            lat = lattices[ci]
+            consts = _cell_consts(cell)
+            with _dispatch(cell.op, H, len(lat)):
+                if eng == "jax":
+                    import jax.numpy as jnp
 
-            f32 = lambda a: jnp.asarray(np.asarray(a, np.float32))
-            grid = _jax_grid_fn(cell.op)(
-                consts,
-                f32(hw.pod)[:, None], f32(hw.data)[:, None], f32(hw.model)[:, None],
-                f32(lat.microbatches), f32(lat.remat_full),
-                f32(lat.fsdp), f32(lat.compress),
-            )
-            grid = np.asarray(grid, np.float64)
-        else:
-            c64 = lambda a: np.asarray(a, np.float64)
-            grid = _grid_times(
-                cell.op, consts,
-                c64(hw.pod)[:, None], c64(hw.data)[:, None], c64(hw.model)[:, None],
-                c64(lat.microbatches), c64(lat.remat_full),
-                c64(lat.fsdp), c64(lat.compress),
-                np,
-            )
-        idx = np.argmin(grid, axis=1)
-        t = grid[np.arange(H), idx]
-        cell_time[ci] = t
-        cell_idx[ci] = np.where(np.isfinite(t), idx, -1)
+                    f32 = lambda a: jnp.asarray(np.asarray(a, np.float32))
+                    grid = _jax_grid_fn(cell.op)(
+                        consts,
+                        f32(hw.pod)[:, None], f32(hw.data)[:, None], f32(hw.model)[:, None],
+                        f32(lat.microbatches), f32(lat.remat_full),
+                        f32(lat.fsdp), f32(lat.compress),
+                    )
+                    with span("lm.fetch"):
+                        # blocks until the grid is done, then copies and casts
+                        grid = np.asarray(grid, np.float64)
+                else:
+                    c64 = lambda a: np.asarray(a, np.float64)
+                    grid = _grid_times(
+                        cell.op, consts,
+                        c64(hw.pod)[:, None], c64(hw.data)[:, None], c64(hw.model)[:, None],
+                        c64(lat.microbatches), c64(lat.remat_full),
+                        c64(lat.fsdp), c64(lat.compress),
+                        np,
+                    )
+            idx = np.argmin(grid, axis=1)
+            t = grid[np.arange(H), idx]
+            cell_time[ci] = t
+            cell_idx[ci] = np.where(np.isfinite(t), idx, -1)
     return LMCodesignResult(workload, hw, cell_time, cell_idx, lattices, gpu_name)

@@ -69,10 +69,6 @@ class MeshPlan:
         return self.pod * self.data
 
 
-def _param_bytes(n_params: int) -> float:
-    return 2.0 * n_params  # bf16 storage
-
-
 def lm_roofline(
     cfg: ArchConfig,
     shape: ShapeSpec,
@@ -83,8 +79,9 @@ def lm_roofline(
     """Three analytic roofline terms + feasibility for one design point.
 
     Args:
-        cfg: architecture (only ``d_model``/``n_layers`` enter directly;
-            expert sparsity is already folded into ``n_active``).
+        cfg: architecture (its attention layers price attention over
+            context; its routed experts, counted by ``jax.eval_shape``,
+            spread over the expert-parallel group).
         shape: workload shape; ``kind`` picks the cost model. For decode,
             "one step" means one token generated per sequence, so the
             compute term scales with ``global_batch`` tokens while the
@@ -95,89 +92,15 @@ def lm_roofline(
 
     Returns a dict of per-step wall-clock seconds — ``compute_s``,
     ``memory_s``, ``collective_s``, their max ``bound_s`` with the
-    ``dominant`` term's name — plus the per-chip working set ``hbm_bytes``
-    and ``fits`` (True iff it is under 90% of HBM, the eq. 9/11 analogue).
-    All terms are smooth in the plan parameters, so a vectorized twin
-    (:mod:`repro.core.lmcells`) can evaluate the whole lattice under
-    ``jax.vmap``/``jit``.
+    ``dominant`` term's name — plus the per-chip working set ``hbm_bytes``,
+    ``fits`` (True iff it is under 90% of HBM, the eq. 9/11 analogue) and
+    the shardability flags ``div_ok``/``feasible``. The terms, and their
+    sources, are :func:`repro.core.lmcells.lm_cell_roofline`'s: this builds
+    the one cell and evaluates it there, so the scalar model, its
+    vectorized twin and this entry point cannot drift apart.
     """
-    chips = plan.chips
-    tokens = shape.tokens if shape.kind != "decode" else shape.global_batch
-    train = shape.kind == "train"
+    from ..models.model import routed_expert_params
+    from .lmcells import lm_cell, lm_cell_roofline
 
-    # ---- compute ----------------------------------------------------------
-    mult = 6.0 if train else 2.0
-    flops_total = mult * n_active * tokens
-    recompute = 1.0 + (0.5 if (train and plan.remat == "full") else 0.0)
-    t_compute = flops_total * recompute / (chips * HW["peak_flops_bf16"])
-
-    # ---- memory -----------------------------------------------------------
-    # weights stream per microbatch pass (fwd [+bwd]), sharded over
-    # model (x data when fsdp); optimizer state traffic once per step
-    passes = (2.0 if train else 1.0) * plan.microbatches
-    w_shards = plan.model * (plan.data_shards if plan.fsdp else 1)
-    weight_traffic = _param_bytes(n_params) / w_shards * passes
-    tokens_local = tokens / plan.data_shards
-    act_traffic = 12.0 * tokens_local * cfg.d_model * 2.0 * max(cfg.n_layers, 1)
-    opt_traffic = (12.0 * n_params / chips) if train else 0.0
-    kv_traffic = 0.0
-    if shape.kind == "decode":
-        # decode reads the whole cache once per token
-        from ..serve.kvcache import cache_bytes
-
-        kv_traffic = cache_bytes(cfg, shape.global_batch, shape.seq_len) / chips
-    t_memory = (weight_traffic + act_traffic / 1.0 + opt_traffic + kv_traffic) / HW[
-        "hbm_bw"
-    ]
-
-    # ---- collectives ------------------------------------------------------
-    # TP: 2 all-reduces of the token activations per layer per pass (4 with
-    # full-remat backward recompute); ICI bandwidth
-    tp_factor = 0.0 if plan.model == 1 else 2.0 * (plan.model - 1) / plan.model
-    ar_per_layer = (4.0 if train and plan.remat == "full" else 2.0) * (
-        2.0 if train else 1.0
-    ) / 2.0
-    tp_bytes = (
-        ar_per_layer * max(cfg.n_layers, 1) * tokens_local * cfg.d_model * 2.0 * tp_factor
-    ) * plan.microbatches
-    # DP gradient reduction: once per step over (pod x data); f32 grads
-    dp_size = plan.data_shards
-    dp_factor = 0.0 if dp_size == 1 or not train else 2.0 * (dp_size - 1) / dp_size
-    grad_bytes_unit = 1.0 if plan.compress_grads else 4.0
-    dp_bytes = grad_bytes_unit * n_params / plan.model * dp_factor
-    # FSDP weight all-gather per microbatch pass
-    fsdp_bytes = (
-        _param_bytes(n_params) / plan.model * passes if plan.fsdp else 0.0
-    )
-    ici_bw = HW["ici_links"] * HW["ici_link_bw"]
-    # the pod axis rides the slower cross-pod fabric
-    pod_fraction = 0.0 if plan.pod == 1 else (plan.pod - 1) / plan.pod
-    dci_bytes = dp_bytes * pod_fraction
-    ici_bytes = tp_bytes + fsdp_bytes + dp_bytes * (1 - pod_fraction)
-    t_coll = ici_bytes / ici_bw + dci_bytes / HW["dci_link_bw"]
-
-    # ---- feasibility (the eq. 9/11 analogue) ------------------------------
-    hbm = _param_bytes(n_params) / w_shards
-    if train:
-        hbm += 12.0 * n_params / chips  # f32 grads+moments, ZeRO over chips
-        hbm += 3.0 * (tokens_local / plan.microbatches) * cfg.d_model * 2.0 * max(
-            cfg.n_layers, 1
-        ) * (1.0 if plan.remat == "full" else 4.0)
-    if shape.kind == "decode":
-        from ..serve.kvcache import cache_bytes
-
-        hbm += cache_bytes(cfg, shape.global_batch, shape.seq_len) / chips
-
-    terms = {
-        "compute_s": t_compute,
-        "memory_s": t_memory,
-        "collective_s": t_coll,
-    }
-    dominant = max(terms, key=terms.get)
-    return {
-        **terms,
-        "dominant": dominant.replace("_s", ""),
-        "bound_s": terms[dominant],
-        "hbm_bytes": hbm,
-        "fits": hbm <= HW["hbm_bytes"] * 0.9,
-    }
+    cell = lm_cell(cfg, shape.kind, shape, n_params, n_active, routed_expert_params(cfg))
+    return lm_cell_roofline(cell, plan)

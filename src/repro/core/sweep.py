@@ -38,13 +38,13 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import threading
 import time
 import warnings
 from typing import Dict, Iterator, Tuple
 
 import numpy as np
 
+from repro.obs.compiles import compiles_so_far, listen_for_compiles
 from repro.obs.metrics import get_registry as _obs_registry
 from repro.obs.trace import set_attrs, span
 
@@ -116,27 +116,8 @@ _M_COMPILES = _REG.counter(
     labels=("engine",),
 )
 
-#: JAX's monitoring event around each backend compile, a load from the
-#: persistent compilation cache included.
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
-class _ThreadCompiles(threading.local):
-    n = 0
-
-
-_COMPILES = _ThreadCompiles()
-
-
-def _on_compile_event(event: str, duration: float, **_) -> None:
-    # JAX compiles on the thread that dispatches, so a per-thread count
-    # read before and after a dispatch is that dispatch's own
-    if event == _COMPILE_EVENT:
-        _COMPILES.n += 1
-
-
 if HAVE_JAX:
-    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+    listen_for_compiles()
 
 
 @contextlib.contextmanager
@@ -145,11 +126,11 @@ def _dispatch(engine: str, dims: int, p: int, h: int) -> Iterator[None]:
     count (the span's ``compiles`` attr), the phase-split wall time and
     the optima counter."""
     with span("sweep.dispatch", engine=engine, dims=dims, p=p, h=h):
-        n0 = _COMPILES.n
+        n0 = compiles_so_far()
         t0 = time.perf_counter()
         yield
         dt = time.perf_counter() - t0
-        compiles = _COMPILES.n - n0
+        compiles = compiles_so_far() - n0
         set_attrs(compiles=compiles)
     _M_DISPATCH_SECONDS.labels(
         engine=engine, phase="compile" if compiles else "steady"

@@ -8,4 +8,5 @@ from .model import (  # noqa: F401
     forward_hidden,
     init_model,
     lm_loss,
+    routed_expert_params,
 )

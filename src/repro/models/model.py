@@ -8,6 +8,7 @@ as leading sequence positions (vlm) or as the encoder input (audio).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -24,6 +25,7 @@ __all__ = [
     "lm_loss",
     "count_params",
     "active_params",
+    "routed_expert_params",
     "mrope_positions",
     "LEARNED_POS_MAX",
 ]
@@ -54,15 +56,22 @@ def init_model(cfg: ArchConfig, key) -> Dict:
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(k_head, (cfg.d_model, cfg.vocab), dtype)
     if cfg.mtp:
+        # DeepSeek-V3's MTP module (arXiv:2412.19437 section 2.2) is one more
+        # block of the main model's kind: MLA and, in an MoE model, an MoE
+        # layer with the same routed and shared experts as the stack's
         km1, km2 = jax.random.split(k_mtp)
         params["mtp"] = {
             "norm_h": rmsnorm_init(cfg.d_model, dtype, cfg.rms_offset),
             "norm_e": rmsnorm_init(cfg.d_model, dtype, cfg.rms_offset),
             "proj": dense_init(km1, (2 * cfg.d_model, cfg.d_model), dtype),
-            "block": block_init(km2, cfg, "attn", "mlp", dtype),
+            "block": block_init(km2, cfg, "attn", _mtp_ffn(cfg), dtype),
             "final_norm": rmsnorm_init(cfg.d_model, dtype, cfg.rms_offset),
         }
     return params
+
+
+def _mtp_ffn(cfg: ArchConfig) -> str:
+    return "moe" if cfg.moe is not None else "mlp"
 
 
 # ---------------------------------------------------------------------------
@@ -196,11 +205,12 @@ def forward_hidden(
         fused = jnp.einsum(
             "bsd,de->bse", jnp.concatenate([h_in, e_in], -1), mp["proj"]
         )
-        fused, _, _ = block_apply(
-            mp["block"], cfg, "attn", "mlp", fused,
+        fused, _, mtp_aux = block_apply(
+            mp["block"], cfg, "attn", _mtp_ffn(cfg), fused,
             positions=positions[:, :-1] if positions.ndim == 2 else positions,
             mode="causal", cache=None, enc_out=None, impl=impl,
         )
+        extras["aux"] = aux + mtp_aux
         extras["mtp_hidden"] = rmsnorm(mp["final_norm"], fused, cfg.rms_offset)
 
     return hn, new_caches, extras
@@ -284,20 +294,36 @@ def lm_loss(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # Parameter accounting (for MODEL_FLOPS / roofline)
 # ---------------------------------------------------------------------------
+@functools.lru_cache(maxsize=64)
+def _param_counts(cfg: ArchConfig) -> Tuple[int, int]:
+    """(total, routed-expert) parameter counts from one ``eval_shape`` over
+    the real init (no alloc); configs are frozen, so the counts are kept."""
+    shapes = jax.eval_shape(lambda: init_model(cfg, jax.random.PRNGKey(0)))
+    total = routed = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(shapes):
+        n = int(math.prod(leaf.shape))
+        total += n
+        if any(isinstance(p, jax.tree_util.DictKey) and p.key == "experts" for p in path):
+            routed += n
+    return total, routed
+
+
 def count_params(cfg: ArchConfig) -> int:
     """Exact parameter count via eval_shape over the real init (no alloc)."""
-    shapes = jax.eval_shape(lambda: init_model(cfg, jax.random.PRNGKey(0)))
-    return sum(int(math.prod(x.shape)) for x in jax.tree.leaves(shapes))
+    return _param_counts(cfg)[0]
+
+
+def routed_expert_params(cfg: ArchConfig) -> int:
+    """Parameters of the routed experts: every ``experts`` stack of the
+    init, the MTP block's included (what expert parallelism spreads; the
+    shared experts and routers are not in it)."""
+    return _param_counts(cfg)[1]
 
 
 def active_params(cfg: ArchConfig) -> int:
     """Active-per-token parameters (MoE: routed top-k + shared only)."""
-    total = count_params(cfg)
+    total, routed = _param_counts(cfg)
     if cfg.moe is None:
         return total
     m = cfg.moe
-    mats = 3 if cfg.act in ("silu", "geglu") else 2
-    per_expert = mats * cfg.d_model * m.d_ff
-    n_moe_layers = sum(1 for _, f in cfg.layer_kinds() if f == "moe")
-    inactive = per_expert * (m.n_experts - m.top_k) * n_moe_layers
-    return total - inactive
+    return total - routed // m.n_experts * (m.n_experts - m.top_k)

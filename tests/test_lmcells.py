@@ -16,6 +16,7 @@ from repro.core.lmcells import (
     LM_GPU_NAME,
     enumerate_lm_hw_space,
     lm_cell_roofline,
+    lm_cells_for,
     lm_codesign,
     lm_sw_lattice,
     lm_workload,
@@ -223,3 +224,193 @@ def test_divisibility_infeasibility(cfgs, hw):
     bad = (3 % ds != 0) & (3 >= ds)
     assert np.all(~np.isfinite(res.cell_time[ci][bad]))
     assert np.all(res.cell_plan_idx[ci][bad] == -1)
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V3: attention over context, expert parallelism, sequence
+# parallelism, and its design question at its published cluster size
+# ---------------------------------------------------------------------------
+def _context_matmul_flops(fn, args, ctx, need):
+    """FLOPs of the ``dot_general`` s in ``fn``'s jaxpr that run over a
+    context axis of length ``ctx`` (at least ``need`` of their output and
+    contracted dims have that length: 2 for the square score and value
+    matmuls of a prefill, 1 for a decode's single query), each scan body
+    counted once per iteration."""
+    import math
+
+    import jax
+
+    def walk(jaxpr, mult):
+        total = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                (lhs_c, _), _ = eqn.params["dimension_numbers"]
+                lhs = eqn.invars[0].aval.shape
+                out = eqn.outvars[0].aval.shape
+                contract = [lhs[i] for i in lhs_c]
+                if sum(d == ctx for d in (*out, *contract)) >= need:
+                    total += mult * 2 * math.prod(out) * math.prod(contract)
+            inner = mult * eqn.params.get("length", 1)
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        total += walk(sub, inner)
+        return total
+
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr, 1)
+
+
+def _attn_cfg(name):
+    cfg = get_arch(name).reduced()
+    if cfg.attn.kind == "swa":
+        # a window below the prefill length and unequal to every width, so
+        # the cap shows and the context axis is found by its length alone
+        cfg = dataclasses.replace(cfg, attn=dataclasses.replace(cfg.attn, window=12))
+    return cfg
+
+
+@pytest.mark.parametrize("op", ["prefill", "decode"])
+@pytest.mark.parametrize("arch", ["deepseek-v3-671b", "llama3-8b", "mixtral-8x22b"])
+def test_attention_term_matches_the_models_own_matmuls(arch, op):
+    """The priced attention FLOPs against a direct count of the score and
+    value matmuls the reduced model's own forward traces: MLA in its naive
+    prefill and absorbed decode forms, full attention, and a sliding window
+    (prefill computes the full square under a mask, so its count is scaled
+    to the causal, windowed pairs counted here one by one)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs.base import ShapeSpec
+    from repro.core.lmcells import attention_flops
+    from repro.models.model import forward, init_model
+    from repro.serve.kvcache import init_caches
+
+    cfg = _attn_cfg(arch)
+    params = jax.eval_shape(lambda: init_model(cfg, jax.random.PRNGKey(0)))
+    b, s = 2, 21
+    w = cfg.attn.window if cfg.attn.kind == "swa" else s
+    if op == "prefill":
+        tokens = jax.ShapeDtypeStruct((b, s), jnp.int32)
+        square = _context_matmul_flops(
+            lambda p, t: forward(p, cfg, {"tokens": t}), (params, tokens), s, 2)
+        pairs = sum(min(i, w) for i in range(1, s + 1))
+        want = square * pairs / s**2
+        got = attention_flops(cfg, ShapeSpec("p", s, b, "prefill"))
+        assert attention_flops(cfg, ShapeSpec("t", s, b, "train")) == 3.0 * got
+    else:
+        caches = jax.eval_shape(lambda: init_caches(cfg, b, s))
+        token = jax.ShapeDtypeStruct((b, 1), jnp.int32)
+        want = _context_matmul_flops(
+            lambda p, t, c: forward(p, cfg, {"tokens": t, "cache_index": jnp.int32(5)},
+                                    caches=c),
+            (params, token, caches), min(s, w), 1)
+        got = attention_flops(cfg, ShapeSpec("d", s, b, "decode"))
+    assert want > 0
+    assert got == pytest.approx(want, rel=1e-12)
+
+
+@pytest.mark.parametrize("full", [False, True], ids=["reduced", "full"])
+def test_routed_experts_are_the_experts_leaves_of_eval_shape(full):
+    """``n_routed`` is what ``eval_shape`` of the real init holds under
+    ``experts``: 3 matrices of d x d_ff per expert in each MoE layer and in
+    the MTP block, which DeepSeek-V3 builds as an MoE layer."""
+    import math
+
+    import jax
+
+    from repro.models.model import init_model
+
+    cfg = get_arch("deepseek-v3-671b")
+    cfg = cfg if full else cfg.reduced()
+    shapes = jax.eval_shape(lambda: init_model(cfg, jax.random.PRNGKey(0)))
+    leaves = jax.tree_util.tree_leaves_with_path(shapes)
+    routed = sum(math.prod(x.shape) for path, x in leaves if "'experts'" in jax.tree_util.keystr(path))
+    m = cfg.moe
+    moe_layers = sum(f == "moe" for _, f in cfg.layer_kinds()) + 1  # + the MTP block
+    assert routed == 3 * cfg.d_model * m.d_ff * m.n_experts * moe_layers
+    cells = lm_cells_for(cfg)
+    assert {c.n_routed for c in cells} == {routed}
+    assert {c.moe_n_experts for c in cells} == {m.n_experts}
+    # active = total - the experts a token does not visit
+    (c0,) = {(c.n_params, c.n_active) for c in cells}
+    assert c0[1] == c0[0] - routed // m.n_experts * (m.n_experts - m.top_k)
+
+
+def _ds_workload(cfg):
+    from repro.configs.base import SHAPES, ShapeSpec
+
+    return lm_workload(archs=[cfg], name="deepseek-v3", shapes={
+        "prefill": SHAPES["prefill_32k"], "decode": SHAPES["decode_32k"],
+        "train": ShapeSpec("train_3072x4k", 4096, 3072, "train")})
+
+
+@pytest.mark.parametrize("full", [False, True], ids=["reduced", "full-16-meshes"])
+def test_deepseek_engines_match_the_scalar_oracle(full):
+    """numpy bit-exact and jax within float32 noise (ties allowed) against
+    the scalar oracle, for every cell of DeepSeek-V3's question."""
+    cfg = get_arch("deepseek-v3-671b")
+    wl = _ds_workload(cfg if full else cfg.reduced())
+    hw = enumerate_lm_hw_space(max_chips=2048).downsample(9) if full else (
+        enumerate_lm_hw_space(max_chips=32))
+    if full:
+        assert len(hw) == 16
+    res = lm_codesign(wl, hw=hw, engine="numpy")
+    assert np.isfinite(res.cell_time).any(axis=1).all()
+    for ci, cell in enumerate(wl.cells):
+        lat = lm_sw_lattice(cell.op)
+        for hi in range(len(hw)):
+            times = _brute_force(cell, lat, hw.point(hi))
+            assert res.cell_time[ci, hi] == min(times), (cell.label, hi)
+    if sweep.HAVE_JAX:
+        jres = lm_codesign(wl, hw=hw, engine="jax")
+        feas = np.isfinite(res.cell_time)
+        assert np.array_equal(feas, np.isfinite(jres.cell_time))
+        assert np.allclose(jres.cell_time[feas], res.cell_time[feas], rtol=RTOL)
+        for ci, cell in enumerate(wl.cells):
+            lat = lm_sw_lattice(cell.op)
+            for hi in np.nonzero(feas[ci] & (jres.cell_plan_idx[ci] != res.cell_plan_idx[ci]))[0]:
+                times = _brute_force(cell, lat, hw.point(int(hi)))
+                j = int(jres.cell_plan_idx[ci, hi])
+                assert times[j] == pytest.approx(res.cell_time[ci, hi], rel=RTOL)
+
+
+def test_deepseek_is_answered_within_its_training_cluster():
+    """At the 2,048 chips of DeepSeek-V3's training cluster the train step
+    fits some mesh and the uniform mix names a design feasible for every
+    cell; prefill's priced compute is about half attention at 32k."""
+    wl = _ds_workload(get_arch("deepseek-v3-671b"))
+    res = lm_codesign(wl, max_chips=2048, engine="numpy")
+    train = next(i for i, c in enumerate(wl.cells) if c.op == "train")
+    assert np.isfinite(res.cell_time[train]).any()
+    i, g = res.best(2048)
+    assert np.isfinite(g) and g > 0
+    assert np.isfinite(res.cell_time[:, i]).all()
+    prefill = next(c for c in wl.cells if c.op == "prefill")
+    assert 0.45 < prefill.attn_flops / prefill.flops < 0.6
+
+
+def test_experts_spread_over_the_expert_parallel_group():
+    """Routed experts divide over data x model (capped at the expert
+    count), so data parallelism alone no longer replicates them, while the
+    other weights divide over the model axis only; the dispatch all-to-all
+    runs over that group, not the model axis."""
+    cfg = get_arch("deepseek-v3-671b").reduced()  # 4 routed experts
+    (disp,) = [c for c in lm_cells_for(cfg) if c.op == "moe_dispatch"]
+    n_dense = disp.n_params - disp.n_routed
+    for data, model, group in ((1, 1, 1), (2, 1, 2), (8, 1, 4), (2, 2, 4), (1, 8, 8)):
+        r = lm_cell_roofline(disp, MeshPlan(1, data, model))
+        assert r["hbm_bytes"] == pytest.approx(
+            2.0 * n_dense / model + 2.0 * disp.n_routed / group), (data, model)
+    assert lm_cell_roofline(disp, MeshPlan(1, 1, 1))["collective_s"] == 0.0
+    assert lm_cell_roofline(disp, MeshPlan(1, 8, 1))["collective_s"] > 0.0
+
+
+def test_train_activations_divide_over_the_model_axis():
+    """Sequence parallelism: the train step's activation working set at
+    model = 4 is a quarter of what it is at model = 1, weights held fixed."""
+    (cell,) = [c for c in lm_workload(archs=[get_arch("llama3-8b")]).cells if c.op == "train"]
+    free = dataclasses.replace(cell, n_params=0, n_routed=0)
+    narrow = lm_cell_roofline(free, MeshPlan(1, 8, 1, microbatches=4, remat="full"))
+    wide = lm_cell_roofline(free, MeshPlan(1, 8, 4, microbatches=4, remat="full"))
+    assert wide["hbm_bytes"] == pytest.approx(narrow["hbm_bytes"] / 4)

@@ -123,3 +123,23 @@ def test_sharded_solver_compiles_over_four_chips(topo):
     )
     compiled = solve.lower(*args).compile()
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("op", ["prefill", "decode", "train", "moe_dispatch"])
+def test_lm_grid_compiles_at_deepseek_size(op, one_chip):
+    """DeepSeek-V3's question: one grid per op over the 144 meshes of up
+    to 2,048 chips and the op's software lattice."""
+    from repro.configs.base import SHAPES
+    from repro.core.lmcells import (LMCell, _cell_consts, _jax_grid_fn, enumerate_lm_hw_space,
+                                    lm_sw_lattice)
+
+    h, l = len(enumerate_lm_hw_space(max_chips=2048)), len(lm_sw_lattice(op))
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32, sharding=one_chip)
+    cell = LMCell(model="m", op=op, shape=SHAPES["decode_32k"], freq=1.0, n_params=0,
+                  n_active=0, kv_bytes=0, d_model=0, n_layers=0, flops=0.0)
+    consts = tuple(f32(()) for _ in _cell_consts(cell))
+    compiled = _jax_grid_fn(op).lower(
+        consts, f32((h, 1)), f32((h, 1)), f32((h, 1)),
+        f32((l,)), f32((l,)), f32((l,)), f32((l,)),
+    ).compile()
+    assert compiled.memory_analysis() is not None
