@@ -24,8 +24,10 @@ Phases (one chip), each printing one line that names what it compared:
   build`` on the calibrated GPU (the loop running; no gate on the error).
 * lm        -- ``lm_codesign`` jax against numpy on the default workload
   (512 chips) and on DeepSeek-V3's question (2,048 chips), the answer
-  feasible for every cell, and a repeat's ``lm.dispatch`` spans compiling
-  nothing.
+  feasible for every cell; jax bit-identical to one jitted grid a cell
+  with its constants as Python floats; a repeat's spans one
+  ``lm.dispatch`` a cell, none compiling, then one ``lm.fetch`` of every
+  grid, after 1 + C host-to-device copies.
 * portfolio -- ``optimize_portfolio_arrays`` jax against numpy, K=3.
 
 Everything runs in this one process (a second process could not open the
@@ -282,10 +284,28 @@ def phase_fit(store_dir, measurement_key):
 def phase_lm():
     import numpy as np
 
+    import jax
+    import jax.numpy as jnp
+
     from repro.configs.base import SHAPES, ShapeSpec
-    from repro.core.lmcells import (enumerate_lm_hw_space, lm_cell_roofline, lm_codesign,
-                                    lm_sw_lattice, lm_workload)
+    from repro.core.lmcells import (_cell_consts, _grid_times, enumerate_lm_hw_space,
+                                    lm_cell_roofline, lm_codesign, lm_sw_lattice, lm_workload)
     from repro.obs.trace import trace
+
+    def per_cell_grids(wl, hw):
+        """The grids as a per-cell loop evaluates them: one jit a cell,
+        constants as Python floats, meshes (H, 1), lattice (L,)."""
+        f32 = lambda a: jnp.asarray(np.asarray(a, np.float32))
+        for cell in wl.cells:
+            lat = lm_sw_lattice(cell.op)
+            grid = jax.jit(lambda *a, op=cell.op: _grid_times(op, *a, jnp))(
+                _cell_consts(cell), f32(hw.pod)[:, None], f32(hw.data)[:, None],
+                f32(hw.model)[:, None], f32(lat.microbatches), f32(lat.remat_full),
+                f32(lat.fsdp), f32(lat.compress))
+            grid = np.asarray(grid, np.float64)
+            j = np.argmin(grid, axis=1)
+            t = grid[np.arange(len(hw)), j]
+            yield t, np.where(np.isfinite(t), j, -1)
 
     deepseek = lm_workload(archs=["deepseek-v3-671b"], name="deepseek-v3", shapes={
         "prefill": SHAPES["prefill_32k"], "decode": SHAPES["decode_32k"],
@@ -314,22 +334,29 @@ def phase_lm():
         best, gflops = res_np.best(max_chips)
         check(np.isfinite(gflops) and gflops > 0 and feas[:, best].all(),
               f"LM {name}: no design feasible for every cell within {max_chips} chips")
-        # a repeat of the question: one lm.dispatch per cell, each holding
-        # its lm.fetch, none compiling
+        ref_t, ref_i = (np.array(a) for a in zip(*per_cell_grids(wl, hw)))
+        check(res_jx.cell_time.tobytes() == ref_t.tobytes()
+              and res_jx.cell_plan_idx.tobytes() == ref_i.tobytes(),
+              f"LM {name}: jax differs from one jitted grid a cell")
+        # a repeat of the question: one lm.dispatch per cell, none
+        # compiling, then one lm.fetch of every grid
+        C = len(wl.cells)
         with trace("lm") as root:
             lm_codesign(wl, hw=hw, engine="jax")
         (top,) = root.tree()["children"]
-        dispatches = top["children"]
-        check(top["name"] == "lm.codesign"
-              and [d["name"] for d in dispatches] == ["lm.dispatch"] * len(wl.cells)
-              and all([c["name"] for c in d.get("children", [])] == ["lm.fetch"]
-                      for d in dispatches), f"LM {name}: spans {top}")
+        dispatches, fetch = top["children"][:C], top["children"][C:]
+        check(top["name"] == "lm.codesign" and top["attrs"]["transfers"] == 1 + C
+              and [d["name"] for d in dispatches] == ["lm.dispatch"] * C
+              and not any(d.get("children") for d in dispatches)
+              and [(f["name"], f["attrs"]) for f in fetch] == [("lm.fetch", {"grids": C})],
+              f"LM {name}: spans {top}")
         compiles = [d["attrs"]["compiles"] for d in dispatches]
-        check(compiles == [0] * len(wl.cells), f"LM {name}: repeat compiled {compiles}")
-        say("lm", f"{name}: lm_codesign jax vs numpy: {len(wl.cells)} cells x {len(res_np.hw)} "
+        check(compiles == [0] * C, f"LM {name}: repeat compiled {compiles}")
+        say("lm", f"{name}: lm_codesign jax vs numpy: {C} cells x {len(res_np.hw)} "
             f"meshes, feasibility exact, max rel err {rel.max(initial=0.0):.3g}, "
             f"{diffs} differing plans all tied; best {res_np.hw.point(best)} at "
-            f"{gflops:.6g} GFLOP/s; repeat {len(dispatches)} dispatches, compiles {compiles}; "
+            f"{gflops:.6g} GFLOP/s; bit-identical to per-cell grids; repeat {C} dispatches, "
+            f"compiles {compiles}, {top['attrs']['transfers']} transfers, one fetch; "
             f"jax {t_jx:.2f}s OK")
 
 

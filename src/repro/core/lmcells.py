@@ -20,7 +20,8 @@ Two engines, mirroring :mod:`repro.core.codesign`: ``"numpy"`` evaluates the
 scalar oracle's exact float64 expressions vectorized over the whole
 ``(hw, sw)`` grid, and ``"jax"`` jits the identical traceable body in
 float32 (one compile per op kind, named ``jit_lm_grid_<op>`` -- cell
-constants enter as traced scalars). :func:`lm_cell_roofline` is the
+constants enter as one traced array, the op's software lattice as
+constants of the program). :func:`lm_cell_roofline` is the
 plain-scalar oracle both are parity-tested against, and what
 :func:`repro.core.lmtime.lm_roofline` evaluates; its docstring names the
 source of each term beyond the weights (attention over context, expert
@@ -628,6 +629,11 @@ _M_COMPILES = _REG.counter(
     "grid dispatches, counted on the dispatching thread",
     labels=("op",),
 )
+_M_TRANSFERS = _REG.counter(
+    "repro_lm_transfers_total",
+    "host-to-device copies of LM grid inputs: the question's meshes once, "
+    "then each cell's constants",
+)
 
 
 @contextlib.contextmanager
@@ -644,23 +650,33 @@ def _dispatch(op: str, h: int, l: int) -> Iterator[None]:
     _M_COMPILES.labels(op=op).inc(compiles)
 
 
-_JIT_CACHE: Dict[str, object] = {}
+_JIT_CACHE: Dict[Tuple[str, LMSwLattice], object] = {}
 
 
 def _jax_grid_fn(op: str):
-    """One compiled grid evaluator per op kind, named ``jit_lm_grid_<op>``
-    in HLO and on the device trace; constants are traced, so every cell of
-    an op reuses the same executable."""
-    if op not in _JIT_CACHE:
+    """One compiled grid evaluator per op kind and its software lattice
+    (:func:`lm_sw_lattice`, cached under both, so a changed lattice never
+    meets a stale program), named ``jit_lm_grid_<op>`` in HLO and on the
+    device trace. It takes a cell's constants as a float32 ``(11,)`` array
+    in :func:`_cell_consts`' order and the meshes as a float32 ``(3, H)``
+    array of pod, data and model rows, sliced to ``(H, 1)`` columns inside
+    the program; the lattice's columns are constants of the program. Every
+    cell of an op reuses the same executable."""
+    lat = lm_sw_lattice(op)
+    if (op, lat) not in _JIT_CACHE:
         import jax
         import jax.numpy as jnp
 
-        def grid(consts, pod, data, model, mb, remat, fsdp, compress):
-            return _grid_times(op, consts, pod, data, model, mb, remat, fsdp, compress, jnp)
+        sw = [np.asarray(c, np.float32)
+              for c in (lat.microbatches, lat.remat_full, lat.fsdp, lat.compress)]
+
+        def grid(consts, meshes):
+            pod, data, model = (meshes[i][:, None] for i in range(3))
+            return _grid_times(op, consts, pod, data, model, *map(jnp.asarray, sw), jnp)
 
         grid.__name__ = grid.__qualname__ = f"lm_grid_{op}"
-        _JIT_CACHE[op] = jax.jit(grid)
-    return _JIT_CACHE[op]
+        _JIT_CACHE[op, lat] = jax.jit(grid)
+    return _JIT_CACHE[op, lat]
 
 
 def resolve_lm_engine(engine: str) -> str:
@@ -857,9 +873,16 @@ def lm_codesign(
     same body in float32; ``"auto"`` picks jax when importable. Infeasible
     (cell, hw) combinations -- HBM overflow or unshardable batch at every
     software setting -- carry ``+inf`` time and plan index ``-1``, exactly
-    the stencil sweep's convention. One ``lm.codesign`` span holds an
-    ``lm.dispatch`` span per cell (its ``compiles`` attr counted as the
-    stencil sweep counts them), which holds the jax engine's ``lm.fetch``.
+    the stencil sweep's convention.
+
+    The jax engine copies the meshes to the device once, as one float32
+    ``(3, H)`` array, and each cell's constants once, as one float32
+    ``(11,)`` array; it enqueues every cell's grid before it fetches them
+    all together. One ``lm.codesign`` span (its ``transfers`` attr counts
+    those copies, 1 + C, and ``repro_lm_transfers_total`` grows by it)
+    holds an ``lm.dispatch`` span per cell (its ``compiles`` attr counted
+    as the stencil sweep counts them), then the jax engine's one
+    ``lm.fetch`` (attr ``grids``, the grids it fetched together).
     """
     if getattr(workload, "family", "stencil") != "lm":
         raise ValueError(f"lm_codesign wants an LM workload, got {workload.family!r}")
@@ -871,32 +894,34 @@ def lm_codesign(
     cell_idx = np.empty((C, H), dtype=np.int64)
     lattices = [lm_sw_lattice(c.op) for c in workload.cells]
     with span("lm.codesign", engine=eng, cells=C, h=H):
-        for ci, cell in enumerate(workload.cells):
-            lat = lattices[ci]
-            consts = _cell_consts(cell)
-            with _dispatch(cell.op, H, len(lat)):
-                if eng == "jax":
-                    import jax.numpy as jnp
+        grids = []
+        if eng == "jax":
+            import jax
 
-                    f32 = lambda a: jnp.asarray(np.asarray(a, np.float32))
-                    grid = _jax_grid_fn(cell.op)(
-                        consts,
-                        f32(hw.pod)[:, None], f32(hw.data)[:, None], f32(hw.model)[:, None],
-                        f32(lat.microbatches), f32(lat.remat_full),
-                        f32(lat.fsdp), f32(lat.compress),
-                    )
-                    with span("lm.fetch"):
-                        # blocks until the grid is done, then copies and casts
-                        grid = np.asarray(grid, np.float64)
-                else:
-                    c64 = lambda a: np.asarray(a, np.float64)
-                    grid = _grid_times(
-                        cell.op, consts,
+            meshes = jax.device_put(np.stack([hw.pod, hw.data, hw.model]).astype(np.float32))
+            for cell, lat in zip(workload.cells, lattices):
+                with _dispatch(cell.op, H, len(lat)):
+                    consts = jax.device_put(np.asarray(_cell_consts(cell), np.float32))
+                    grids.append(_jax_grid_fn(cell.op)(consts, meshes))
+            transfers = 1 + C
+            with span("lm.fetch", grids=C):
+                # blocks until every grid is done, then copies them together
+                grids = [np.asarray(g, np.float64) for g in jax.device_get(grids)]
+        else:
+            c64 = lambda a: np.asarray(a, np.float64)
+            for cell, lat in zip(workload.cells, lattices):
+                with _dispatch(cell.op, H, len(lat)):
+                    grids.append(_grid_times(
+                        cell.op, _cell_consts(cell),
                         c64(hw.pod)[:, None], c64(hw.data)[:, None], c64(hw.model)[:, None],
                         c64(lat.microbatches), c64(lat.remat_full),
                         c64(lat.fsdp), c64(lat.compress),
                         np,
-                    )
+                    ))
+            transfers = 0
+        set_attrs(transfers=transfers)
+        _M_TRANSFERS.inc(transfers)
+        for ci, grid in enumerate(grids):
             idx = np.argmin(grid, axis=1)
             t = grid[np.arange(H), idx]
             cell_time[ci] = t

@@ -414,3 +414,88 @@ def test_train_activations_divide_over_the_model_axis():
     narrow = lm_cell_roofline(free, MeshPlan(1, 8, 1, microbatches=4, remat="full"))
     wide = lm_cell_roofline(free, MeshPlan(1, 8, 4, microbatches=4, remat="full"))
     assert wide["hbm_bytes"] == pytest.approx(narrow["hbm_bytes"] / 4)
+
+
+# ---------------------------------------------------------------------------
+# the jax engine: inputs staged once a question, every grid fetched at once
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module", params=["default-pair-512", "deepseek-v3-2048"])
+def question(request):
+    """The two LM questions ``chip_smoke.py`` asks at full widths: the
+    default pair over the meshes of up to 512 chips, and DeepSeek-V3's
+    question over its 2,048-chip cluster (H = 144)."""
+    if request.param == "default-pair-512":
+        return lm_workload(), enumerate_lm_hw_space(max_chips=512)
+    return _ds_workload(get_arch("deepseek-v3-671b")), enumerate_lm_hw_space(max_chips=2048)
+
+
+def _per_cell_reference(wl, hw):
+    """The jax engine as a per-cell loop evaluates it: one jitted
+    :func:`_grid_times` a cell, its 11 constants as Python floats, the
+    meshes as (H, 1) and the lattice as (L,) float32 arguments, each grid
+    fetched before the next; argmin on the host."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.lmcells import _cell_consts, _grid_times
+
+    f32 = lambda a: jnp.asarray(np.asarray(a, np.float32))
+    times, idx = [], []
+    for cell in wl.cells:
+        lat = lm_sw_lattice(cell.op)
+        grid = jax.jit(lambda *a, op=cell.op: _grid_times(op, *a, jnp))(
+            _cell_consts(cell), f32(hw.pod)[:, None], f32(hw.data)[:, None],
+            f32(hw.model)[:, None], f32(lat.microbatches), f32(lat.remat_full),
+            f32(lat.fsdp), f32(lat.compress))
+        grid = np.asarray(grid, np.float64)
+        j = np.argmin(grid, axis=1)
+        t = grid[np.arange(len(hw)), j]
+        times.append(t)
+        idx.append(np.where(np.isfinite(t), j, -1))
+    return np.array(times), np.array(idx)
+
+
+def _bits(a):
+    return a.dtype, a.shape, a.tobytes()
+
+
+@pytest.mark.skipif(not sweep.HAVE_JAX, reason="jax not installed")
+def test_jax_engine_is_bit_identical_to_per_cell_grids(question):
+    wl, hw = question
+    res = lm_codesign(wl, hw=hw, engine="jax")
+    times, idx = _per_cell_reference(wl, hw)
+    assert _bits(res.cell_time) == _bits(times)
+    assert _bits(res.cell_plan_idx) == _bits(idx)
+    assert np.isfinite(res.cell_time).any(axis=1).all()
+
+
+@pytest.mark.skipif(not sweep.HAVE_JAX, reason="jax not installed")
+def test_permuted_meshes_give_the_permuted_result(question):
+    """The benchmark's questions: the whole mesh space in a new order."""
+    from repro.core.lmcells import LMHardwareSpace
+
+    wl, hw = question
+    res = lm_codesign(wl, hw=hw, engine="jax")
+    p = np.random.default_rng(2**31 + 7).permutation(len(hw))
+    moved = LMHardwareSpace(hw.pod[p], hw.data[p], hw.model[p], hw.area[p])
+    got = lm_codesign(wl, hw=moved, engine="jax")
+    assert _bits(got.cell_time) == _bits(np.ascontiguousarray(res.cell_time[:, p]))
+    assert _bits(got.cell_plan_idx) == _bits(np.ascontiguousarray(res.cell_plan_idx[:, p]))
+
+
+@pytest.mark.skipif(not sweep.HAVE_JAX, reason="jax not installed")
+def test_one_transfer_for_the_meshes_and_one_a_cell(question, monkeypatch):
+    import jax
+
+    wl, hw = question
+    real, calls = jax.device_put, []
+
+    def device_put(x, *args, **kwargs):
+        calls.append(np.shape(x))
+        return real(x, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", device_put)
+    for _ in range(2):  # the first question compiles, the second does not
+        calls.clear()
+        lm_codesign(wl, hw=hw, engine="jax")
+        assert calls == [(3, len(hw))] + [(11,)] * len(wl.cells)

@@ -251,31 +251,38 @@ def _lm_question():
 
 
 def test_lm_codesign_spans_nest_and_count_compiles():
-    """``lm.codesign`` holds one ``lm.dispatch`` per cell, each holding its
-    ``lm.fetch``; a repeat of the same question compiles nothing, and the
-    counter grows by what the spans counted."""
+    """``lm.codesign`` holds one ``lm.dispatch`` per cell, then one
+    ``lm.fetch`` of every grid; it made 1 + C host-to-device copies, and
+    ``repro_lm_transfers_total`` grows by them. A repeat of the same
+    question compiles nothing, and the compile counter grows by what the
+    spans counted."""
     from repro.core.lmcells import _JIT_CACHE, lm_codesign, lm_sw_lattice
 
     wl, hw = _lm_question()
+    C = len(wl.cells)
     _JIT_CACHE.clear()  # the first call traces each op's grid afresh
     counted = {op: counter("repro_lm_compiles_total", op=op) for op in
                ("prefill", "decode", "train", "moe_dispatch")}
     trees = []
     for _ in range(2):
+        transfers = counter("repro_lm_transfers_total")
         with trace("q") as root:
             lm_codesign(wl, hw=hw, engine="jax")
+        assert counter("repro_lm_transfers_total") == transfers + 1 + C
         (top,) = root.tree()["children"]
         trees.append(top)
     for top in trees:
         assert top["name"] == "lm.codesign"
-        assert top["attrs"] == {"engine": "jax", "cells": len(wl.cells), "h": len(hw)}
-        assert [names(d) for d in top["children"]] == [
-            ("lm.dispatch", [("lm.fetch", [])])] * len(wl.cells)
-        assert [(d["attrs"]["op"], d["attrs"]["h"], d["attrs"]["l"]) for d in top["children"]] == [
+        assert top["attrs"] == {"engine": "jax", "cells": C, "h": len(hw), "transfers": 1 + C}
+        assert [names(c) for c in top["children"]] == [("lm.dispatch", [])] * C + [
+            ("lm.fetch", [])]
+        dispatches, fetch = top["children"][:C], top["children"][C]
+        assert [(d["attrs"]["op"], d["attrs"]["h"], d["attrs"]["l"]) for d in dispatches] == [
             (c.op, len(hw), len(lm_sw_lattice(c.op))) for c in wl.cells]
-    first = [d["attrs"]["compiles"] for d in trees[0]["children"]]
+        assert fetch["attrs"] == {"grids": C}
+    first = [d["attrs"]["compiles"] for d in trees[0]["children"][:C]]
     assert all(n >= 1 for n in first)
-    assert all(d["attrs"]["compiles"] == 0 for d in trees[1]["children"])
+    assert all(d["attrs"]["compiles"] == 0 for d in trees[1]["children"][:C])
     for op, before in counted.items():
         (n,) = [n for n, c in zip(first, wl.cells) if c.op == op]
         assert counter("repro_lm_compiles_total", op=op) == before + n
@@ -283,12 +290,10 @@ def test_lm_codesign_spans_nest_and_count_compiles():
 
 @pytest.mark.parametrize("op", ["prefill", "decode", "train", "moe_dispatch"])
 def test_lm_grid_programs_have_stable_names(op):
-    from repro.core.lmcells import _cell_consts, _jax_grid_fn, lm_sw_lattice
+    from repro.core.lmcells import _cell_consts, _jax_grid_fn
 
     wl, hw = _lm_question()
     (cell,) = [c for c in wl.cells if c.op == op]
-    lat = lm_sw_lattice(op)
-    col = np.zeros((len(hw), 1), np.float32)
-    row = np.zeros(len(lat), np.float32)
-    lowered = _jax_grid_fn(op).lower(_cell_consts(cell), col, col, col, row, row, row, row)
+    consts = np.asarray(_cell_consts(cell), np.float32)
+    lowered = _jax_grid_fn(op).lower(consts, np.zeros((3, len(hw)), np.float32))
     assert f"module @jit_lm_grid_{op} " in lowered.as_text()
