@@ -1,8 +1,7 @@
 """Measurement + calibration loop: time the tile-parameterized Pallas
 stencils over a grid, refit the time model's machine parameters from the
 timings, and land the per-stencil predicted-vs-measured error
-before/after refit in a JSON artifact and the ``BENCH_sweep.json``
-trajectory. A synthetic-recovery stage asserts the fit itself is sound
+before/after refit in a JSON artifact. A synthetic-recovery stage asserts the fit itself is sound
 (model-generated timings from perturbed starting parameters must recover
 the generating machine) -- the empirical-loop analogue of the sweep
 suite's engine-parity asserts."""
@@ -16,7 +15,7 @@ from repro.measure import fit_machine_params, measure_grid, synthetic_records
 from repro.measure.calibrate import RECOVERY_RTOL
 from repro.measure.harness import default_grid
 
-from .common import append_trajectory, cache_json, emit, skey, smoke
+from .common import cache_json, emit, skey, smoke
 
 
 def run() -> None:
@@ -76,21 +75,3 @@ def run() -> None:
         f"max param rel err {err:.2e} (acceptance < {RECOVERY_RTOL})",
     )
     assert err < RECOVERY_RTOL, f"synthetic recovery off by {err:.1%}"
-
-    append_trajectory(
-        "sweep",
-        {
-            "suite": "measure",
-            "smoke": smoke(),
-            "records": len(measured.records),
-            "backend": measured.backend,
-            "interpret": measured.interpret,
-            "grid_s": round(t_grid, 3),
-            "fit_s": round(t_fit, 3),
-            "loss_before": cal.loss_before,
-            "loss_after": cal.loss_after,
-            "rel_err_before": {k: round(v, 4) for k, v in cal.errors_before.items()},
-            "rel_err_after": {k: round(v, 4) for k, v in cal.errors_after.items()},
-            "synthetic_recovery_rel_err": err,
-        },
-    )

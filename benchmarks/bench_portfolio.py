@@ -18,11 +18,9 @@ K = 2
 BUDGET = 900.0  # mm^2 fleet budget, the docs' running example
 
 
-def run() -> dict:
+def run() -> None:
     hw = enumerate_hw_space().downsample(SMOKE_HW_STRIDE if smoke() else 4)
-    t0 = time.perf_counter()
     res = codesign(paper_workload(), hw=hw, engine="numpy")
-    solve_s = time.perf_counter() - t0
 
     # the dominance prefilter is what makes C(n, K) enumerable: report how
     # hard it squeezes the swept space before any subset is scored
@@ -59,17 +57,3 @@ def run() -> dict:
         f"fleet {p_np.fleet_gflops:.0f} vs best single {single:.0f} GFLOP/s "
         f"under {BUDGET:.0f} mm^2",
     )
-    return {
-        "suite": "portfolio",
-        "smoke": smoke(),
-        "k": K,
-        "budget_mm2": BUDGET,
-        "n_hw": int(len(hw)),
-        "n_candidates": n_cand,
-        "sweep_solve_s": round(solve_s, 4),
-        "numpy_s": round(numpy_s, 4),
-        "jax_s": round(jax_s, 4),
-        "members": [int(i) for i in p_np.members],
-        "fleet_gflops": round(p_np.fleet_gflops, 1),
-        "single_gflops": round(float(single), 1),
-    }

@@ -14,9 +14,7 @@ entire point of persisting the separability matrix.
 The gateway stages build a second GPU target (titanx) into the same store
 and alternate requests across both artifacts -- real fleet traffic, every
 query routed -- first through :meth:`Gateway.query` in-process, then
-through the stdlib HTTP server + client. Gateway QPS (warm local vs
-over-HTTP) is appended to the repo-root ``BENCH_sweep.json`` trajectory
-(schema: ``benchmarks/README.md``)."""
+through the stdlib HTTP server + client."""
 
 from __future__ import annotations
 
@@ -41,7 +39,6 @@ from repro.service import (
 from .common import (
     ARTIFACTS,
     SMOKE_HW_STRIDE,
-    append_trajectory,
     emit,
     skey,
     smoke,
@@ -250,25 +247,4 @@ def run() -> None:
     assert res_overhead < 0.05, (
         f"resilience tax {res_overhead * 100:.1f}% >= 5% "
         f"(on {qps_res_on:.0f} q/s, off {qps_res_off:.0f} q/s)"
-    )
-
-    append_trajectory(
-        "sweep",
-        {
-            "suite": "service",
-            "smoke": smoke(),
-            "artifacts": len(gw),
-            "hw_points": len(srv.hw),
-            "cold_s": round(t_cold, 4),
-            "warm_qps": round(qps_warm, 1),
-            "warm_lru_qps": round(len(reqs) / t_lru, 1),
-            "batched_qps": round(len(batch) / t_batch, 1),
-            "gateway_local_qps": round(qps_gw_local, 1),
-            "gateway_http_conn_per_req_qps": round(qps_http_cpr, 1),
-            "gateway_http_qps": round(qps_gw_http, 1),
-            "gateway_http_batched_qps": round(qps_http_many, 1),
-            "resilience_on_qps": round(qps_res_on, 1),
-            "resilience_off_qps": round(qps_res_off, 1),
-            "resilience_overhead_pct": round(res_overhead * 100, 2),
-        },
     )

@@ -4,16 +4,12 @@ the whole suite is CI-runnable in minutes)."""
 
 from __future__ import annotations
 
-import datetime
 import json
-import math
 import os
 import time
 from typing import Callable, Dict
 
 ARTIFACTS = os.path.join(os.path.dirname(__file__), "artifacts")
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: hardware-space downsampling stride used by suites in smoke mode.
 SMOKE_HW_STRIDE = 8
@@ -30,18 +26,6 @@ def smoke() -> bool:
     """True when running under ``benchmarks/run.py --smoke`` (env contract
     so suite modules stay import-order independent)."""
     return os.environ.get("REPRO_BENCH_SMOKE", "") == "1"
-
-
-def refine_enabled() -> bool:
-    """True when ``benchmarks/run.py --refine`` asked the sweep suite to run
-    the batched coordinate-descent polish stage (same env contract)."""
-    return os.environ.get("REPRO_BENCH_REFINE", "") == "1"
-
-
-def lm_enabled() -> bool:
-    """True when ``benchmarks/run.py --lm`` asked the sweep suite to time
-    the LM cell family (mesh-factorization sweep) alongside the stencils."""
-    return os.environ.get("REPRO_BENCH_LM", "") == "1"
 
 
 def skey(key: str) -> str:
@@ -64,87 +48,6 @@ def timed(fn: Callable, *args, repeats: int = 3, **kw):
 def emit(name: str, us_per_call: float, derived: str) -> None:
     """The harness CSV contract: name,us_per_call,derived."""
     print(f"{name},{us_per_call:.1f},{derived}")
-
-
-#: key suffixes that declare a units contract for trajectory fields --
-#: any field named ``*_s`` / ``*_qps`` / ``*_us`` (or any leaf under such
-#: a field, e.g. ``engines_total_s``'s per-engine values) must be a
-#: finite number, or the trajectory diff across PRs turns meaningless.
-_NUMERIC_SUFFIXES = ("_s", "_qps", "_us")
-
-
-def _leaves(value):
-    if isinstance(value, dict):
-        for v in value.values():
-            yield from _leaves(v)
-    elif isinstance(value, (list, tuple)):
-        for v in value:
-            yield from _leaves(v)
-    else:
-        yield value
-
-
-def validate_trajectory_entry(record: Dict) -> None:
-    """Schema gate for trajectory entries (raises ``TypeError``/
-    ``ValueError``): a dict carrying a non-empty ``"suite"`` string, with
-    every units-suffixed field (see ``_NUMERIC_SUFFIXES``) holding finite
-    numbers. A NaN/inf/None wall time means the suite recorded a
-    measurement it never actually took -- fail the run, don't commit it."""
-    if not isinstance(record, dict):
-        raise TypeError(
-            f"trajectory entry must be a dict, got {type(record).__name__}"
-        )
-    if not isinstance(record.get("suite"), str) or not record["suite"]:
-        raise ValueError("trajectory entry must carry a non-empty 'suite' string")
-
-    def _walk(obj: Dict, path: str) -> None:
-        for k, v in obj.items():
-            here = f"{path}.{k}" if path else str(k)
-            if str(k).endswith(_NUMERIC_SUFFIXES):
-                for leaf in _leaves(v):
-                    if (
-                        isinstance(leaf, bool)
-                        or not isinstance(leaf, (int, float))
-                        or not math.isfinite(leaf)
-                    ):
-                        raise ValueError(
-                            f"trajectory field {here!r} must hold finite "
-                            f"numbers, got {leaf!r}"
-                        )
-            elif isinstance(v, dict):
-                _walk(v, here)
-
-    _walk(record, "")
-
-
-def append_trajectory(name: str, record: Dict) -> str:
-    """Append a timestamped entry to the repo-root ``BENCH_<name>.json``
-    perf trajectory (a JSON list, one entry per recorded run), so wall-time
-    regressions are diffable across PRs. Returns the file path.
-
-    Unlike :func:`cache_json` artifacts (scratch outputs under
-    ``benchmarks/artifacts/``), the trajectory is a *committed* file: each
-    PR's benchmark run extends it in place. Entries pass
-    :func:`validate_trajectory_entry` before touching the file."""
-    validate_trajectory_entry(record)
-    path = os.path.join(REPO_ROOT, f"BENCH_{name}.json")
-    entries = []
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                entries = json.load(f)
-        except (json.JSONDecodeError, OSError):
-            entries = []  # corrupt trajectory: restart rather than crash
-    if not isinstance(entries, list):
-        entries = []
-    entries.append(
-        {"ts": datetime.datetime.now(datetime.timezone.utc).isoformat(), **record}
-    )
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(entries, f, indent=1)
-    os.replace(tmp, path)
-    return path
 
 
 def cache_json(key: str, compute: Callable[[], Dict], force: bool = False) -> Dict:

@@ -385,22 +385,40 @@ def phase_portfolio(oracles):
 
 
 def phase_sharded(n_dev):
-    """engine="sharded" over n_dev chips against engine="jax" on one."""
+    """The sharded engine over n_dev chips against engine="jax" on one:
+    ``engine="auto"`` when n_dev is every attached chip, else
+    ``sweep_cells_sharded(devices=n_dev)`` one stencil group at a time."""
+    import jax
     import numpy as np
 
-    from repro.core import codesign, enumerate_hw_space
+    from repro.core import codesign, enumerate_hw_space, sweep
+    from repro.core.codesign import _stencil_groups
+    from repro.core.engines import dispatch_engine
     from repro.core.workload import paper_workload
 
+    wl = paper_workload()
     full = enumerate_hw_space()
     for label, hw in (("paper space", full), ("every third point", full.downsample(3))):
-        res_jax = codesign(paper_workload(), hw=hw, engine="jax")
+        res_jax = codesign(wl, hw=hw, engine="jax")
         t0 = time.perf_counter()
-        res_sh = codesign(paper_workload(), hw=hw, engine="sharded", devices=n_dev)
+        if n_dev == jax.device_count():
+            check(dispatch_engine("auto", len(hw)) == "sharded",
+                  f"engine=auto does not shard over {n_dev} chips")
+            res = codesign(wl, hw=hw, engine="auto")
+            times, idx, how = res.cell_time, res.cell_tile_idx, "engine=auto"
+        else:
+            times = np.empty_like(res_jax.cell_time)
+            idx = np.empty_like(res_jax.cell_tile_idx)
+            for st, cis, sizes in _stencil_groups(wl).values():
+                times[cis], idx[cis] = sweep.sweep_cells_sharded(
+                    st, res_jax.gpu, sizes, hw.n_sm, hw.n_v, hw.m_sm,
+                    res_jax.lattices[cis[0]], devices=n_dev)
+            how = "sweep_cells_sharded"
         t_sh = time.perf_counter() - t0
-        same = (np.array_equal(res_sh.cell_time, res_jax.cell_time)
-                and np.array_equal(res_sh.cell_tile_idx, res_jax.cell_tile_idx))
+        same = (np.array_equal(times, res_jax.cell_time)
+                and np.array_equal(idx, res_jax.cell_tile_idx))
         check(same, f"sharded over {n_dev} chips differs from jax on {label}")
-        say("sharded", f"{label} (H={len(hw)}): engine=sharded on {n_dev} chips "
+        say("sharded", f"{label} (H={len(hw)}): {how} on {n_dev} chips "
             f"bit-identical to engine=jax on one (cell_time and cell_tile_idx); "
             f"sharded {t_sh:.2f}s OK")
 

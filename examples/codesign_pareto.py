@@ -13,14 +13,13 @@ import numpy as np
 
 from repro.core import GTX980, MAXWELL, TITAN_X, codesign, enumerate_hw_space
 from repro.core.codesign import evaluate_fixed_hw
+from repro.core.engines import ENGINES
 from repro.core.pareto import pareto_mask
 from repro.core.workload import paper_workload
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--fast", action="store_true")
-ap.add_argument(
-    "--engine", choices=("auto", "jax", "sharded", "numpy"), default="auto"
-)
+ap.add_argument("--engine", choices=ENGINES, default="auto")
 args = ap.parse_args()
 
 for cls, names in (

@@ -23,7 +23,6 @@ from .solver import LATTICE_2D, LATTICE_3D, TileLattice, refine_point, solve_cel
 # .sweep imports jax at module scope (~1s); load it lazily (PEP 562) so the
 # pure-NumPy oracle/area paths keep the seed's cheap `import repro.core`.
 _SWEEP_EXPORTS = (
-    "HAVE_JAX",
     "device_count",
     "refine_points",
     "sweep_cell",

@@ -7,18 +7,18 @@ cached as a ``(cells x hardware)`` matrix, the §V.B "workload sensitivity
 for free" analyses (re-weighting frequencies, single-stencil workloads)
 are simple matrix re-reductions -- no re-solving.
 
-The inner solves run on one of three engines:
+The inner solves run on one of the engines that :mod:`repro.core.engines`
+chooses between:
 
 * ``"jax"`` -- the compiled sweep of :mod:`repro.core.sweep` (jitted vmap
-  over hardware x tile lattice; CPU/GPU/TPU); the default whenever jax is
-  importable and the hardware space is big enough to amortize compilation;
-* ``"sharded"`` -- the same fused body with the hardware axis partitioned
-  over a 1-D device mesh (``shard_map`` + ``NamedSharding``); bit-identical
-  to ``"jax"`` and the ``engine="auto"`` promotion whenever more than one
-  device is attached (the ``devices=`` knob picks the mesh);
+  over hardware x tile lattice; CPU/GPU/TPU);
 * ``"numpy"`` -- the seed's chunked-broadcast reference solver
   (:func:`repro.core.solver.solve_cell`), kept bit-exact as the oracle the
-  jax engines are equivalence-tested against.
+  jax engines are equivalence-tested against;
+* ``"auto"`` -- numpy below 64 hardware points, else jax; with more than
+  one device attached, jax's body with the hardware axis partitioned over
+  a 1-D device mesh (``shard_map`` + ``NamedSharding``), bit-identical to
+  ``"jax"``.
 """
 
 from __future__ import annotations
@@ -33,6 +33,7 @@ from repro.obs.metrics import get_registry as _obs_registry
 from repro.obs.trace import span
 
 from .area import GTX980, TITAN_X, HardwarePoint, LinearAreaModel, MAXWELL
+from .engines import dispatch_engine, engine_family
 from .pareto import pareto_mask
 from .solver import LATTICE_2D, LATTICE_3D, TileLattice, decode_index, solve_cell
 from .timemodel import GPUSpec, MAXWELL_GPU, ProblemSize, stencil_time
@@ -226,20 +227,9 @@ class CodesignResult:
                 [[start[ci][k] for k in sweep.SW_NAMES] for ci in cis],
                 np.float64,
             )
-            if sweep.HAVE_JAX:
-                _, sw_ref = sweep.refine_points(
-                    st, self.gpu, sizes, np.tile(hw_row, (len(cis), 1)), sw0
-                )
-            else:  # seed fallback: sequential scans
-                from .solver import refine_point
-
-                sw_ref = np.empty_like(sw0)
-                for j, ci in enumerate(cis):
-                    _, swd = refine_point(
-                        st, self.gpu, self.workload.cells[ci].size, hw_row,
-                        dict(start[ci]),
-                    )
-                    sw_ref[j] = [swd[k] for k in sweep.SW_NAMES]
+            _, sw_ref = sweep.refine_points(
+                st, self.gpu, sizes, np.tile(hw_row, (len(cis), 1)), sw0
+            )
             # re-evaluate BOTH candidates in the float64 oracle model:
             # acceptance must never be decided by float32 evaluation noise,
             # and reported times must reproduce at the reported tiles
@@ -377,55 +367,6 @@ class CodesignResult:
         )
 
 
-#: below this many hardware points the jit compile cannot pay for itself;
-#: ``engine="auto"`` falls back to the NumPy reference solver.
-_AUTO_MIN_HW = 64
-
-
-def _devices_engine(engine: str, devices) -> str:
-    """An explicit device selection IS a request for the mesh engine:
-    promote auto (even below the numpy floor -- the caller knows their
-    mesh) and reject engines that would silently drop the knob. Cheap
-    (never touches jax), so key-time callers can share the rule."""
-    if devices is None or engine == "sharded":
-        return engine
-    if engine == "auto":
-        return "sharded"
-    raise ValueError(
-        f"devices= only applies to engine='sharded' (or 'auto'); "
-        f"engine={engine!r} would silently ignore it"
-    )
-
-
-def _resolve_engine(engine: str, n_hw: int, devices=None) -> str:
-    if engine not in ("auto", "jax", "sharded", "numpy"):
-        raise ValueError(
-            f"unknown engine {engine!r} (want auto|jax|sharded|numpy)"
-        )
-    engine = _devices_engine(engine, devices)
-    # decide every numpy-bound case before touching .sweep: importing it
-    # loads jax (~1s), which the lazy PEP-562 loader exists to avoid
-    if engine == "numpy" or (engine == "auto" and n_hw < _AUTO_MIN_HW):
-        return "numpy"
-    from . import sweep
-
-    if engine == "auto":
-        if not sweep.HAVE_JAX:
-            return "numpy"
-        # promote to the mesh engine whenever there is a mesh to feed;
-        # on one device "sharded" degenerates to "jax" (same program),
-        # so the single-device jit path stays the simpler choice.
-        if sweep.device_count() > 1:
-            return "sharded"
-        return "jax"
-    if not sweep.HAVE_JAX:
-        raise ModuleNotFoundError(
-            f"engine={engine!r} requested but jax is not installed; "
-            "use engine='auto' (soft fallback) or engine='numpy'"
-        )
-    return engine
-
-
 def codesign(
     workload: Workload,
     gpu: GPUSpec = MAXWELL_GPU,
@@ -436,20 +377,16 @@ def codesign(
     lattice_3d: TileLattice = LATTICE_3D,
     chunk: Optional[int] = None,
     engine: str = "auto",
-    devices=None,
 ) -> CodesignResult:
     """Solve eq. (18): for every feasible hardware point, the optimal tile
     sizes (and time) of every workload cell.
 
-    ``engine`` picks the inner solver: ``"jax"`` (compiled sweep),
-    ``"sharded"`` (hardware axis over a device mesh), ``"numpy"`` (seed
-    reference), or ``"auto"`` (sharded when >1 device is attached, else
-    jax, else numpy). ``chunk`` bounds solver memory (hardware points per
-    slab -- per device on the sharded engine); ``None`` uses each engine's
-    default. ``devices`` is ``None`` for every attached device, an int for
-    the first n, or an explicit device sequence; setting it implies the
-    mesh engine (``"auto"`` promotes to ``"sharded"``, non-mesh engines
-    reject it rather than silently ignore it).
+    ``engine`` picks the inner solver: ``"jax"`` (compiled sweep on one
+    device), ``"numpy"`` (seed reference), or ``"auto"``
+    (:func:`repro.core.engines.dispatch_engine`: numpy below 64 hardware
+    points, else the sharded engine when more than one device is attached,
+    else jax). ``chunk`` bounds solver memory (hardware points per slab --
+    per device on the sharded engine); ``None`` uses each engine's default.
 
     Dispatches on the workload's cell family: LM op-graph workloads
     (``workload.family == "lm"``) route to :func:`repro.core.lmcells
@@ -459,12 +396,12 @@ def codesign(
     model, tile lattices) do not apply there.
     """
     if getattr(workload, "family", "stencil") == "lm":
-        from .lmcells import lm_codesign, resolve_lm_engine
+        from .lmcells import lm_codesign
 
         t0 = time.perf_counter()
         with span("codesign", family="lm"):
             result = lm_codesign(workload, hw=hw, engine=engine)
-        eng = resolve_lm_engine(engine)
+        eng = engine_family(engine)
         _M_CODESIGN_SECONDS.labels(engine=eng, family="lm").observe(
             time.perf_counter() - t0
         )
@@ -472,7 +409,7 @@ def codesign(
         return result
     if hw is None:
         hw = enumerate_hw_space(area_model, max_area=max_area)
-    eng = _resolve_engine(engine, len(hw), devices)
+    eng = dispatch_engine(engine, len(hw))
     C, H = len(workload.cells), len(hw)
     cell_time = np.empty((C, H))
     cell_idx = np.empty((C, H), dtype=np.int64)
@@ -487,17 +424,11 @@ def codesign(
             # dispatch/launch overhead on accelerators; same argmins).
             from . import sweep
 
+            solve = sweep.sweep_cells_sharded if eng == "sharded" else sweep.sweep_cells
             for st, cis, sizes in _stencil_groups(workload).values():
-                if eng == "sharded":
-                    t, i = sweep.sweep_cells_sharded(
-                        st, gpu, sizes, hw.n_sm, hw.n_v, hw.m_sm,
-                        lattices[cis[0]], chunk, devices=devices,
-                    )
-                else:
-                    t, i = sweep.sweep_cells(
-                        st, gpu, sizes, hw.n_sm, hw.n_v, hw.m_sm,
-                        lattices[cis[0]], chunk,
-                    )
+                t, i = solve(
+                    st, gpu, sizes, hw.n_sm, hw.n_v, hw.m_sm, lattices[cis[0]], chunk
+                )
                 for j, ci in enumerate(cis):
                     cell_time[ci] = t[j]
                     cell_idx[ci] = i[j]

@@ -41,6 +41,7 @@ from ..configs.base import SHAPES, ArchConfig, ShapeSpec
 from ..obs.compiles import compiles_so_far, listen_for_compiles
 from ..obs.metrics import get_registry as _obs_registry
 from ..obs.trace import set_attrs, span
+from .engines import engine_family
 from .lmtime import HW, MeshPlan
 from .pareto import pareto_mask
 from .workload import Workload
@@ -59,7 +60,6 @@ __all__ = [
     "lm_workload",
     "lm_cell_roofline",
     "lm_codesign",
-    "resolve_lm_engine",
 ]
 
 #: default "gpu" routing attribute of LM artifacts: the chip the roofline
@@ -240,9 +240,9 @@ def lm_sw_lattice(op: str) -> LMSwLattice:
     """The software lattice an op minimizes over (the tile-size analogue).
 
     Train steps search the full ``microbatches x remat x fsdp x compress``
-    product (48 rows, matching :func:`repro.core.meshopt.enumerate_plans`'s
-    knob ranges); inference ops and MoE dispatch have no backward pass, so
-    only the weight-sharding knob remains (2 rows).
+    product (48 rows: microbatches 1..32, remat none/full, fsdp off/on,
+    compression off/on); inference ops and MoE dispatch have no backward
+    pass, so only the weight-sharding knob remains (2 rows).
     """
     if op == "train":
         rows = list(
@@ -405,7 +405,8 @@ def lm_workload(
 # Scalar oracle
 # ---------------------------------------------------------------------------
 def _div_ok(op: str, gb: int, data_shards: int, microbatches: int) -> bool:
-    """The :func:`repro.core.meshopt.optimize` shardability constraints."""
+    """Shardability: the global batch splits evenly over the data shards
+    (or is smaller than them), and a train step's over its microbatches."""
     if gb % data_shards and gb >= data_shards:
         return False
     if op == "train" and gb % (data_shards * microbatches):
@@ -679,26 +680,6 @@ def _jax_grid_fn(op: str):
     return _JIT_CACHE[op, lat]
 
 
-def resolve_lm_engine(engine: str) -> str:
-    """Concrete engine for the LM sweep. The LM hardware axis is small
-    (dozens of factorizations), so ``"sharded"`` degenerates to the
-    single-program jit path rather than paying mesh setup."""
-    if engine not in ("auto", "jax", "sharded", "numpy"):
-        raise ValueError(f"unknown engine {engine!r} (want auto|jax|sharded|numpy)")
-    if engine == "numpy":
-        return "numpy"
-    from . import sweep  # module import only; no backend init
-
-    if engine == "auto":
-        return "jax" if sweep.HAVE_JAX else "numpy"
-    if not sweep.HAVE_JAX:
-        raise ModuleNotFoundError(
-            f"engine={engine!r} requested but jax is not installed; "
-            "use engine='auto' (soft fallback) or engine='numpy'"
-        )
-    return "jax"
-
-
 # ---------------------------------------------------------------------------
 # Result + driver
 # ---------------------------------------------------------------------------
@@ -870,7 +851,8 @@ def lm_codesign(
 
     ``engine="numpy"`` evaluates the oracle's float64 expressions
     vectorized (bit-exact vs :func:`lm_cell_roofline`); ``"jax"`` jits the
-    same body in float32; ``"auto"`` picks jax when importable. Infeasible
+    same body in float32; ``"auto"`` is jax (:func:`repro.core.engines
+    .engine_family`, with no hardware floor). Infeasible
     (cell, hw) combinations -- HBM overflow or unshardable batch at every
     software setting -- carry ``+inf`` time and plan index ``-1``, exactly
     the stencil sweep's convention.
@@ -888,7 +870,7 @@ def lm_codesign(
         raise ValueError(f"lm_codesign wants an LM workload, got {workload.family!r}")
     if hw is None:
         hw = enumerate_lm_hw_space(max_chips=max_chips)
-    eng = resolve_lm_engine(engine)
+    eng = engine_family(engine)
     C, H = len(workload.cells), len(hw)
     cell_time = np.empty((C, H))
     cell_idx = np.empty((C, H), dtype=np.int64)

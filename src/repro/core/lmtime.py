@@ -10,9 +10,8 @@ Software parameters s: microbatches, remat policy, fsdp on/off,
 
 The model returns the three roofline terms (seconds/step, per chip) plus
 an HBM-fit feasibility flag (the eq. 9/11 analogue: the working set must
-fit the per-chip memory budget). Constants are validated against the
-dry-run artifacts: `meshopt.optimize` only *proposes*; §Perf re-lowers the
-winning plans and measures the real compiled terms.
+fit the per-chip memory budget). The constants are v5e datasheet values;
+no prediction has yet been checked against a timed step on the chip.
 """
 
 from __future__ import annotations

@@ -19,8 +19,7 @@ solver is stronger (global-on-lattice instead of a local NLP solve).
 
 This module is the **NumPy reference oracle**: the compiled JAX engine in
 :mod:`repro.core.sweep` must match its argmins cell-by-cell (see
-``tests/test_sweep.py``), and ``benchmarks/bench_sweep.py`` tracks the
-wall-time gap between the two. Keep it simple and exact rather than fast.
+``tests/test_sweep.py``). Keep it simple and exact rather than fast.
 """
 
 from __future__ import annotations

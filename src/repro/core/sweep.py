@@ -26,12 +26,6 @@ and runs it on whatever backend is attached (CPU, GPU, TPU):
   all reported design points at once -- each descent round evaluates every
   (point, +/-step neighbor) pair in a single compiled call instead of the
   seed's one-at-a-time Python loops.
-
-When jax is absent ``HAVE_JAX`` is False and every entry point raises
-``ModuleNotFoundError`` -- asking for the compiled engine is an explicit
-contract. Graceful degradation lives one layer up: the driver
-(:mod:`repro.core.codesign`) defaults to ``engine="auto"``, which routes
-to the NumPy reference solver instead of this module.
 """
 
 from __future__ import annotations
@@ -42,7 +36,12 @@ import time
 import warnings
 from typing import Dict, Iterator, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.obs.compiles import compiles_so_far, listen_for_compiles
 from repro.obs.metrics import get_registry as _obs_registry
@@ -52,23 +51,7 @@ from .solver import TileLattice
 from .solver import _STEPS as _SOLVER_STEPS
 from .timemodel import GPUSpec, ProblemSize, StencilSpec, stencil_time
 
-try:  # pragma: no cover - exercised implicitly on import
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.sharding import Mesh, NamedSharding
-    from jax.sharding import PartitionSpec as P
-
-    HAVE_JAX = True
-except ModuleNotFoundError:  # pragma: no cover
-    jax = None
-    jnp = None
-    lax = None
-    Mesh = NamedSharding = P = None
-    HAVE_JAX = False
-
 __all__ = [
-    "HAVE_JAX",
     "DEFAULT_CHUNK",
     "device_count",
     "sweep_cell",
@@ -116,8 +99,7 @@ _M_COMPILES = _REG.counter(
     labels=("engine",),
 )
 
-if HAVE_JAX:
-    listen_for_compiles()
+listen_for_compiles()
 
 
 @contextlib.contextmanager
@@ -139,28 +121,19 @@ def _dispatch(engine: str, dims: int, p: int, h: int) -> Iterator[None]:
     _M_OPTIMA.labels(engine=engine).inc(p * h)
 
 
-def _require_jax():
-    if not HAVE_JAX:
-        raise ModuleNotFoundError(
-            "jax is required for the compiled sweep engine; "
-            "use engine='numpy' (repro.core.solver.solve_cell) instead"
-        )
-
-
 def device_count() -> int:
-    """Attached devices, 0 when jax is absent. The engine="auto" promotion
-    test monkeypatches this, so route all auto decisions through here."""
-    return jax.device_count() if HAVE_JAX else 0
+    """Attached devices. The engine="auto" promotion test monkeypatches
+    this, so route all auto decisions through here."""
+    return jax.device_count()
 
 
 def _resolve_devices(devices):
     """Normalize the ``devices=`` knob to a concrete device list.
 
-    ``None`` -> every attached device; an int n -> the first n devices (so
-    scaling-efficiency benchmarks can sweep 1..D on one host); an explicit
-    sequence of jax devices is used as-is.
+    ``None`` -> every attached device; an int n -> the first n devices (a
+    mesh smaller than the host); an explicit sequence of jax devices is
+    used as-is.
     """
-    _require_jax()
     if devices is None:
         return tuple(jax.devices())
     if isinstance(devices, int):
@@ -266,7 +239,6 @@ def _cells_solver(dims: int, gpu: GPUSpec, lattice: TileLattice, chunk: int):
     The whole six-stencil paper sweep still compiles exactly twice
     (2D + 3D); only a new (P, H) shape pair retraces.
     """
-    _require_jax()
     lat, keep_idx = _lattice_arrays(lattice, gpu)
     if keep_idx.shape[0] == 0:  # no candidate survives the static constraints
         return _solve_empty
@@ -321,7 +293,6 @@ def _sharded_cells_solver(
     regardless of how large the hardware space grows. The hw slab buffers
     are donated: at fleet scale they are dead weight after the stack.
     """
-    _require_jax()
     mesh = Mesh(np.array(devices), ("hw",))
     lat, keep_idx = _lattice_arrays(lattice, gpu)
     if keep_idx.shape[0] == 0:
@@ -393,7 +364,6 @@ def sweep_cells(
     infeasible points get ``+inf`` / ``-1``. ``chunk=None`` scales the
     hardware slab down by P so peak memory matches the single-size sweep.
     """
-    _require_jax()
     lattice, sizes, chunk = _prep_cells(st, sizes, lattice, chunk)
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
     h = np.asarray(n_sm).size
@@ -440,7 +410,6 @@ def sweep_cells_sharded(
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` *before* jax
     initializes to exercise the real sharded path.
     """
-    _require_jax()
     lattice, sizes, chunk = _prep_cells(st, sizes, lattice, chunk)
     devs = _resolve_devices(devices)
     n_dev = len(devs)
@@ -502,8 +471,6 @@ def sweep_cell(
 
     Returns ``(best_time (H,), best_lattice_index (H,))`` as float64/int64
     NumPy arrays; infeasible hardware points get ``+inf`` / ``-1``.
-    Raises ``ModuleNotFoundError`` when jax is unavailable (use
-    ``codesign(engine="auto")`` or the NumPy solver for soft fallback).
     """
     sizes = np.array([[size.s1, size.s2, size.s3, size.t]], np.float64)
     best_t, best_i = sweep_cells(
@@ -529,7 +496,6 @@ def _refine_descent(dims: int, gpu: GPUSpec):
     ``max_rounds`` is a dynamic operand, so changing the budget never
     retraces.
     """
-    _require_jax()
     steps = jnp.asarray(SW_STEPS, jnp.float32)
     mins = jnp.asarray(SW_MINS, jnp.float32)
     n_par = len(SW_NAMES)
@@ -618,7 +584,6 @@ def refine_points(
     (the previous per-round ``bool(jnp.all(...))`` convergence check forced
     a blocking transfer every round).
     """
-    _require_jax()
     hw64 = np.asarray(hw, np.float64)
     sizes64 = np.asarray(sizes, np.float64)
     sw = np.asarray(sw0, np.float64)

@@ -85,7 +85,7 @@ def applicable(arch: str, shape_name: str) -> Tuple[bool, str]:
 
 
 # ---------------------------------------------------------------------------
-# Per-cell plan: the pre-hillclimb defaults (meshopt refines these in §Perf)
+# Per-cell plan: the pre-hillclimb defaults
 # ---------------------------------------------------------------------------
 def plan_cell(cfg: ArchConfig, shape: ShapeSpec, mesh) -> Dict:
     """Pre-hillclimb defaults.

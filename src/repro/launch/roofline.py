@@ -100,7 +100,7 @@ _LEVERS = {
     "collective": (
         "re-shard to cut all-reduce bytes: sequence-parallel reduce-scatter, "
         "microbatch-amortized grad reduction, int8 cross-pod compression, "
-        "or a different mesh factorization (meshopt)"
+        "or a different mesh factorization"
     ),
 }
 

@@ -30,6 +30,7 @@ import time
 from typing import Optional
 
 from repro.compile_cache import enable_compile_cache
+from repro.core.engines import ENGINES
 from repro.service.cli import DEFAULT_STORE, _die, _gpu, _gpu_names
 from repro.service.store import Artifact, ArtifactStore
 
@@ -265,9 +266,7 @@ def main(argv=None) -> None:
                    help="calibration artifact (default: most recent)")
     b.add_argument("--max-hw-area", type=float, default=650.0)
     b.add_argument("--downsample", type=int, default=1)
-    b.add_argument(
-        "--engine", choices=("auto", "jax", "sharded", "numpy"), default="auto"
-    )
+    b.add_argument("--engine", choices=ENGINES, default="auto")
     b.set_defaults(fn=cmd_build)
 
     args = ap.parse_args(argv)

@@ -49,6 +49,7 @@ import urllib.error
 import numpy as np
 
 from ..compile_cache import enable_compile_cache
+from ..core.engines import ENGINES
 from .query import QueryRequest
 from .server import CodesignServer
 from .store import ArtifactStore
@@ -114,13 +115,7 @@ def _add_server_args(p: argparse.ArgumentParser) -> None:
                    help="hardware-space enumeration budget (mm^2)")
     p.add_argument("--downsample", type=int, default=1,
                    help="keep every Nth hardware point (quick demos)")
-    p.add_argument(
-        "--engine", choices=("auto", "jax", "sharded", "numpy"), default="auto"
-    )
-    p.add_argument(
-        "--devices", type=int, default=None,
-        help="sharded engine: first N attached devices (default: all)",
-    )
+    p.add_argument("--engine", choices=ENGINES, default="auto")
 
 
 def _server(args):
@@ -156,7 +151,6 @@ def _server(args):
         max_area=args.max_hw_area,
         downsample=args.downsample,
         engine=args.engine,
-        devices=args.devices,
         batch_window=0.0,  # CLI is single-threaded; no rendezvous needed
     )
 

@@ -18,9 +18,8 @@ object (field for field, and on the wire byte for byte) the in-process
 Transport: one persistent ``http.client.HTTPConnection`` per client,
 reused across requests (the gateway speaks HTTP/1.1 keep-alive). The
 previous ``urllib`` implementation opened a fresh TCP connection per
-request -- connection setup was most of the measured ~7-10x wire tax
-(ROADMAP; before/after QPS lands in ``BENCH_sweep.json`` via
-``benchmarks/bench_service.py``). A request that fails on a *reused*
+request -- connection setup was most of the wire tax
+``benchmarks/bench_service.py`` measured on a CPU host. A request that fails on a *reused*
 connection (the server closed its keep-alive side) is retried once on a
 fresh connection; a fresh-connection failure propagates. ``keepalive=
 False`` restores the connection-per-request behavior for A/B measurement.

@@ -281,11 +281,12 @@ class CodesignServer(_BaseServer):
     waits for followers; 0 disables batching (every query answers solo,
     still thread-safe). The default workload is the paper's Fig.-3
     six-stencil uniform mix; ``downsample`` thins the hardware space for
-    demos/CI. ``engine``/``devices`` pick the sweep engine for the miss
-    path (``"sharded"`` partitions the hardware axis over a device mesh);
-    the content address canonicalizes bit-identical engines, so an
-    artifact built sharded on an 8-device host warms a single-device
-    ``engine="jax"`` server and vice versa.
+    demos/CI. ``engine`` picks the sweep engine for the miss path
+    (:mod:`repro.core.engines`; ``"auto"`` shards the hardware axis over
+    every attached device when there is more than one); the content
+    address records only the engine's matrix family, so an artifact built
+    on an 8-device host warms a single-device ``engine="jax"`` server and
+    vice versa.
     """
 
     def __init__(
@@ -299,7 +300,6 @@ class CodesignServer(_BaseServer):
         downsample: int = 1,
         engine: str = "auto",
         chunk: Optional[int] = None,
-        devices=None,
         lattice_2d: TileLattice = LATTICE_2D,
         lattice_3d: TileLattice = LATTICE_3D,
         batch_window: float = 0.002,
@@ -309,7 +309,6 @@ class CodesignServer(_BaseServer):
         self.workload = workload or paper_workload()
         self.gpu = gpu
         self.chunk = chunk
-        self.devices = devices
         self.lattice_2d = lattice_2d
         self.lattice_3d = lattice_3d
         if hw is None:
@@ -317,16 +316,6 @@ class CodesignServer(_BaseServer):
             if downsample > 1:
                 hw = hw.downsample(downsample)
         self.hw = hw
-        # apply the devices= promotion ONCE (auto -> sharded, non-mesh
-        # engines rejected), so the key below, the miss-path build, and
-        # the persisted artifact can never disagree about which matrix
-        # family they name. Full auto resolution stays lazy: it needs
-        # device_count(), which would initialize the jax backend on warm
-        # paths that never sweep (the digest resolves the remaining
-        # "auto" to its matrix family without touching a backend).
-        from repro.core.codesign import _devices_engine
-
-        engine = _devices_engine(engine, devices)
         self.engine = engine
         self.key = store.key_for(
             self.workload, gpu, self.hw, engine, lattice_2d, lattice_3d
@@ -341,7 +330,6 @@ class CodesignServer(_BaseServer):
             lattice_3d=self.lattice_3d,
             chunk=self.chunk,
             engine=self.engine,
-            devices=self.devices,
         )
         return self.store.put(
             result,

@@ -60,6 +60,7 @@ _HELD_LOCKS: Dict[str, list] = {}
 _HELD_LOCKS_MU = threading.Lock()
 
 from repro.core.codesign import CodesignResult, HardwareSpace
+from repro.core.engines import engine_family
 from repro.core.solver import LATTICE_2D, LATTICE_3D, TileLattice
 from repro.core.timemodel import GPUSpec
 from repro.core.workload import Workload
@@ -107,18 +108,6 @@ _INT_WIDTHS = (np.int8, np.int16, np.int32, np.int64)
 #: Manifests written before kinds existed read as "sweep".
 KINDS = ("sweep", "measurement", "calibration", "telemetry", "portfolio")
 
-#: engines whose optima matrices are bit-identical share one content
-#: address: "sharded" is the same compiled program as "jax", merely
-#: partitioned over a device mesh, so an artifact built on an 8-device
-#: host warms a single-device host (and vice versa). "numpy" keeps its own
-#: key -- the float64 oracle differs from the float32 engines in the last
-#: ulps, and the digest must never claim two different matrices are one.
-#: "auto" is resolved to the concrete engine it would pick *before*
-#: digesting (see :func:`artifact_spec`): keying the unresolved alias
-#: would let a jax host's float32 matrix and a jax-less host's float64
-#: matrix share one key.
-_DIGEST_ENGINE = {"sharded": "jax"}
-
 # ---- observability (repro.obs) -------------------------------------------
 _REG = _obs_registry()
 _M_BUILDS = _REG.counter(
@@ -155,25 +144,6 @@ class BuildLockTimeoutError(GatewayError):
         self.retry_after_s = float(retry_after_s)
 
 
-def _digest_engine(engine: str, n_hw: int) -> str:
-    if engine == "auto":
-        # resolve only the matrix *family* (float64 oracle vs float32
-        # compiled) -- deliberately NOT via _resolve_engine, whose
-        # device_count() call would initialize the jax backend (on GPU
-        # hosts: ~75% memory preallocation) on warm paths that never
-        # sweep. Device count cannot matter here: multi-device auto picks
-        # "sharded", which canonicalizes to "jax" anyway.
-        from repro.core.codesign import _AUTO_MIN_HW
-
-        if n_hw < _AUTO_MIN_HW:
-            engine = "numpy"
-        else:
-            from repro.core import sweep  # module import only, no backend
-
-            engine = "jax" if sweep.HAVE_JAX else "numpy"
-    return _DIGEST_ENGINE.get(engine, engine)
-
-
 def _canonical_json(obj) -> str:
     return json.dumps(obj, sort_keys=True, separators=(",", ":"))
 
@@ -197,7 +167,12 @@ def artifact_spec(
 ) -> dict:
     """The content-address identity of a sweep, computable WITHOUT running
     it. Frequencies are deliberately excluded: the stored matrix serves
-    every mix, so re-weighting must not change the key."""
+    every mix, so re-weighting must not change the key. The engine enters
+    as its matrix family (:func:`repro.core.engines.engine_family`:
+    ``"jax"`` or ``"numpy"``), so bit-identical engines share one key --
+    an artifact built on the sharded engine of a many-device host warms a
+    one-device host -- while the float64 oracle keeps its own, and
+    ``"auto"`` keys as the family it builds, never as the alias."""
     lat_d = lambda lat: {k: list(getattr(lat, k)) for k in ("t_s1", "t_s2", "t_t", "k", "t_s3")}
     return {
         "format_version": FORMAT_VERSION,
@@ -213,7 +188,7 @@ def artifact_spec(
         "hw_digest": _array_digest(hw.n_sm, hw.n_v, hw.m_sm, hw.area),
         "n_hw": len(hw),
         "lattices": {"2d": lat_d(lattice_2d), "3d": lat_d(lattice_3d)},
-        "engine": _digest_engine(engine, len(hw)),
+        "engine": engine_family(engine, len(hw)),
     }
 
 
@@ -227,7 +202,7 @@ def lm_artifact_spec(workload: Workload, hw, engine: str, gpu_name: str) -> dict
     numeric identity -- model/op/shape plus the precomputed constants that
     enter the time model -- so any change that could move the matrix moves
     the key."""
-    from repro.core.lmcells import resolve_lm_engine, lm_sw_lattice
+    from repro.core.lmcells import lm_sw_lattice
 
     return {
         "format_version": FORMAT_VERSION,
@@ -248,7 +223,7 @@ def lm_artifact_spec(workload: Workload, hw, engine: str, gpu_name: str) -> dict
                 for c in workload.cells
             }
         ),
-        "engine": resolve_lm_engine(engine),
+        "engine": engine_family(engine),
     }
 
 

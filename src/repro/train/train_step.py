@@ -7,7 +7,8 @@ Distributed-optimization posture:
   ``lax.scan``, so the data-parallel gradient all-reduce is emitted once
   per step, not once per microbatch (collective bytes / step drop by M);
 * the remat policy is a named knob ('none'|'dots'|'full') -- it is one of
-  the software parameters the meshopt codesign sweeps;
+  the software parameters the LM codesign sweep (repro.core.lmcells)
+  searches;
 * parameter/optimizer shardings are donated, so the step is in-place at
   the XLA level.
 """

@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch
-from repro.core import sweep
 from repro.core.codesign import codesign
+from repro.core.engines import engine_family
 from repro.core.lmcells import (
     LM_GPU_NAME,
     enumerate_lm_hw_space,
@@ -20,7 +20,6 @@ from repro.core.lmcells import (
     lm_codesign,
     lm_sw_lattice,
     lm_workload,
-    resolve_lm_engine,
 )
 from repro.core.lmtime import MeshPlan, lm_roofline
 from repro.core.workload import Workload, paper_workload
@@ -120,7 +119,6 @@ def test_scalar_oracle_mirrors_lm_roofline(cfgs, wl):
     assert checked == 6 * len(plans)
 
 
-@pytest.mark.skipif(not sweep.HAVE_JAX, reason="jax not installed")
 def test_jax_engine_matches_numpy(wl, hw, oracle):
     jres = lm_codesign(wl, hw=hw, engine="jax")
     feas = np.isfinite(oracle.cell_time)
@@ -136,11 +134,17 @@ def test_jax_engine_matches_numpy(wl, hw, oracle):
             assert times[j] == pytest.approx(oracle.cell_time[ci, hi], rel=RTOL)
 
 
-def test_engine_resolution():
-    assert resolve_lm_engine("numpy") == "numpy"
-    assert resolve_lm_engine("auto") in ("numpy", "jax")
-    with pytest.raises(ValueError):
-        resolve_lm_engine("cuda")
+def test_engine_resolution(wl, hw):
+    """The LM grid asks the one rule with no hardware floor: auto is jax
+    on any mesh space, however small; "sharded" is not an engine."""
+    assert engine_family("numpy") == "numpy"
+    assert engine_family("jax") == "jax"
+    assert engine_family("auto") == "jax"
+    for bad in ("cuda", "sharded"):
+        with pytest.raises(ValueError, match="unknown engine"):
+            engine_family(bad)
+        with pytest.raises(ValueError, match="unknown engine"):
+            lm_codesign(wl, hw=hw, engine=bad)
 
 
 def test_codesign_dispatches_on_family(wl, hw, oracle):
@@ -211,7 +215,7 @@ def test_key_tracks_the_question(tmp_path, wl, cfgs, hw):
 
 def test_divisibility_infeasibility(cfgs, hw):
     """A global batch that cannot shard over the data axis must surface as
-    +inf / plan -1, mirroring meshopt's constraint -- not as a silently
+    +inf / plan -1 (the shardability constraint) -- not as a silently
     wrong time."""
     from repro.configs.base import ShapeSpec
 
@@ -362,17 +366,16 @@ def test_deepseek_engines_match_the_scalar_oracle(full):
         for hi in range(len(hw)):
             times = _brute_force(cell, lat, hw.point(hi))
             assert res.cell_time[ci, hi] == min(times), (cell.label, hi)
-    if sweep.HAVE_JAX:
-        jres = lm_codesign(wl, hw=hw, engine="jax")
-        feas = np.isfinite(res.cell_time)
-        assert np.array_equal(feas, np.isfinite(jres.cell_time))
-        assert np.allclose(jres.cell_time[feas], res.cell_time[feas], rtol=RTOL)
-        for ci, cell in enumerate(wl.cells):
-            lat = lm_sw_lattice(cell.op)
-            for hi in np.nonzero(feas[ci] & (jres.cell_plan_idx[ci] != res.cell_plan_idx[ci]))[0]:
-                times = _brute_force(cell, lat, hw.point(int(hi)))
-                j = int(jres.cell_plan_idx[ci, hi])
-                assert times[j] == pytest.approx(res.cell_time[ci, hi], rel=RTOL)
+    jres = lm_codesign(wl, hw=hw, engine="jax")
+    feas = np.isfinite(res.cell_time)
+    assert np.array_equal(feas, np.isfinite(jres.cell_time))
+    assert np.allclose(jres.cell_time[feas], res.cell_time[feas], rtol=RTOL)
+    for ci, cell in enumerate(wl.cells):
+        lat = lm_sw_lattice(cell.op)
+        for hi in np.nonzero(feas[ci] & (jres.cell_plan_idx[ci] != res.cell_plan_idx[ci]))[0]:
+            times = _brute_force(cell, lat, hw.point(int(hi)))
+            j = int(jres.cell_plan_idx[ci, hi])
+            assert times[j] == pytest.approx(res.cell_time[ci, hi], rel=RTOL)
 
 
 def test_deepseek_is_answered_within_its_training_cluster():
@@ -459,7 +462,6 @@ def _bits(a):
     return a.dtype, a.shape, a.tobytes()
 
 
-@pytest.mark.skipif(not sweep.HAVE_JAX, reason="jax not installed")
 def test_jax_engine_is_bit_identical_to_per_cell_grids(question):
     wl, hw = question
     res = lm_codesign(wl, hw=hw, engine="jax")
@@ -469,7 +471,6 @@ def test_jax_engine_is_bit_identical_to_per_cell_grids(question):
     assert np.isfinite(res.cell_time).any(axis=1).all()
 
 
-@pytest.mark.skipif(not sweep.HAVE_JAX, reason="jax not installed")
 def test_permuted_meshes_give_the_permuted_result(question):
     """The benchmark's questions: the whole mesh space in a new order."""
     from repro.core.lmcells import LMHardwareSpace
@@ -483,7 +484,6 @@ def test_permuted_meshes_give_the_permuted_result(question):
     assert _bits(got.cell_plan_idx) == _bits(np.ascontiguousarray(res.cell_plan_idx[:, p]))
 
 
-@pytest.mark.skipif(not sweep.HAVE_JAX, reason="jax not installed")
 def test_one_transfer_for_the_meshes_and_one_a_cell(question, monkeypatch):
     import jax
 

@@ -8,17 +8,11 @@ import dataclasses
 import io
 import json
 import logging as pylogging
-import os
-import sys
 import tempfile
 import threading
 
 import pytest
 
-# benchmarks/ is a repo-root namespace package: on sys.path under
-# `python -m pytest` (cwd prepended) but not under a bare `pytest`
-sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir)))
-from benchmarks.common import validate_trajectory_entry  # noqa: E402
 from repro.core import MAXWELL, enumerate_hw_space
 from repro.core.timemodel import MAXWELL_GPU, TITANX_GPU
 from repro.core.workload import paper_workload
@@ -464,23 +458,3 @@ def test_exemplar_trace_id_cross_references_header(fleet):
     everything = (snap["routes"].get("/v1/query", {}).get("slow", [])
                   + list(snap["routes"].get("/v1/query", {}).get("errors", [])))
     assert any(e["trace_id"] == tid for e in everything) or len(everything) > 0
-
-
-# ---------------------------------------------------------------------------
-# trajectory schema gate
-# ---------------------------------------------------------------------------
-def test_validate_trajectory_entry():
-    validate_trajectory_entry(
-        {"suite": "service", "cold_s": 1.2, "warm_qps": 900,
-         "engines_total_s": {"jax": 0.5}}
-    )
-    with pytest.raises(TypeError):
-        validate_trajectory_entry(["not", "a", "dict"])
-    with pytest.raises(ValueError, match="suite"):
-        validate_trajectory_entry({"cold_s": 1.0})
-    with pytest.raises(ValueError, match="cold_s"):
-        validate_trajectory_entry({"suite": "x", "cold_s": float("inf")})
-    with pytest.raises(ValueError, match="nested.t_s"):
-        validate_trajectory_entry({"suite": "x", "nested": {"t_s": "1.2"}})
-    with pytest.raises(ValueError, match="warm_qps"):
-        validate_trajectory_entry({"suite": "x", "warm_qps": True})

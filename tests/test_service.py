@@ -188,23 +188,23 @@ def test_stale_format_version_reads_as_miss(built, monkeypatch):
 
 
 def test_key_is_engine_invariant_for_bit_identical_engines(built):
-    """'sharded' is the same compiled program as 'jax' over a mesh, so the
-    two must share one content address (a warm artifact built on an
-    8-device host serves a 1-device host); the float64 'numpy' oracle must
-    keep a distinct key."""
+    """The key records the engine's matrix family (repro.core.engines):
+    "auto" keys as the jax engine it builds with -- which the sharded
+    engine shares, so an artifact built on an 8-device host serves a
+    1-device host -- below the floor as the numpy oracle it takes there,
+    and the float64 oracle keeps a key of its own. "sharded" is not a
+    value a caller picks."""
     store, _, _ = built
     wl = paper_workload()
     k_jax = store.key_for(wl, MAXWELL_GPU, small_hw(), "jax")
-    assert store.key_for(wl, MAXWELL_GPU, small_hw(), "sharded") == k_jax
+    assert store.key_for(wl, MAXWELL_GPU, small_hw(), "auto") == k_jax
     assert store.key_for(wl, MAXWELL_GPU, small_hw(), "numpy") != k_jax
-    # "auto" digests as the engine it would resolve to on this host --
-    # never as the raw alias (which would let a float32 and a float64
-    # matrix share one key depending on where the build happened)
-    from repro.core import sweep
-
-    k_auto = store.key_for(wl, MAXWELL_GPU, small_hw(), "auto")
-    assert k_auto == (k_jax if sweep.HAVE_JAX else
-                      store.key_for(wl, MAXWELL_GPU, small_hw(), "numpy"))
+    tiny = small_hw()
+    tiny = type(tiny)(tiny.n_sm[:3], tiny.n_v[:3], tiny.m_sm[:3], tiny.area[:3])
+    assert (store.key_for(wl, MAXWELL_GPU, tiny, "auto")
+            == store.key_for(wl, MAXWELL_GPU, tiny, "numpy"))
+    with pytest.raises(ValueError, match="unknown engine"):
+        store.key_for(wl, MAXWELL_GPU, small_hw(), "sharded")
 
 
 def test_put_same_key_reuses_winner_without_restaging(built):
@@ -278,9 +278,8 @@ def test_warm_query_is_engine_free_and_exact(built, monkeypatch):
     monkeypatch.setattr(codesign_mod, "codesign", boom)
     monkeypatch.setattr(server_mod, "codesign", boom)
     sweep_mod = importlib.import_module("repro.core.sweep")
-    if sweep_mod.HAVE_JAX:
-        monkeypatch.setattr(sweep_mod, "sweep_cell", boom)
-        monkeypatch.setattr(sweep_mod, "sweep_cells", boom)
+    monkeypatch.setattr(sweep_mod, "sweep_cell", boom)
+    monkeypatch.setattr(sweep_mod, "sweep_cells", boom)
 
     # a NEW server over the same store: key computed from the spec alone
     srv = CodesignServer(store, hw=small_hw(), engine="auto", batch_window=0.0)

@@ -15,8 +15,6 @@ from repro.core.solver import LATTICE_2D, LATTICE_3D, TileLattice, solve_cell
 from repro.core.timemodel import stencil_time
 from repro.core.workload import paper_workload
 
-pytestmark = pytest.mark.skipif(not sweep.HAVE_JAX, reason="jax not installed")
-
 #: float32 evaluation noise bound: disagreements beyond this are real bugs.
 RTOL = 1e-5
 
@@ -151,10 +149,15 @@ def test_codesign_engine_parity():
     assert g_jax == pytest.approx(g_np, rel=RTOL)
 
 
-def test_codesign_rejects_unknown_engine():
+@pytest.mark.parametrize("engine", ["cuda", "sharded"])
+def test_codesign_rejects_unknown_engine(engine):
+    """"sharded" is no longer a value a caller picks: "auto" takes the mesh
+    engine from the device count. Nor does codesign() take devices=."""
     wl = paper_workload(["jacobi2d"])
     with pytest.raises(ValueError, match="unknown engine"):
-        codesign(wl, hw=small_hw(step=64), engine="fortran")
+        codesign(wl, hw=small_hw(step=64), engine=engine)
+    with pytest.raises(TypeError, match="devices"):
+        codesign(wl, hw=small_hw(step=64), engine="auto", devices=1)
 
 
 def test_refine_points_batched():

@@ -3,8 +3,11 @@
 The sharded engine runs the *same* fused time-model body per shard, so the
 bar is **bit-identity** with :func:`repro.core.sweep.sweep_cells` -- not a
 tolerance -- for every padding regime (H not divisible by devices x chunk,
-H smaller than the device count) and every `devices=` selection. The CI
-sharded lane runs this file under
+H smaller than the device count) and every `devices=` selection of
+:func:`repro.core.sweep.sweep_cells_sharded`. Through ``codesign()`` the
+mesh is reached only by ``engine="auto"`` with more than one device
+attached; where one is, the tests monkeypatch ``sweep.device_count``. The
+CI sharded lane runs this file under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the mesh is a
 real 8-way partition; on a plain host the same tests exercise the 1-device
 mesh (the degenerate but still shard_map-compiled path), and a subprocess
@@ -19,11 +22,9 @@ import pytest
 
 from repro.core import MAXWELL, MAXWELL_GPU, STENCILS, codesign, enumerate_hw_space
 from repro.core import sweep
-from repro.core.codesign import _resolve_engine
+from repro.core.engines import dispatch_engine
 from repro.core.solver import LATTICE_2D
 from repro.core.workload import paper_workload
-
-pytestmark = pytest.mark.skipif(not sweep.HAVE_JAX, reason="jax not installed")
 
 
 def small_hw(step=16):
@@ -37,13 +38,21 @@ def hw_cols(hw):
 SIZES_2D = np.array([[4096, 4096, 1, 1024], [2048, 2048, 1, 512]], np.float64)
 
 
-def test_sharded_bit_identical_paper_sweep():
+@pytest.fixture
+def many_devices(monkeypatch):
+    """engine="auto" sees a many-device host: codesign() takes the mesh
+    engine, over every device actually attached."""
+    monkeypatch.setattr(sweep, "device_count", lambda: 8)
+
+
+def test_sharded_bit_identical_paper_sweep(many_devices):
     """Full six-stencil paper workload: the sharded driver path must equal
     the single-device engine bit-for-bit (times AND argmin indices)."""
     wl = paper_workload()
     hw = small_hw(step=24)
+    assert dispatch_engine("auto", len(hw)) == "sharded"
     res_jax = codesign(wl, hw=hw, engine="jax")
-    res_sh = codesign(wl, hw=hw, engine="sharded")
+    res_sh = codesign(wl, hw=hw, engine="auto")
     np.testing.assert_array_equal(res_sh.cell_time, res_jax.cell_time)
     np.testing.assert_array_equal(res_sh.cell_tile_idx, res_jax.cell_tile_idx)
 
@@ -116,48 +125,37 @@ def test_sharded_devices_knob():
 
 def test_engine_auto_promotes_on_multi_device(monkeypatch):
     """auto -> sharded iff >1 device; -> jax on one device; -> numpy below
-    the compile-amortization floor or without jax."""
+    the compile-amortization floor, whatever the device count."""
     monkeypatch.setattr(sweep, "device_count", lambda: 8)
-    assert _resolve_engine("auto", 1000) == "sharded"
+    assert dispatch_engine("auto", 1000) == "sharded"
+    assert dispatch_engine("auto", 3) == "numpy"  # tiny space: no compile
     monkeypatch.setattr(sweep, "device_count", lambda: 1)
-    assert _resolve_engine("auto", 1000) == "jax"
-    assert _resolve_engine("auto", 3) == "numpy"  # tiny space: no compile
-    monkeypatch.setattr(sweep, "HAVE_JAX", False)
-    assert _resolve_engine("auto", 1000) == "numpy"
+    assert dispatch_engine("auto", 1000) == "jax"
+    assert dispatch_engine("auto", 3) == "numpy"
 
 
-def test_devices_knob_implies_mesh_engine():
-    """devices= promotes auto to sharded (even below the numpy floor --
-    an explicit mesh request wins) and is rejected, not silently ignored,
-    by non-mesh engines."""
-    assert _resolve_engine("auto", 1000, devices=4) == "sharded"
-    assert _resolve_engine("auto", 3, devices=1) == "sharded"
-    assert _resolve_engine("sharded", 1000, devices=4) == "sharded"
-    for eng in ("jax", "numpy"):
-        with pytest.raises(ValueError, match="devices"):
-            _resolve_engine(eng, 1000, devices=2)
+def test_devices_knob_implies_mesh_engine(monkeypatch):
+    """The device count alone decides the mesh, and only under auto: an
+    explicit engine stays what it names on a many-device host, and the
+    driver takes no devices= of its own."""
+    monkeypatch.setattr(sweep, "device_count", lambda: 8)
+    assert dispatch_engine("jax", 1000) == "jax"
+    assert dispatch_engine("jax", 3) == "jax"  # the floor binds auto only
+    assert dispatch_engine("numpy", 1000) == "numpy"
     wl = paper_workload(["jacobi2d"])
-    with pytest.raises(ValueError, match="devices"):
-        codesign(wl, hw=small_hw(step=64), engine="numpy", devices=1)
-    res_auto = codesign(wl, hw=small_hw(step=64), engine="auto", devices=1)
+    res_auto = codesign(wl, hw=small_hw(step=64), engine="auto")
     res_jax = codesign(wl, hw=small_hw(step=64), engine="jax")
     np.testing.assert_array_equal(res_auto.cell_time, res_jax.cell_time)
+    np.testing.assert_array_equal(res_auto.cell_tile_idx, res_jax.cell_tile_idx)
 
 
-def test_engine_sharded_explicit_requires_jax(monkeypatch):
-    monkeypatch.setattr(sweep, "HAVE_JAX", False)
-    wl = paper_workload(["jacobi2d"])
-    with pytest.raises(ModuleNotFoundError, match="sharded"):
-        codesign(wl, hw=small_hw(step=64), engine="sharded")
-
-
-def test_sharded_matches_numpy_oracle_reductions():
+def test_sharded_matches_numpy_oracle_reductions(many_devices):
     """Workload-level reductions through the full driver stack agree with
     the float64 oracle within the cross-engine noise bound."""
     wl = paper_workload(["heat2d", "heat3d"], name="sharded-parity")
     hw = small_hw(step=48)
     res_np = codesign(wl, hw=hw, engine="numpy")
-    res_sh = codesign(wl, hw=hw, engine="sharded")
+    res_sh = codesign(wl, hw=hw, engine="auto")
     np.testing.assert_allclose(
         res_sh.weighted_time(), res_np.weighted_time(), rtol=1e-5
     )
@@ -171,14 +169,14 @@ import numpy as np
 import jax
 assert jax.device_count() == 8, jax.device_count()
 from repro.core import MAXWELL, codesign, enumerate_hw_space
-from repro.core.codesign import _resolve_engine
+from repro.core.engines import dispatch_engine
 from repro.core.workload import paper_workload
 
-assert _resolve_engine("auto", 1000) == "sharded"
+assert dispatch_engine("auto", 1000) == "sharded"
 wl = paper_workload(["jacobi2d", "heat3d"], name="forced8")
 hw = enumerate_hw_space(MAXWELL, max_area=650.0).downsample(32)
 res_jax = codesign(wl, hw=hw, engine="jax")
-res_sh = codesign(wl, hw=hw, engine="sharded")
+res_sh = codesign(wl, hw=hw, engine="auto")
 assert np.array_equal(res_sh.cell_time, res_jax.cell_time)
 assert np.array_equal(res_sh.cell_tile_idx, res_jax.cell_tile_idx)
 print("FORCED8_OK")
