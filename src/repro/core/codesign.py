@@ -30,7 +30,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.obs.metrics import get_registry as _obs_registry
-from repro.obs.trace import span
+from repro.obs.trace import set_attrs, span
 
 from .area import GTX980, TITAN_X, HardwarePoint, LinearAreaModel, MAXWELL
 from .engines import dispatch_engine, engine_family
@@ -387,6 +387,10 @@ def codesign(
     points, else the sharded engine when more than one device is attached,
     else jax). ``chunk`` bounds solver memory (hardware points per slab --
     per device on the sharded engine); ``None`` uses each engine's default.
+    The jax engine copies the hardware columns to the device once
+    (:func:`repro.core.sweep.stage_columns`), then calls
+    :func:`repro.core.sweep.sweep_cells` once per stencil group; the
+    ``codesign`` span's ``transfers`` attr counts the copies, 3 + groups.
 
     Dispatches on the workload's cell family: LM op-graph workloads
     (``workload.family == "lm"``) route to :func:`repro.core.lmcells
@@ -424,11 +428,18 @@ def codesign(
             # dispatch/launch overhead on accelerators; same argmins).
             from . import sweep
 
-            solve = sweep.sweep_cells_sharded if eng == "sharded" else sweep.sweep_cells
-            for st, cis, sizes in _stencil_groups(workload).values():
-                t, i = solve(
-                    st, gpu, sizes, hw.n_sm, hw.n_v, hw.m_sm, lattices[cis[0]], chunk
-                )
+            groups = _stencil_groups(workload).values()
+            if eng == "jax":
+                # the hardware columns go to the device once a question;
+                # each group's dispatch then copies only its constants
+                solve, cols = sweep.sweep_cells, sweep.stage_columns(hw.n_sm, hw.n_v, hw.m_sm)
+                transfers = len(cols) + len(groups)
+            else:
+                # the sharded engine pads and places the columns per call
+                solve, cols = sweep.sweep_cells_sharded, (hw.n_sm, hw.n_v, hw.m_sm)
+                transfers = 4 * len(groups)
+            for st, cis, sizes in groups:
+                t, i = solve(st, gpu, sizes, *cols, lattices[cis[0]], chunk)
                 for j, ci in enumerate(cis):
                     cell_time[ci] = t[j]
                     cell_idx[ci] = i[j]
@@ -446,6 +457,8 @@ def codesign(
             from repro.core.sweep import _M_OPTIMA
 
             _M_OPTIMA.labels(engine="numpy").inc(C * H)
+            transfers = 0
+        set_attrs(transfers=transfers)
     _M_CODESIGN_SECONDS.labels(engine=eng, family="stencil").observe(
         time.perf_counter() - t0
     )

@@ -57,6 +57,7 @@ __all__ = [
     "sweep_cell",
     "sweep_cells",
     "sweep_cells_sharded",
+    "stage_columns",
     "refine_points",
     "clear_caches",
 ]
@@ -98,16 +99,24 @@ _M_COMPILES = _REG.counter(
     "sweep dispatches, counted on the dispatching thread",
     labels=("engine",),
 )
+_M_TRANSFERS = _REG.counter(
+    "repro_sweep_transfers_total",
+    "host-to-device copies of sweep inputs: a question's staged hardware "
+    "columns once, then each dispatch's constants and any host columns",
+    labels=("engine",),
+)
 
 listen_for_compiles()
 
 
 @contextlib.contextmanager
-def _dispatch(engine: str, dims: int, p: int, h: int) -> Iterator[None]:
+def _dispatch(engine: str, dims: int, p: int, h: int, transfers: int) -> Iterator[None]:
     """One compiled dispatch: the ``sweep.dispatch`` span, its compile
-    count (the span's ``compiles`` attr), the phase-split wall time and
-    the optima counter."""
-    with span("sweep.dispatch", engine=engine, dims=dims, p=p, h=h):
+    count (the span's ``compiles`` attr), its host-to-device copies (the
+    ``transfers`` attr), the phase-split wall time and the optima
+    counter."""
+    _M_TRANSFERS.labels(engine=engine).inc(transfers)
+    with span("sweep.dispatch", engine=engine, dims=dims, p=p, h=h, transfers=transfers):
         n0 = compiles_so_far()
         t0 = time.perf_counter()
         yield
@@ -169,6 +178,22 @@ def _lattice_arrays(lattice: TileLattice, gpu: GPUSpec):
     return cols, jnp.asarray(keep_idx, jnp.int32)
 
 
+def _pack_consts(st: StencilSpec, sizes: np.ndarray) -> np.ndarray:
+    """A stencil group's constants as one float32 ``(P + 1, 4)`` host
+    array: the P ``(s1, s2, s3, t)`` size rows, then ``(radius, c_iter,
+    n_arrays, 0)``. Rounded to float32 here, on the host, as a
+    ``jnp.asarray(..., float32)`` of each value rounds it: the program
+    sees the same bits."""
+    scalars = np.array([[st.radius, st.c_iter, st.n_arrays, 0.0]], np.float64)
+    return np.concatenate([sizes, scalars]).astype(np.float32)
+
+
+def _unpack_consts(dims: int, consts):
+    """Inside a sweep program: ``(sizes (P, 4), traced spec)`` sliced from
+    the constants :func:`_pack_consts` packed."""
+    return consts[:-1], _traced_spec(dims, consts[-1, 0], consts[-1, 1], consts[-1, 2])
+
+
 def _traced_spec(dims: int, radius, c_iter, n_arrays) -> StencilSpec:
     """A StencilSpec carrying tracers for its numeric fields.
 
@@ -218,10 +243,10 @@ def _best_of_factory(gpu: GPUSpec, lat, keep_idx):
     return best_of
 
 
-def _solve_empty(n_sm, n_v, m_sm, sizes, radius, c_iter, n_arrays):
+def _solve_empty(n_sm, n_v, m_sm, consts):
     """Every-candidate-infeasible fast path (no lattice point survives the
     static constraints): +inf / -1 without touching the mesh or compiler."""
-    p, h = sizes.shape[0], n_sm.shape[0]
+    p, h = consts.shape[0] - 1, n_sm.shape[0]
     return jnp.full((p, h), jnp.inf), jnp.full((p, h), -1, jnp.int32)
 
 
@@ -231,11 +256,12 @@ def _cells_solver(dims: int, gpu: GPUSpec, lattice: TileLattice, chunk: int):
     (dims, GPU, lattice, chunk).
 
     Returned callable:
-    ``(n_sm, n_v, m_sm, sizes (P, 4), radius, c_iter, n_arrays)
-    -> (best_t (P, H), best_i (P, H))`` over (H,) hardware arrays. Sizes
-    and stencil scalars are dynamic jit arguments, and the size axis is an
-    extra vmap dimension: all P problem sizes of a stencil family sweep in
-    ONE dispatch (the seed looped Python-side, paying per-cell dispatch).
+    ``(n_sm, n_v, m_sm, consts (P + 1, 4)) -> (best_t (P, H), best_i (P,
+    H))`` over (H,) hardware arrays, ``consts`` the sizes and stencil
+    scalars :func:`_pack_consts` packs. They are one dynamic jit argument,
+    and the size axis is an extra vmap dimension: all P problem sizes of a
+    stencil family sweep in ONE dispatch (the seed looped Python-side,
+    paying per-cell dispatch).
     The whole six-stencil paper sweep still compiles exactly twice
     (2D + 3D); only a new (P, H) shape pair retraces.
     """
@@ -244,8 +270,8 @@ def _cells_solver(dims: int, gpu: GPUSpec, lattice: TileLattice, chunk: int):
         return _solve_empty
     best_of = _best_of_factory(gpu, lat, keep_idx)
 
-    def solve(n_sm, n_v, m_sm, sizes, radius, c_iter, n_arrays):
-        st = _traced_spec(dims, radius, c_iter, n_arrays)
+    def solve(n_sm, n_v, m_sm, consts):
+        sizes, st = _unpack_consts(dims, consts)
         hw = jnp.stack([n_sm, n_v, m_sm], axis=1)  # (H, 3)
         h = hw.shape[0]
         if chunk <= 0 or h <= chunk:
@@ -299,9 +325,9 @@ def _sharded_cells_solver(
         return mesh, _solve_empty
     best_of = _best_of_factory(gpu, lat, keep_idx)
 
-    def shard_body(n_sm, n_v, m_sm, sizes, radius, c_iter, n_arrays):
+    def shard_body(n_sm, n_v, m_sm, consts):
         """One device's shard: hw columns are the local (H/D,) slice."""
-        st = _traced_spec(dims, radius, c_iter, n_arrays)
+        sizes, st = _unpack_consts(dims, consts)
         hw = jnp.stack([n_sm, n_v, m_sm], axis=1)  # (H/D, 3)
         h, p = hw.shape[0], sizes.shape[0]
         if chunk <= 0 or h <= chunk:
@@ -325,7 +351,7 @@ def _sharded_cells_solver(
     sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
-        in_specs=(P("hw"), P("hw"), P("hw"), P(), P(), P(), P()),
+        in_specs=(P("hw"), P("hw"), P("hw"), P()),
         out_specs=(P(None, "hw"), P(None, "hw")),
     )
     return mesh, jax.jit(sharded, donate_argnums=(0, 1, 2))
@@ -363,24 +389,39 @@ def sweep_cells(
     ``(best_time (P, H), best_lattice_index (P, H))`` as float64/int64;
     infeasible points get ``+inf`` / ``-1``. ``chunk=None`` scales the
     hardware slab down by P so peak memory matches the single-size sweep.
+
+    Hardware columns that are already device arrays, as
+    :func:`stage_columns` makes them, are used where they lie; host ones
+    are copied as float32. The host columns and the packed sizes and
+    stencil scalars go in one explicit ``jax.device_put`` (the span's
+    ``transfers``: 1 with staged columns, 4 with host ones), and the
+    optima come back in one ``jax.device_get``.
     """
     lattice, sizes, chunk = _prep_cells(st, sizes, lattice, chunk)
-    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
-    h = np.asarray(n_sm).size
-    with _dispatch("jax", st.dims, sizes.shape[0], h):
+    cols = (n_sm, n_v, m_sm)
+    staged = all(isinstance(a, jax.Array) for a in cols)
+    host = [] if staged else [np.asarray(a, np.float32).ravel() for a in cols]
+    h = np.size(n_sm)  # a device array's size is read from its shape
+    with _dispatch("jax", st.dims, sizes.shape[0], h, len(host) + 1):
         solve = _cells_solver(st.dims, gpu, lattice, chunk)
-        best_t, best_i = solve(
-            f32(np.asarray(n_sm).ravel()),
-            f32(np.asarray(n_v).ravel()),
-            f32(np.asarray(m_sm).ravel()),
-            f32(sizes),
-            f32(st.radius),
-            f32(st.c_iter),
-            f32(st.n_arrays),
-        )
+        *put, consts = jax.device_put(host + [_pack_consts(st, sizes)])
+        best_t, best_i = solve(*(cols if staged else put), consts)
         with span("sweep.fetch"):
             # blocks until the dispatch is done, then copies and casts
-            return np.asarray(best_t, np.float64), np.asarray(best_i, np.int64)
+            best_t, best_i = jax.device_get((best_t, best_i))
+            return best_t.astype(np.float64), best_i.astype(np.int64)
+
+
+def stage_columns(n_sm, n_v, m_sm) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The three hardware columns as float32 device arrays, copied in one
+    explicit ``jax.device_put``: what every :func:`sweep_cells` call of a
+    question can share instead of copying them again. Counts 3 transfers
+    on ``repro_sweep_transfers_total{engine="jax"}``."""
+    cols = jax.device_put(
+        tuple(np.asarray(a, np.float32).ravel() for a in (n_sm, n_v, m_sm))
+    )
+    _M_TRANSFERS.labels(engine="jax").inc(len(cols))
+    return cols
 
 
 def sweep_cells_sharded(
@@ -431,8 +472,7 @@ def sweep_cells_sharded(
     h_pad = -(-h // quantum) * quantum
     if h_pad != h:
         cols = [np.concatenate([a, np.full(h_pad - h, a[0], a.dtype)]) for a in cols]
-    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
-    with _dispatch("sharded", st.dims, sizes.shape[0], h):
+    with _dispatch("sharded", st.dims, sizes.shape[0], h, len(cols) + 1):
         mesh, solve = _sharded_cells_solver(st.dims, gpu, lattice, chunk, devs)
         shard = NamedSharding(mesh, P("hw"))
         repl = NamedSharding(mesh, P())
@@ -444,16 +484,13 @@ def sweep_cells_sharded(
                 "ignore", message="Some donated buffers were not usable"
             )
             best_t, best_i = solve(
-                *(jax.device_put(a, shard) for a in cols),
-                jax.device_put(f32(sizes), repl),
-                f32(st.radius),
-                f32(st.c_iter),
-                f32(st.n_arrays),
+                *jax.device_put(cols, shard),
+                jax.device_put(_pack_consts(st, sizes), repl),
             )
         with span("sweep.fetch"):
             # blocks on the dispatch, then copies, casts and drops the padding
-            return (np.asarray(best_t, np.float64)[:, :h],
-                    np.asarray(best_i, np.int64)[:, :h])
+            best_t, best_i = jax.device_get((best_t, best_i))
+            return best_t[:, :h].astype(np.float64), best_i[:, :h].astype(np.int64)
 
 
 def sweep_cell(

@@ -156,16 +156,16 @@ def test_sweep_programs_have_stable_names(engine, dims):
     hw = small_hw()
     h = len(hw)
     cols = [np.zeros(h, np.float32)] * 3
-    scalars = [np.float32(1.0)] * 3
+    consts = sweep._pack_consts(STENCILS["heat3d" if dims == 3 else "heat2d"], SIZES)
     if engine == "jax":
         solve = sweep._cells_solver(dims, MAXWELL_GPU, lattice, 64)
-        lowered = solve.lower(*cols, SIZES.astype(np.float32), *scalars)
+        lowered = solve.lower(*cols, consts)
         want = f"jit_sweep_{dims}d"
     else:
         _, solve = sweep._sharded_cells_solver(
             dims, MAXWELL_GPU, lattice, 64, tuple(jax.devices()[:1]))
         cols = [np.zeros(64, np.float32)] * 3
-        lowered = solve.lower(*cols, SIZES.astype(np.float32), *scalars)
+        lowered = solve.lower(*cols, consts)
         want = f"jit_sweep_{dims}d_sharded"
     assert f"module @{want} " in lowered.as_text()
 
@@ -183,6 +183,32 @@ def test_optima_counter_grows_by_p_times_h(engine):
             ENGINES[engine](st, sizes, hw, None)
         added = (len(SIZES) + len(SIZES_3D)) * len(hw)
     assert counter("repro_sweep_optima_total", engine=engine) == before + added
+
+
+def test_codesign_stages_hw_columns_once_a_question():
+    """A warm question copies the hardware columns once and each group's
+    constants once, all explicitly: no implicit host-to-device copy, 3 + G
+    transfers on the ``codesign`` span and the counter, 1 a dispatch."""
+    wl = paper_workload(["heat2d", "heat3d"])
+    hw = small_hw()
+    codesign(wl, gpu=MAXWELL_GPU, hw=hw, engine="jax")  # warm
+    before = counter("repro_sweep_transfers_total", engine="jax")
+    with jax.transfer_guard_host_to_device("disallow"), trace("q") as root:
+        codesign(wl, gpu=MAXWELL_GPU, hw=hw, engine="jax")
+    (cd,) = root.tree()["children"]
+    assert cd["attrs"]["transfers"] == 3 + 2
+    assert [d["attrs"]["transfers"] for d in cd["children"]] == [1, 1]
+    assert counter("repro_sweep_transfers_total", engine="jax") == before + 5
+
+
+def test_sweep_cells_with_host_columns_counts_four_transfers():
+    hw = small_hw()
+    before = counter("repro_sweep_transfers_total", engine="jax")
+    with trace("q") as root:
+        ENGINES["jax"](STENCILS["heat2d"], SIZES, hw, None)
+    (dispatch,) = root.tree()["children"]
+    assert dispatch["attrs"]["transfers"] == 4
+    assert counter("repro_sweep_transfers_total", engine="jax") == before + 4
 
 
 def test_codesign_holds_one_dispatch_per_stencil():

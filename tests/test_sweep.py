@@ -91,6 +91,47 @@ def test_sweep_cells_batches_all_sizes_in_one_dispatch():
             np.testing.assert_array_equal(i[p], i_ref)
 
 
+@pytest.mark.parametrize("name,lattice", [("heat2d", LATTICE_2D), ("heat3d", LATTICE_3D)])
+def test_staged_columns_match_host_columns(name, lattice):
+    """Float32 device columns from ``stage_columns`` give the very arrays
+    the float64 host columns give: same bits, same dtypes."""
+    from repro.core.workload import paper_sizes
+
+    st = STENCILS[name]
+    hw = small_hw(step=13)
+    sizes = np.array([(s.s1, s.s2, s.s3, s.t) for s in paper_sizes(st.dims)], np.float64)
+    cols = (hw.n_sm, hw.n_v, hw.m_sm)
+    staged = sweep.stage_columns(*cols)
+    assert all(c.dtype == np.float32 and c.shape == (len(hw),) for c in staged)
+    want = sweep.sweep_cells(st, MAXWELL_GPU, sizes, *cols, lattice)
+    got = sweep.sweep_cells(st, MAXWELL_GPU, sizes, *staged, lattice)
+    assert np.isfinite(want[0]).any()
+    for w, g in zip(want, got):
+        assert g.dtype == w.dtype and np.array_equal(g, w)
+
+
+def test_codesign_answers_through_sweep_cells_once_a_group(monkeypatch):
+    """``codesign()`` calls ``sweep.sweep_cells`` once per stencil group
+    and keeps what it answers: a wrapper that answers only half the
+    hardware columns, repeated, changes ``cell_time``."""
+    wl = paper_workload(["heat2d", "heat3d"])
+    hw = small_hw(step=48)
+    sound = codesign(wl, hw=hw, engine="jax").cell_time
+    real, calls = sweep.sweep_cells, []
+
+    def half_batch(st, gpu, sizes, n_sm, n_v, m_sm, lattice=None, chunk=None):
+        calls.append(st.name)
+        h = len(n_sm)
+        k = (h + 1) // 2
+        t, i = real(st, gpu, sizes, n_sm[:k], n_v[:k], m_sm[:k], lattice, chunk)
+        return np.concatenate([t, t], axis=1)[:, :h], np.concatenate([i, i], axis=1)[:, :h]
+
+    monkeypatch.setattr(sweep, "sweep_cells", half_batch)
+    faulty = codesign(wl, hw=hw, engine="jax").cell_time
+    assert sorted(calls) == ["heat2d", "heat3d"]
+    assert faulty.shape == sound.shape and not np.array_equal(faulty, sound)
+
+
 def test_codesign_jax_groups_match_oracle_per_cell():
     """The driver's one-dispatch-per-stencil-family path must equal the
     NumPy per-cell oracle on the full multi-size workload."""

@@ -92,9 +92,7 @@ def _solver_args(h, sharding, hw_sharding=None):
     hw = hw_sharding or sharding
     return (
         f32((h,), sharding=hw), f32((h,), sharding=hw), f32((h,), sharding=hw),
-        f32((PAPER_P, 4), sharding=sharding),
-        f32((), sharding=sharding), f32((), sharding=sharding),
-        f32((), sharding=sharding),
+        f32((PAPER_P + 1, 4), sharding=sharding),  # the sizes, then the stencil's scalars
     )
 
 
